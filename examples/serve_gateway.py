@@ -57,15 +57,13 @@ def main():
     ap.add_argument("--flight-dir", default=None)
     args = ap.parse_args()
 
-    import jax
-
+    from paddle_tpu.framework.platform import init_platform
     from paddle_tpu.incubate.nn import ContinuousBatchingEngine
     from paddle_tpu.observability import SLOMonitor, tracing
     from paddle_tpu.serving import run_gateway
 
-    if jax.devices()[0].platform != "tpu":
-        from paddle_tpu.ops.pallas import flash_attention as _fa
-        _fa._INTERPRET = True   # run the Pallas kernels on CPU
+    print(f"platform: {init_platform()}")   # raises with no TPU unless
+    #                                         JAX_PLATFORMS=cpu asks for it
 
     if not args.no_flight_recorder:
         fr = tracing.arm_default(args.flight_dir)

@@ -157,9 +157,8 @@ def main():
             ap.error("--tp needs --continuous (the paged serving path "
                      "is the sharded one; dense generate() is "
                      "single-chip)")
-        # must land before the first jax backend init: off-TPU the tp
-        # mesh runs on virtual CPU devices (the dryrun_multichip
-        # pattern)
+        # must land before the first jax backend init: under
+        # JAX_PLATFORMS=cpu the tp mesh runs on virtual CPU devices
         import os
         flag = f"--xla_force_host_platform_device_count={args.tp}"
         if "xla_force_host_platform_device_count" not in \
@@ -167,6 +166,9 @@ def main():
             os.environ["XLA_FLAGS"] = (
                 os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
 
+    from paddle_tpu.framework.platform import init_platform
+    print(f"platform: {init_platform()}")   # raises with no TPU unless
+    #                                         JAX_PLATFORMS=cpu asks for it
     rng = np.random.default_rng(0)
     V, E, H, G, D, L, F = 512, 128, 8, 4, 16, 4, 344
     SMAX = 128
@@ -191,10 +193,6 @@ def main():
         tp=args.tp)
 
     if args.continuous:
-        import jax
-        if jax.devices()[0].platform != "tpu":
-            from paddle_tpu.ops.pallas import flash_attention as _fa
-            _fa._INTERPRET = True  # run the Pallas kernels on CPU
         return run_continuous(engine, rng, V, args)
 
     prompts = rng.integers(0, V, (args.batch, 16)).astype(np.int32)

@@ -33,73 +33,23 @@ newaxis = None
 
 
 def resolve_shard_map():
-    """shard_map moved across JAX releases: new JAX exposes a callable
-    `jax.shard_map` (kwargs `axis_names` / `check_vma`), 0.4.x keeps it
-    in `jax.experimental.shard_map` (kwargs `auto` / `check_rep`), and
-    some intermediate versions export `jax.shard_map` as the submodule.
-    Every in-tree user routes through here instead of importing from jax
-    directly (a bare `from jax import shard_map` raises at import time on
-    0.4.x and takes the whole package — and the test suite — down with
-    it). In-tree callers write the NEW kwargs; on old jax this returns an
-    adapter that maps `check_vma` to `check_rep` and handles
-    `axis_names`: fully-manual calls (axis_names covers the mesh) pass
-    straight through, but partial-auto calls are REFUSED with
-    NotImplementedError — 0.4.x's experimental shard_map does accept an
-    `auto=` kwarg for that case, yet feeding it these call sites aborts
-    the process outright (Fatal Python error in XLA, observed on the
-    ulysses context-parallel path), and a clean per-call failure beats
-    killing the whole test run."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None and not callable(sm):
-        sm = getattr(sm, "shard_map", None)
-    if sm is not None:
-        try:
-            accepts_new = "check_vma" in inspect.signature(sm).parameters
-        except (TypeError, ValueError):
-            accepts_new = True  # unsignaturable builtin: assume current
-        if accepts_new:
-            return sm
-        legacy = sm  # jax.shard_map exists but predates the VMA rename
-    else:
-        from jax.experimental.shard_map import shard_map as legacy
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  axis_names=None, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        if axis_names is not None:
-            auto = frozenset(a for a in mesh.axis_names
-                             if a not in set(axis_names))
-            if auto:
-                # partial-auto (manual over a subset of mesh axes) is
-                # crash-prone in 0.4.x's experimental shard_map on CPU —
-                # refuse loudly rather than abort the process
-                raise NotImplementedError(
-                    "shard_map partial-auto mode (manual axes "
-                    f"{sorted(axis_names)} over mesh axes "
-                    f"{list(mesh.axis_names)}) needs a newer jax; this "
-                    f"jax ({jax.__version__}) only supports fully-manual "
-                    "shard_map here")
-        return legacy(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-    return shard_map
+    """`jax.shard_map` (keywords `axis_names`, a set, and `check_vma`).
+    Every in-tree user takes shard_map from here, so there is one
+    import site to change when jax moves it (graftlint GL101)."""
+    return jax.shard_map
 
 
-shard_map = resolve_shard_map()
+shard_map = jax.shard_map
 
 
 def resolve_compiler_params():
-    """jax renamed `pltpu.TPUCompilerParams` -> `pltpu.CompilerParams`
-    across releases (same contract either way); spelling either one
-    directly binds code to one side of the rename. Every in-tree user
-    routes through here (graftlint GL102 enforces it). Lazy pltpu import:
-    this module is imported before the Pallas tier and must not pull it
-    in at package-import time."""
+    """`pltpu.CompilerParams`. Every kernel builds its Mosaic parameters
+    through here (graftlint GL102). Lazy pltpu import: this module is
+    imported before the Pallas tier and must not pull it in at
+    package-import time."""
     from jax.experimental.pallas import tpu as pltpu
-    return getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+    return pltpu.CompilerParams
+
 
 float8_e4m3fn = ml_dtypes.float8_e4m3fn
 float8_e5m2 = ml_dtypes.float8_e5m2

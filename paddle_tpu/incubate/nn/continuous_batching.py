@@ -600,8 +600,7 @@ class ContinuousBatchingEngine:
                  tpot_slo=None, min_prefill_chunk=64, prefix_cache=False,
                  monitor=None, memory_watch=None, shed_on_pressure=False,
                  shed_priority_min=1, autotune_cache=None,
-                 host_fastpath=True, host_debug_check=False,
-                 overlap_fetch=False):
+                 host_fastpath=True, host_debug_check=False):
         import jax
 
         self.engine = engine
@@ -763,13 +762,6 @@ class ContinuousBatchingEngine:
         self._host_fastpath = bool(host_fastpath)
         self._host_debug = bool(host_debug_check) or bool(
             os.environ.get("PADDLE_TPU_HOST_DEBUG_CHECK"))
-        # overlap is OPT-IN: it reorders token-independent host
-        # bookkeeping (non-completing prefill advancement, stall
-        # events, monitor/memory ticks) to before the token fetch, so
-        # tick cadence sees last step's samples — token-exact (pinned
-        # by serve_bench --host in every mode), but not span/metric-
-        # order-identical, hence not the default
-        self._overlap_fetch = bool(overlap_fetch)
         self._work_builder = RaggedWorkBuilder(
             self.max_batch, self.max_blocks, self.block_size,
             self._pack) if self._host_fastpath else None
@@ -779,10 +771,7 @@ class ContinuousBatchingEngine:
         self._sel_bufs = {}         # w_sel -> [B, w_sel] int32
         self._q_arr_buf = np.zeros(self.max_batch, np.int32)
         self._attn_buf = np.zeros(self.max_batch, np.int32)
-        self._rw_old_buf = np.zeros(self.max_batch, np.int32)
-        self._ztab_buf = None       # lazily: only prefix-on rewinds
         self._input_copy_bytes = 0  # engine-local mirror of the counter
-        self._overlap_steps = 0
         self._last_host_phases = {}
         self._wb_last = (0, 0, 0, 0)    # registry-mirrored builder state
 
@@ -790,20 +779,17 @@ class ContinuousBatchingEngine:
         """Engine-local host-fast-path accounting (the process registry
         aggregates across engines; tests and serve_bench want THIS
         engine's numbers): work-segment reuse/rebuild and assembly-mode
-        counts from the work-list builder, step-input copy bytes,
-        overlap-mode step count, and the last step's host-phase split
-        in seconds."""
+        counts from the work-list builder, step-input copy bytes, and
+        the last step's host-phase split in seconds."""
         wb = self._work_builder
         return {
             "fastpath": self._host_fastpath,
-            "overlap": self._overlap_fetch,
             "segments_reused": wb.segments_reused if wb else 0,
             "segments_rebuilt": wb.segments_rebuilt if wb else 0,
             "assemblies_full": wb.assemblies_full if wb else 0,
             "assemblies_incremental":
                 wb.assemblies_incremental if wb else 0,
             "input_copy_bytes": self._input_copy_bytes,
-            "overlap_steps": self._overlap_steps,
             "phases": dict(self._last_host_phases),
         }
 
@@ -1794,69 +1780,20 @@ class ContinuousBatchingEngine:
                 "psum", group="tp",
                 nbytes=self.engine.tp_step_comm_bytes(self.max_batch, c))
         pc_step = time.perf_counter()
-        # tables/lens go in as the persistent scheduler arrays
-        # themselves: jit snapshots committed numpy arguments at
-        # dispatch, so host mutation AFTER this call (the overlap
-        # window below, next step's bookkeeping) can never race the
-        # device read — the per-step asarray round-trip the fast path
-        # retired was pure copy discipline
+        # tables/lens (and the slab/sel/work buffers) go in as the
+        # persistent scheduler arrays themselves. jit does NOT snapshot
+        # a numpy argument at dispatch: a 64-byte-aligned buffer is
+        # aliased by the CPU client, and an accelerator copies it
+        # asynchronously — so nothing may mutate these arrays until the
+        # token fetch below has waited the step out
         toks2, self.caches = self.engine._paged_step(
             self.engine._w, self.caches, slab, q_arr, sel,
             self.tables, self.lens, tuple(work),
             pack, np.float32(self._temp), np.float32(self._topp), sub)
         pc_disp = time.perf_counter()
-        pc_ovl = pc_disp
-        ticked = False
         emitted = 0
         rewinds = []    # (slot, new_end, old_end): rejected draft spans
         slot_spans = []  # (slot, request_id, span name, args) this step
-        pre_done = set()    # slots the overlap window fully handled
-        if self._overlap_fetch:
-            # overlap window: host work that cannot depend on this
-            # step's sampled tokens runs while the device executes —
-            # starved-slot stall bookkeeping, prefill-chunk advancement
-            # for chunks that do NOT complete their prompt (the prompt
-            # is immutable; only the completing chunk samples a token),
-            # and the monitor/memory tick cadence (which consequently
-            # evaluates the PREVIOUS step's samples — the eager path
-            # ticks after commit). Token-exact in every scheduler mode
-            # (pinned by serve_bench --host): nothing here feeds the
-            # accept/rewind loop.
-            for i in active:
-                req = self.slots[i]
-                n = int(q_lens[i])
-                if n == 0:
-                    if req.progress < req._resume_len:
-                        if i in self._pending_stalls:
-                            tr.event("stall_cache_pending",
-                                     request=req.request_id,
-                                     prompt_remaining=req._resume_len
-                                     - req.progress)
-                        else:
-                            tr.event("stall_budget",
-                                     request=req.request_id,
-                                     prompt_remaining=req._resume_len
-                                     - req.progress,
-                                     token_budget=self.token_budget)
-                    pre_done.add(i)
-                elif req.progress < req._resume_len \
-                        and req.progress + n < req._resume_len:
-                    requested, granted = self._sched_info.get(i, (n, n))
-                    slot_spans.append(
-                        (i, req.request_id, "prefill_chunk",
-                         {"width": n, "granted": granted,
-                          "requested": requested,
-                          "progress": req.progress + n}))
-                    self.lens[i] += n
-                    req.progress += n
-                    pre_done.add(i)
-            if self.monitor is not None:
-                self.monitor.tick()
-            if self.memory_watch is not None:
-                self.memory_watch.tick()
-            ticked = True
-            self._overlap_steps += 1
-            pc_ovl = time.perf_counter()
         toks2 = np.asarray(toks2)      # [B, W]: a sample per sel column
         t_done = time.monotonic()
         pc_done = time.perf_counter()
@@ -1871,8 +1808,6 @@ class ContinuousBatchingEngine:
                     self._comm_seconds[rid] = self._comm_seconds.get(
                         rid, 0.0) + comm_dur
         for i in active:
-            if i in pre_done:
-                continue        # settled in the overlap window above
             req = self.slots[i]
             n = int(q_lens[i])
             if n == 0:
@@ -1954,9 +1889,13 @@ class ContinuousBatchingEngine:
             # cases unreachable in normal flow (drafts only ever land
             # in exclusively-held blocks), but the rewind must stay
             # safe against ANY sharing topology.
-            ztab = self.tables
+            #
+            # The rewind program reads its arguments whenever the device
+            # gets to them (jit does not snapshot numpy arguments) and
+            # the host rolls tables/lens on right below, with no fetch
+            # in between: it gets private copies.
+            shared_drops = []
             if self._prefix_on:
-                shared_drops = []
                 for i, ne, oe in rewinds:
                     req = self.slots[i]
                     keep = -(-ne // self.block_size) if ne > 0 else 0
@@ -1968,32 +1907,11 @@ class ContinuousBatchingEngine:
                                 self._cow_block(i, idx)
                             else:
                                 shared_drops.append((i, idx))
-                if shared_drops:
-                    if self._host_fastpath:
-                        # persistent retarget scratch (lazy: only
-                        # prefix-on rewinds with shared drops ever
-                        # need a diverging table view)
-                        if self._ztab_buf is None:
-                            self._ztab_buf = self.tables.copy()
-                        else:
-                            np.copyto(self._ztab_buf, self.tables)
-                        ztab = self._ztab_buf
-                    else:
-                        ztab = self.tables.copy()
-                        self._count_input_bytes(ztab.nbytes)
-                    for i, idx in shared_drops:
-                        ztab[i, idx] = 0
-            if self._host_fastpath:
-                # persistent-buffer discipline (GL109 family): new_l IS
-                # the settled lens array — jit snapshots it at dispatch
-                # — and old_l reuses one preallocated scratch row
-                new_l = self.lens
-                old_l = self._rw_old_buf
-                np.copyto(old_l, self.lens)
-            else:
-                new_l = self.lens.copy()
-                old_l = self.lens.copy()
-                self._count_input_bytes(new_l.nbytes + old_l.nbytes)
+            ztab = self.tables.copy()       # after the COW remaps
+            for i, idx in shared_drops:
+                ztab[i, idx] = 0
+            new_l = self.lens.copy()
+            old_l = self.lens.copy()
             for i, _, oe in rewinds:
                 old_l[i] = oe
             self.caches = self.engine._paged_rewind(
@@ -2025,8 +1943,7 @@ class ContinuousBatchingEngine:
                        host_sched_us=int((pc_sched - pc_begin) * 1e6),
                        host_build_us=int((pc_step - pc_sched) * 1e6),
                        host_dispatch_us=int((pc_disp - pc_step) * 1e6),
-                       host_overlap_us=int((pc_ovl - pc_disp) * 1e6),
-                       host_fetch_us=int((pc_done - pc_ovl) * 1e6))
+                       host_fetch_us=int((pc_done - pc_disp) * 1e6))
         self._step_count += 1
         _metrics.serve_step_seconds().observe(dur)
         if emitted:
@@ -2038,31 +1955,26 @@ class ContinuousBatchingEngine:
         # engine is prompt-bound
         _metrics.serve_effective_tokens_per_step().set(emitted)
         self._maybe_shrink_chunk()
-        if not ticked:
-            # host-side cadence hooks: registry sample + burn-rate pass
-            # when the monitor's cadence elapsed, a monotonic compare
-            # otherwise — AFTER the step's own metrics landed, so a
-            # breach evaluation always sees this step's samples (the
-            # overlap window already ticked, one step behind, when
-            # overlap_fetch is on)
-            if self.monitor is not None:
-                self.monitor.tick()
-            if self.memory_watch is not None:
-                # same cadence contract: HBM/census + hbm_pressure
-                self.memory_watch.tick()
+        # host-side cadence hooks: registry sample + burn-rate pass
+        # when the monitor's cadence elapsed, a monotonic compare
+        # otherwise — AFTER the step's own metrics landed, so a
+        # breach evaluation always sees this step's samples
+        if self.monitor is not None:
+            self.monitor.tick()
+        if self.memory_watch is not None:
+            # same cadence contract: HBM/census + hbm_pressure
+            self.memory_watch.tick()
         pc_end = time.perf_counter()
         phases = {"schedule": pc_sched - pc_begin,
                   "build": pc_step - pc_sched,
                   "dispatch": pc_disp - pc_step,
-                  "overlap": pc_ovl - pc_disp,
-                  "fetch": pc_done - pc_ovl,
+                  "fetch": pc_done - pc_disp,
                   "commit": pc_end - pc_done}
         self._last_host_phases = phases
         hp = _metrics.serve_host_phase_seconds()
         hp.labels(phase="schedule").observe(phases["schedule"])
         hp.labels(phase="build").observe(phases["build"])
         hp.labels(phase="dispatch").observe(phases["dispatch"])
-        hp.labels(phase="overlap").observe(phases["overlap"])
         hp.labels(phase="fetch").observe(phases["fetch"])
         hp.labels(phase="commit").observe(phases["commit"])
         wb = self._work_builder
@@ -2206,7 +2118,7 @@ class ContinuousBatchingEngine:
         `collective` span records) — and the mesh width ``tp``."""
         out = _tracing.request_summary(request_id)
         # ISSUE 20: the engine's last-step host-phase split (seconds,
-        # schedule/build/dispatch/overlap/fetch/commit) rides on every
+        # schedule/build/dispatch/fetch/commit) rides on every
         # digest — the live counterpart of the per-step `host` args the
         # serve_step spans carry into flight dumps
         out["host_phases"] = dict(self._last_host_phases)

@@ -31,15 +31,13 @@ __all__ = ["llama_sharding_rules", "gpt_sharding_rules",
            "flagship_config"]
 
 
-def flagship_config(on_tpu=True):
-    """The headline benchmark shape: (LlamaConfig, batch, seq).
+def flagship_config():
+    """The one-chip training shape: (LlamaConfig, batch, seq).
 
-    bench.py AND tools/step_profile.py build from HERE — the profile
-    evidence must always describe the step being benchmarked; the config
-    has been retuned every round, so a copy would silently drift."""
+    chip_smoke.py, bench.py AND tools/step_profile.py build from HERE —
+    the profile evidence must always describe the step being run; a copy
+    would silently drift."""
     from .llama import LlamaConfig
-    if not on_tpu:  # CPU smoke
-        return LlamaConfig.tiny(dtype="float32"), 4, 64
     cfg = LlamaConfig(
         vocab_size=32000, hidden_size=1024, intermediate_size=2816,
         num_hidden_layers=24, num_attention_heads=16,

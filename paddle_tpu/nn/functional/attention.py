@@ -15,15 +15,45 @@ import jax.numpy as jnp
 from ...core.dispatch import apply_op
 from ...core import random as _random
 
-_USE_PALLAS = True  # flipped off on CPU automatically inside _flash_available
+_USE_PALLAS = True
 
 
 @functools.lru_cache(maxsize=1)
 def _flash_available():
-    try:
-        return _USE_PALLAS and jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """The Pallas flash kernel runs on the TPU; every other backend takes
+    the XLA reference path. A backend that fails to initialise raises."""
+    return _USE_PALLAS and jax.devices()[0].platform == "tpu"
+
+
+def _per_shard(fn, q, k, v, *rope):
+    """Run the flash kernel under the global mesh. GSPMD cannot partition
+    a Mosaic kernel ("Mosaic kernels cannot be automatically partitioned"
+    at lowering), so on a multi-device mesh the call goes through a
+    fully-manual shard_map: batch over (dp, fsdp), heads over mp, every
+    other dim whole — an sp-sharded sequence gathers, since the kernel
+    needs all of it (context parallelism has its own ring path). An axis
+    that does not divide its dim is left out, as `spec_for_param` does
+    for parameters. cos/sin tables replicate."""
+    from ...distributed.mesh import get_mesh
+    pm = get_mesh()
+    if pm is None or pm.size == 1:
+        return fn(q, k, v, *rope)
+    from jax.sharding import PartitionSpec as P
+    from ...framework.compat import shard_map
+    mesh = pm.jax_mesh
+    batch_axes, shards = [], 1
+    for ax in ("dp", "fsdp"):
+        n = mesh.shape.get(ax, 1)
+        if n > 1 and q.shape[0] % (shards * n) == 0:
+            batch_axes.append(ax)
+            shards *= n
+    mp = mesh.shape.get("mp", 1)
+    heads = "mp" if mp > 1 and q.shape[2] % mp == 0 \
+        and k.shape[2] % mp == 0 else None
+    spec = P(tuple(batch_axes) or None, None, heads, None)
+    return shard_map(
+        fn, mesh=mesh, in_specs=(spec, spec, spec) + (P(),) * len(rope),
+        out_specs=spec, check_vma=False)(q, k, v, *rope)
 
 
 def _sdpa_ref(q, k, v, mask=None, dropout=0.0, causal=False, scale=None,
@@ -75,29 +105,29 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
     """paddle.nn.functional.flash_attention.flash_attention parity:
     inputs [batch, seqlen, num_heads, head_dim]; returns (out, softmax|None).
 
-    On TPU dispatches to the Pallas flash kernel (M7); elsewhere uses the XLA
-    reference path (XLA fuses it reasonably; the Pallas kernel wins at long
-    sequence). rope_cos/rope_sin [S, D/2] (neox): applied to q/k INSIDE the
-    Pallas kernels when available, otherwise rotated before the reference
-    path — either way rotated q/k are an implementation detail."""
+    On TPU dispatches to the Pallas flash kernel (M7) — a kernel that
+    fails to lower or run raises, there is no second path behind it;
+    other backends use the XLA reference path. rope_cos/rope_sin
+    [S, D/2] (neox): applied to q/k INSIDE the Pallas kernels on TPU,
+    otherwise rotated before the reference path — either way rotated
+    q/k are an implementation detail."""
     if _flash_available() and dropout == 0.0 and not return_softmax:
         from ...ops.pallas import flash_attention as pallas_flash
-        try:
-            bq, bk = pallas_flash.tuned_blocks(query, key, value, causal)
+        bq, bk = pallas_flash.tuned_blocks(query, key, value, causal)
 
-            def impl(q, k, v, rc=None, rs=None):
-                return pallas_flash.flash_attention_bshd(
-                    q, k, v, causal=causal, block_q=bq, block_k=bk,
-                    rope_cos=rc, rope_sin=rs)
+        def kernel(q, k, v, rc=None, rs=None):
+            return pallas_flash.flash_attention_bshd(
+                q, k, v, causal=causal, block_q=bq, block_k=bk,
+                rope_cos=rc, rope_sin=rs)
 
-            if rope_cos is None:
-                args = (query, key, value)
-            else:
-                args = (query, key, value, rope_cos, rope_sin)
-            out = apply_op("flash_attention", impl, args, {})
-            return out, None
-        except Exception:
-            pass  # fall through to reference path
+        def impl(*arrs):
+            return _per_shard(kernel, *arrs)
+
+        if rope_cos is None:
+            args = (query, key, value)
+        else:
+            args = (query, key, value, rope_cos, rope_sin)
+        return apply_op("flash_attention", impl, args, {}), None
     if rope_cos is not None:
         # non-kernel path: rotate explicitly (same math, materialized)
         from .rope import apply_rotary_pos_emb

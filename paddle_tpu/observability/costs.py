@@ -125,10 +125,10 @@ def cost_analyses_total():
 
 # -- device peaks for MFU / roofline ---------------------------------------
 # (device-kind substring, peak flops/s, peak HBM bytes/s) — bf16 MXU peaks
-# from published TPU specs; first substring match wins. CPU (and anything
-# unrecognized) gets a NOMINAL peak so MFU stays a well-defined ratio the
-# CI can bounds-check: interpret-mode numbers are coverage evidence, not
-# speed claims (same caveat as every committed serving baseline).
+# from published TPU specs; first substring match wins. A TPU whose kind
+# matches no row is an error, never a default. Off the TPU a NOMINAL pair
+# keeps MFU a well-defined ratio the CPU gates can bounds-check:
+# interpret-mode numbers are coverage evidence, not speed claims.
 _TPU_PEAKS = (
     ("v6", 918e12, 1640e9),
     ("v5p", 459e12, 2765e9),
@@ -146,7 +146,8 @@ _peak_lock = threading.Lock()
 def _resolve_peaks():
     """(peak_flops/s, peak_bytes/s) for the current backend. Env
     overrides (PADDLE_TPU_PEAK_FLOPS / PADDLE_TPU_PEAK_BYTES_PER_S) win;
-    without jax the nominal pair comes back — never an ImportError."""
+    without jax installed the nominal pair comes back. On platform
+    `tpu` a `device_kind` the table does not know raises."""
     flops = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
     bw = os.environ.get("PADDLE_TPU_PEAK_BYTES_PER_S")
     if flops and bw:
@@ -154,15 +155,21 @@ def _resolve_peaks():
     f, b = _NOMINAL_PEAK
     try:
         import jax
+    except ImportError:
+        jax = None
+    if jax is not None:
         d = jax.devices()[0]
         if d.platform == "tpu":
-            kind = getattr(d, "device_kind", "").lower()
+            kind = d.device_kind.lower()
             for sub, pf, pb in _TPU_PEAKS:
                 if sub in kind:
                     f, b = pf, pb
                     break
-    except Exception:
-        pass
+            else:
+                raise RuntimeError(
+                    f"no peak entry for TPU device_kind {d.device_kind!r}: "
+                    "add it to costs._TPU_PEAKS with its source, or set "
+                    "PADDLE_TPU_PEAK_FLOPS and PADDLE_TPU_PEAK_BYTES_PER_S")
     return (float(flops) if flops else f, float(bw) if bw else b)
 
 

@@ -74,11 +74,10 @@ def serve_host_phase_seconds():
         "serve_host_phase_seconds",
         help="host side of one serving step, split by phase: schedule "
              "(retire/admit/chunk grants/grow), build (slab/sel/work-"
-             "list assembly), dispatch (compiled-step enqueue), overlap "
-             "(token-independent host work hidden under device "
-             "execution), fetch (block on sampled tokens), commit "
-             "(accept/rewind/emission bookkeeping)",
-        labels=("phase",))     # bounded: the six phases above
+             "list assembly), dispatch (compiled-step enqueue), fetch "
+             "(block on sampled tokens), commit (accept/rewind/"
+             "emission bookkeeping)",
+        labels=("phase",))     # bounded: the five phases above
 
 
 def serve_work_segments():

@@ -6,8 +6,13 @@ attention) behind python/paddle/incubate/nn/functional
 block_multihead_attention (SURVEY.md §2.9).
 
 TPU-native shape: the KV cache lives in HBM as fixed-size blocks
-[KVH, num_blocks, block_size, D]; each sequence owns a list of block ids
-(block_tables [B, max_blocks]).
+[KVH, num_blocks, block_size, Dc]; each sequence owns a list of block ids
+(block_tables [B, max_blocks]). Dc is the head dim rounded up to the
+128-lane tile (`paged_head_dim`; the pad lanes hold zeros): Mosaic DMAs
+whole (sublane, 128) tiles, so a 64-wide row cannot be sliced out of HBM
+("Slice shape along dimension 3 must be aligned to tiling (128)"), and
+XLA lays a sub-128 minor dim out transposed, which would put a relayout
+copy of the whole cache around every kernel call.
 
 Two kernels:
 
@@ -175,6 +180,23 @@ def next_pow2(n):
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
+def paged_head_dim(head_dim):
+    """Minor dim of a paged KV cache serving `head_dim`-wide heads: the
+    next multiple of the 128-lane tile (see the module docstring)."""
+    return -(-int(head_dim) // LANES) * LANES
+
+
+def _lane_pad(x, width):
+    """Zero-pad x's minor dim up to `width` (a cache's Dc)."""
+    pad = width - x.shape[-1]
+    if pad < 0:
+        raise ValueError(
+            f"head dim {x.shape[-1]} wider than the cache rows ({width})")
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def kv_head_shard(num_kv_heads, tp, rank=None):
     """Kv-head ownership under tensor-parallel serving: the ragged
     kernel's grid is (kv_head, work item), so the natural multi-chip
@@ -324,9 +346,11 @@ class RaggedWorkBuilder:
     count ACTIVE slots only, so a steady-state decode step scores 100%
     reuse — the number `serve_bench --host` pins.
 
-    The returned arrays are views of the persistent bucket buffer: jit
-    copies committed host arguments at dispatch, so mutating them on
-    the NEXT build is safe once the previous step was dispatched."""
+    The returned arrays are views of the persistent bucket buffer. jit
+    does not copy a numpy argument at dispatch (an aligned buffer is
+    aliased on the CPU, copied asynchronously on an accelerator), so
+    the NEXT build may run only once the previous step's result has
+    been fetched — which is where the engine's step() ends."""
 
     def __init__(self, batch, max_blocks, block_size, pack,
                  bucket_to=next_pow2):
@@ -611,7 +635,11 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     q:            [B, H, D] — one query token per sequence (decode), or
                   [B, C, H, D] — a chunk of up to C query tokens per
                   sequence (chunked prefill; rows past q_lens[b] ignored)
-    k/v_cache:    [KVH, num_blocks, block_size, D]
+    k/v_cache:    [KVH, num_blocks, block_size, Dc], Dc >= D (the
+                  engine allocates Dc = paged_head_dim(D); q is
+                  zero-padded to Dc for the kernel and the output sliced
+                  back to D — zeros add nothing to q.k, and the pad
+                  lanes of p.v are dropped)
     block_tables: [B, max_blocks_per_seq] int32 cache-block ids
     context_lens: [B] int32 valid cache length per sequence INCLUDING
                   this call's query span (0 allowed: the row costs zero
@@ -646,11 +674,12 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
-    b, c, h, d = q.shape
-    kvh, _, block_size, _ = k_cache.shape
+    b, c, h, d_q = q.shape
+    kvh, _, block_size, d = k_cache.shape
     g = h // kvh
     if scale is None:
-        scale = 1.0 / math.sqrt(d)
+        scale = 1.0 / math.sqrt(d_q)
+    q = _lane_pad(q, d)
     if work is not None:
         work_arrs, t_total = work[0], work[2]
         work_pack = work[3] if len(work) > 3 else None
@@ -673,7 +702,7 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         work_arrs, _, t_total, pack = build_ragged_work(
             block_tables, context_lens, block_size, pack, q_lens=q_lens)
     if t_total == 0:
-        out = jnp.zeros_like(q)
+        out = jnp.zeros((b, c, h, d_q), q.dtype)
         return out[:, 0] if squeeze else out
     ngroups = -(-b // pack)
     pg = pack * c * g
@@ -685,8 +714,8 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         in_specs=[
             pl.BlockSpec((1, 1, pg, d),
                          lambda hh, t, ws, wg, *_: (wg[t], hh, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # K stays in HBM;
-            pl.BlockSpec(memory_space=pltpu.ANY),   # blocks DMA'd by hand
+            pl.BlockSpec(memory_space=pl.ANY),   # K/V stay in HBM;
+            pl.BlockSpec(memory_space=pl.ANY),   # blocks DMA'd by hand
         ],
         out_specs=pl.BlockSpec(
             (1, 1, pg, d), lambda hh, t, ws, wg, *_: (wg[t], hh, 0, 0)),
@@ -709,7 +738,7 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         interpret=_interpret_mode(),
     )(*[jnp.asarray(a, jnp.int32) for a in work_arrs],
       qp, k_cache, v_cache)
-    out = _unpack_outputs(out, b, c, h, g, pack)
+    out = _unpack_outputs(out, b, c, h, g, pack)[..., :d_q]
     # rows whose group was never visited (len 0 / q_len 0) carry
     # uninitialised VMEM — mask every invalid (seq, chunk-pos) row off
     if q_lens is None:
@@ -734,13 +763,14 @@ def ragged_paged_attention_reference(q, k_cache, v_cache, block_tables,
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
-    b, c, h, d = q.shape
+    b, c, h, d_q = q.shape
     kc = jnp.asarray(k_cache)
     vc = jnp.asarray(v_cache)
-    kvh, _, bs, _ = kc.shape
+    kvh, _, bs, d = kc.shape
     g = h // kvh
     if scale is None:
-        scale = 1.0 / math.sqrt(d)
+        scale = 1.0 / math.sqrt(d_q)
+    q = _lane_pad(q, d)        # same padded tiles as the kernel
     if pack is None:
         pack = default_pack(b, g)
     lens = np.asarray(context_lens)
@@ -789,7 +819,7 @@ def ragged_paged_attention_reference(q, k_cache, v_cache, block_tables,
                             int(wqs[t]), int(wql[t]))
             if wlast[t]:
                 out[wg[t], hh] = np.asarray(fin(acc, l))
-    out = _unpack_outputs(jnp.asarray(out), b, c, h, g, pack)
+    out = _unpack_outputs(jnp.asarray(out), b, c, h, g, pack)[..., :d_q]
     if q_lens is None:
         valid = jnp.asarray(lens).reshape(-1, 1) > 0
     else:
@@ -804,7 +834,8 @@ def update_paged_kv_cache(k_cache, v_cache, k_new, v_new, block_tables,
     """Append one decode step's K/V ([B, KVH, D]) into the paged cache at
     position context_lens (the slot the new token occupies). Returns the
     updated caches. Pure scatter — XLA keeps it in-place under jit when
-    the caches are donated.
+    the caches are donated. Rows narrower than the cache's Dc are
+    zero-padded (`paged_head_dim`).
 
     Boundary contract: a row whose context_lens already equals the table
     capacity (max_blocks * block_size) has nowhere to append — its write
@@ -812,6 +843,7 @@ def update_paged_kv_cache(k_cache, v_cache, k_new, v_new, block_tables,
     clamped) instead of aliasing whatever XLA's clamped gather happened
     to hand back."""
     kvh, nb, bs, d = k_cache.shape
+    k_new, v_new = _lane_pad(k_new, d), _lane_pad(v_new, d)
     b = k_new.shape[0]
     max_nb = block_tables.shape[1]
     full = context_lens >= max_nb * bs                # [B] no slot left
@@ -908,6 +940,7 @@ def update_paged_kv_cache_chunk(k_cache, v_cache, k_new, v_new,
     capacity (max_blocks * block_size) are DROPPED — never aliased onto
     whatever block a clamped gather would hand back."""
     kvh, nb, bs, d = k_cache.shape
+    k_new, v_new = _lane_pad(k_new, d), _lane_pad(v_new, d)
     b, c = k_new.shape[0], k_new.shape[1]
     max_nb = block_tables.shape[1]
     pos = context_lens.reshape(-1, 1) + jnp.arange(c)[None, :]    # [B, C]

@@ -138,8 +138,7 @@ class CustomOpModule:
             import jax.numpy as jnp
             if not any(isinstance(a, jax.core.Tracer) for a in arrays):
                 # eager: run the host kernel directly (no callback channel
-                # needed — some PJRT transports, e.g. tunneled backends,
-                # don't support host send/recv)
+                # needed)
                 outs = [jnp.asarray(o) for o in run_fwd(*arrays)]
                 return tuple(outs) if len(outs) > 1 else outs[0]
             shapes = out_shapes_fn(*[a.shape for a in arrays])

@@ -1,19 +1,14 @@
-"""framework/compat resolver coverage (ISSUE 2 satellite).
+"""framework/compat resolver coverage.
 
 resolve_shard_map and resolve_compiler_params are the two places the
-whole tree routes around jax version skew; a regression in either is a
-collection-killer (PR 1's import skew) or a Pallas-tier AttributeError.
-These tests pin the contract on whichever jax is installed:
+whole tree takes jax's moving names from. The one installation is jax
+0.9.0; these tests pin what the tree relies on there:
 
-* fully-manual shard_map calls pass through and compute correct
-  collectives (with and without the new-style axis_names kwarg);
-* partial-auto calls are REFUSED with a clear NotImplementedError on
-  legacy jax (0.4.x aborts the process otherwise) — on a jax new enough
-  to accept partial-auto natively, the refusal test asserts the native
-  path instead;
-* resolve_compiler_params returns whichever of CompilerParams /
-  TPUCompilerParams this jax ships, constructible with the shared
-  contract kwarg (vmem_limit_bytes).
+* `axis_names` is a SET (a tuple is refused by jax.shard_map);
+* fully-manual and partial-auto calls both run and compute correct
+  collectives;
+* resolve_compiler_params is `pltpu.CompilerParams`, constructible with
+  the shared contract kwarg (vmem_limit_bytes).
 """
 import numpy as np
 import jax
@@ -31,19 +26,12 @@ def _mesh(shape, names):
     return Mesh(devs, names)
 
 
-def _is_native(sm):
-    # the compat ADAPTER also takes check_vma (it's the translation shim),
-    # so signature probing can't tell the two apart — provenance can
-    return getattr(sm, "__module__", "") != "paddle_tpu.framework.compat"
-
-
 class TestResolveShardMap:
-    def test_resolves_to_callable(self):
-        sm = resolve_shard_map()
-        assert callable(sm)
+    def test_resolves_to_jax_shard_map(self):
+        assert resolve_shard_map() is jax.shard_map
 
     def test_fully_manual_passthrough(self):
-        """axis_names covering the whole mesh: runs on every jax."""
+        """axis_names covering the whole mesh."""
         sm = resolve_shard_map()
         mesh = _mesh((8,), ("dp",))
         x = jnp.arange(8.0)
@@ -64,33 +52,32 @@ class TestResolveShardMap:
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(x).sum(1, keepdims=True))
 
-    def test_partial_auto_refused_on_legacy_jax(self):
-        """Manual over `dp` only, mesh has (dp, mp): legacy jax must get a
-        clean NotImplementedError (the alternative, feeding it to 0.4.x's
-        experimental shard_map, aborts the whole process)."""
+    def test_partial_auto_runs(self):
+        """Manual over `dp` only on a (dp, mp) mesh: `mp` stays auto
+        (under jit, as every in-tree partial-auto site is)."""
         sm = resolve_shard_map()
         mesh = _mesh((4, 2), ("dp", "mp"))
-        if _is_native(sm):
-            # new jax accepts partial-auto natively; nothing to refuse
-            assert sm is getattr(jax, "shard_map", None) or callable(sm)
-            return
-        with pytest.raises(NotImplementedError, match="partial-auto"):
+        x = jnp.arange(8.0).reshape(4, 2)
+        out = jax.jit(sm(lambda v: jax.lax.psum(v, "dp"), mesh=mesh,
+                         in_specs=P("dp"), out_specs=P(),
+                         axis_names={"dp"}, check_vma=False))(x)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(x).sum(0, keepdims=True))
+
+    def test_axis_names_tuple_is_refused(self):
+        """The break that took tp>1 serving down on this jax: keep the
+        tree on sets."""
+        sm = resolve_shard_map()
+        mesh = _mesh((8,), ("dp",))
+        with pytest.raises(TypeError):
             sm(lambda v: v, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-               axis_names=frozenset({"dp"}))
-        # the message must name the manual axes, the mesh, and the way out
-        with pytest.raises(NotImplementedError,
-                           match=r"\['dp'\].*needs a newer jax"):
-            sm(lambda v: v, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-               axis_names=frozenset({"dp"}))
+               axis_names=("dp",), check_vma=False)(jnp.arange(8.0))
 
 
 class TestResolveCompilerParams:
-    def test_resolves_whichever_rename_side_exists(self):
+    def test_resolves_to_compiler_params(self):
         from jax.experimental.pallas import tpu as pltpu
-        cp = resolve_compiler_params()
-        expected = getattr(pltpu, "CompilerParams", None) \
-            or getattr(pltpu, "TPUCompilerParams")
-        assert cp is expected
+        assert resolve_compiler_params() is pltpu.CompilerParams  # graftlint: disable=GL102 - pins which class the resolver returns
 
     def test_shared_contract_constructible(self):
         obj = resolve_compiler_params()(vmem_limit_bytes=1 << 20)

@@ -1,5 +1,5 @@
 """Host-step fast path (ISSUE 20): incremental work lists, in-place
-step inputs, overlapped token fetch.
+step inputs.
 
 Four claims, all host-deterministic under CPU interpret mode:
   * the incremental RaggedWorkBuilder is BIT-EXACT vs the from-scratch
@@ -8,9 +8,9 @@ Four claims, all host-deterministic under CPU interpret mode:
   * dirty accounting is EXACT: a steady decode reuses every cached
     segment, one dirtied slot rebuilds exactly that slot's segments,
     and a missed dirty mark is CAUGHT by the debug cross-check,
-  * the fast-path and overlap engines generate token-for-token what
-    the eager engine does in every scheduler mode, with zero copied
-    step-input bytes and an identical compile-bucket set,
+  * the fast-path engine generates token-for-token what the eager
+    engine does in every scheduler mode, with zero copied step-input
+    bytes and an identical compile-bucket set,
   * nothing leaks: KV blocks return to baseline and the builder's
     buffer pool stays bounded by the bucket set it has seen.
 """
@@ -180,15 +180,13 @@ def _mode_workload(mode, V):
 
 class TestEngineTokenExactness:
     @pytest.mark.parametrize("mode", sorted(_MODE_KW))
-    def test_fast_and_overlap_match_eager(self, mode):
+    def test_fast_matches_eager(self, mode):
         eng, V = _tiny_engine()
         prompts, new = _mode_workload(mode, V)
         outs = {}
         for cfg, kw in (
                 ("eager", {"host_fastpath": False}),
-                ("fast", {"host_debug_check": True}),
-                ("overlap", {"host_debug_check": True,
-                             "overlap_fetch": True})):
+                ("fast", {"host_debug_check": True})):
             toks, cb = _serve(eng, prompts, new,
                               **_MODE_KW[mode], **kw)
             outs[cfg] = [list(t) for t in toks]
@@ -199,15 +197,12 @@ class TestEngineTokenExactness:
             else:
                 assert hs["fastpath"]
                 assert hs["input_copy_bytes"] == 0
-            if cfg == "overlap":
-                assert hs["overlap"]
             # KV leak check: every allocatable block back, either free
             # or parked in the (reclaimable) prefix pool
             assert (cb.allocator.num_free
                     + getattr(cb.allocator, "num_pooled", 0)
                     == cb.allocator.num_blocks - cb.allocator.reserved)
         assert outs["fast"] == outs["eager"]
-        assert outs["overlap"] == outs["eager"]
 
     def test_bucket_sets_identical_and_phases_reported(self):
         eng, V = _tiny_engine()
@@ -219,7 +214,7 @@ class TestEngineTokenExactness:
             seen[cfg] = set(cb._seen_buckets)
             phases = cb.host_stats()["phases"]
             assert set(phases) == {"schedule", "build", "dispatch",
-                                   "overlap", "fetch", "commit"}
+                                   "fetch", "commit"}
             rid = next(iter(cb.finished))
             assert cb.explain(rid)["host_phases"] == phases
         assert seen["fast"] == seen["eager"]

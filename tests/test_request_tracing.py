@@ -281,8 +281,7 @@ def test_render_rolls_up_serve_step_host_phases(tmp_path):
     lines = [ln for ln in text.splitlines()
              if ln.startswith("host phases over ")]
     assert len(lines) == 1
-    for phase in ("sched=", "build=", "dispatch=", "overlap=",
-                  "fetch="):
+    for phase in ("sched=", "build=", "dispatch=", "fetch="):
         assert phase in lines[0], (phase, lines[0])
 
 

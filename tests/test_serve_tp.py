@@ -293,7 +293,8 @@ class TestMeshAccounting:
     def test_kv_device_bytes_drop_by_tp(self):
         bs = 8
         single = _engine(1).kv_device_block_bytes(bs)
-        assert single == L * 2 * G * bs * D * 4
+        # the bytes the cache really stores: rows padded to the lane tile
+        assert single == L * 2 * G * bs * pa.paged_head_dim(D) * 4
         for tp in (2, 4, 8):
             assert _engine(tp).kv_device_block_bytes(bs) * tp == single
 

@@ -52,34 +52,27 @@ SERVE_PROGRAMS = ("paged_step", "paged_rewind", "paged_copy")
 
 
 def _force_virtual_devices(n=8):
-    """The dryrun_multichip pattern: must run before jax initializes."""
+    """Ask the CPU backend (JAX_PLATFORMS=cpu) for n virtual devices:
+    must run before jax initializes."""
     flag = f"--xla_force_host_platform_device_count={n}"
     if "xla_force_host_platform_device_count" not in os.environ.get(
             "XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
-    import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
 
 
 def serve_cost_leg(new_tokens=24, spec_k=4, chunk=8, block_size=8):
     """Drive the ragged serving workload with the catalog on; returns
     the per-program attribution plus the neutrality and leak gates."""
     import numpy as np
-    import jax
 
     from paddle_tpu import observability as obs
+    from paddle_tpu.framework.platform import init_platform
     from paddle_tpu.incubate.nn import (ContinuousBatchingEngine,
                                         GenerationRequest)
-    from paddle_tpu.ops.pallas import flash_attention as fa
     from tools.serve_bench import _tiny_cpu_engine
 
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not on_tpu:
-        fa._INTERPRET = True
+    on_tpu = init_platform() == "tpu"
     rng = np.random.default_rng(0)
     eng, V = _tiny_cpu_engine(rng, max_seq_len=128)
     # the PR-5 repetitive workload: prompt-lookup drafts hit often but

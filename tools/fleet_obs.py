@@ -82,17 +82,15 @@ def rank_worker(args):
         from tools.train_monitor import _force_virtual_devices
         _force_virtual_devices(2)
     import numpy as np
-    import jax
 
     from paddle_tpu import inference
     from paddle_tpu import observability as obs
+    from paddle_tpu.framework.platform import init_platform
     from paddle_tpu.incubate.nn import (ContinuousBatchingEngine,
                                         GenerationRequest)
-    from paddle_tpu.ops.pallas import flash_attention as fa
     from tools.serve_bench import _tiny_cpu_engine
 
-    if jax.devices()[0].platform != "tpu":
-        fa._INTERPRET = True
+    init_platform()
     rng = np.random.default_rng(0)      # identical workload on every rank
     eng, V = _tiny_cpu_engine(rng, max_seq_len=32)
     cb = ContinuousBatchingEngine(eng, num_blocks=12, block_size=8,
@@ -167,7 +165,7 @@ def _spawn(fleet_dir, rank, fault):
         cmd += ["--pretrain", "--train-steps", str(TRAIN_STEPS)]
     out = open(os.path.join(fleet_dir, f"worker_{rank}.log"), "w")
     return subprocess.Popen(
-        cmd, stdout=out, stderr=subprocess.STDOUT,
+        cmd, stdout=out, stderr=subprocess.STDOUT, env=env,
         cwd=os.path.join(os.path.dirname(__file__), "..")), out
 
 

@@ -19,7 +19,7 @@ Machine output:    python -m tools.graftlint --jsonl <paths>
                    python -m tools.graftlint --sarif <paths>
 Self-test corpus:  python -m tools.graftlint --selftest
 List rules:        python -m tools.graftlint --list-rules
-Suppress a line:   trailing `# graftlint: disable=GL201` (comma list; a
+Suppress a line:   trailing `# graftlint: disable=GL103` (comma list; a
                    comment anywhere on a multi-line statement's span
                    works). Suppressions are CHECKED: one no finding
                    consumes — or naming an unknown rule id — flags

@@ -124,7 +124,7 @@ def main(argv=None):
         prog="python -m tools.graftlint",
         description="framework-aware static analysis (two-phase: "
                     "project index + context colors, then trace safety, "
-                    "shard_map hygiene, Pallas bounds, repo hygiene, "
+                    "Pallas bounds, repo hygiene, "
                     "async/concurrency rules)")
     ap.add_argument("paths", nargs="*",
                     help="files/directories to lint (e.g. paddle_tpu/ "

@@ -2,12 +2,10 @@
 
 Framework-aware static analysis for this repo (stdlib `ast` only — the
 linter must import in a bare CI container, before jax, before anything).
-Three of the four original rule families encode bugs PR 1 fixed by hand:
+Two of the original rule families encode bugs PR 1 fixed by hand:
 
 * the `from jax import shard_map` import skew that silently wiped 43 of
   47 test files off the collection (trace-safety family),
-* the partial-auto `shard_map` call shape jax 0.4.x aborts the process
-  on (shard_map-hygiene family),
 * the `update_paged_kv_cache` out-of-bounds block-table write (Pallas
   bounds family).
 
@@ -27,8 +25,7 @@ suppression comment no finding consumed (stale) or naming an unknown
 rule id, so suppressions rot visibly. Findings listed in the committed
 baseline (tools/graftlint_baseline.json) are reported but don't fail
 the run — the baseline is the triage ledger for pre-existing,
-understood debt (today: the partial-auto shard_map sites that need a
-newer jax).
+understood debt (today: empty).
 """
 from __future__ import annotations
 

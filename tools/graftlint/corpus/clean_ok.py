@@ -1,5 +1,5 @@
 # graftlint-corpus-expect: none
-# graftlint-corpus-rule: GL101 GL102 GL103 GL104 GL201 GL301 GL302 GL401 GL402 GL403
+# graftlint-corpus-rule: GL101 GL102 GL103 GL104 GL301 GL302 GL401 GL402 GL403
 """False-positive tripwire: the CORRECT spellings of every pattern the
 rules hunt. If any rule fires here, it drifted into noise."""
 import os
@@ -30,7 +30,6 @@ def copy_window_clamped(src_ref, dst_ref, lens_ref, i):
 
 
 def fully_manual(fn, jm, specs):
-    # no axis_names/auto: fully-manual shard_map, safe on jax 0.4.x
     return shard_map(fn, mesh=jm, in_specs=specs, out_specs=specs)
 
 

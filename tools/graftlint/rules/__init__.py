@@ -14,14 +14,12 @@ Codes are grouped by family:
                              guard data races, lock-order cycles,
                              guarded-collection escapes)
   GL124  unvalidated-committed-json (hygiene family, tools/ included)
-  GL2xx  shard_map hygiene  (partial-auto call shapes)
   GL3xx  Pallas bounds      (unclamped dynamic indexing, tile shapes)
   GL4xx  repo hygiene       (bare except, mutable defaults, import-time env)
 """
 from . import trace_safety    # noqa: F401
 from . import mxu             # noqa: F401
 from . import donation        # noqa: F401
-from . import shard_map_hygiene  # noqa: F401
 from . import pallas_bounds   # noqa: F401
 from . import hygiene         # noqa: F401
 from . import concurrency     # noqa: F401

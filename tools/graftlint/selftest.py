@@ -26,8 +26,8 @@ from . import rules  # noqa: F401
 _EXPECT_RE = re.compile(r"#\s*graftlint-corpus-expect:\s*(.+)")
 _CLAIM_RE = re.compile(r"#\s*graftlint-corpus-rule:\s*(.+)")
 
-FAMILIES = ("trace-safety", "mxu", "donation", "shard-map",
-            "pallas-bounds", "hygiene", "concurrency", "locksets")
+FAMILIES = ("trace-safety", "mxu", "donation", "pallas-bounds",
+            "hygiene", "concurrency", "locksets")
 
 
 def corpus_expectations(path):

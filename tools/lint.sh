@@ -2,7 +2,7 @@
 # Static-analysis entry point: rule self-test corpus first (a lobotomized
 # rule must not green-light the tree scan; the selftest also fails any
 # ORPHANED corpus file no registered rule claims), then the full-tree
-# two-phase scan — all 33 rules incl. the lockset family (GL121-GL123
+# two-phase scan — all 32 rules incl. the lockset family (GL121-GL123
 # data-race/deadlock detection over per-object lock identity, GL125
 # callback-under-lock, GL126 check-then-act split across two guarded
 # regions, GL127 blocking waits under a contended lock identity) and
@@ -27,8 +27,8 @@ if [ "${LINT_SKIP_SERVE:-0}" != "1" ]; then
   python tools/serve_bench.py --check tools/serve_ragged.json
   python tools/serve_bench.py --check tools/serve_spec.json
   python tools/serve_bench.py --check tools/serve_prefix.json
-  # host fast-path gate: the incremental work-list / in-place-input /
-  # overlapped-fetch engine must stay token-exact vs the eager rebuild
+  # host fast-path gate: the incremental work-list / in-place-input
+  # engine must stay token-exact vs the eager rebuild
   # path in every scheduler mode at tp=1/2 (debug cross-check on), with
   # ZERO step-input copy bytes, 100% steady-decode segment reuse, an
   # identical compile-bucket set, and exact per-mode work counters

@@ -540,7 +540,7 @@ def selfcheck():
         shutil.rmtree(d7, ignore_errors=True)
 
     # host-step fast path (ISSUE 20): the serve_host_phase_seconds
-    # histogram's bounded six-phase label set, the work-segment /
+    # histogram's bounded five-phase label set, the work-segment /
     # assembly counter families, and the step-input copy-bytes counter
     # whose steady-state zero the serve_host gate pins — stdlib-only
     reg8 = obs.MetricsRegistry()
@@ -548,12 +548,11 @@ def selfcheck():
     hp8.labels(phase="schedule").observe(1e-3)
     hp8.labels(phase="build").observe(2e-3)
     hp8.labels(phase="dispatch").observe(3e-3)
-    hp8.labels(phase="overlap").observe(0.0)
     hp8.labels(phase="fetch").observe(4e-3)
     hp8.labels(phase="commit").observe(1e-3)
     kids8 = reg8.snapshot()["serve_host_phase_seconds"]["children"]
     check(sorted(kids8) == ["build", "commit", "dispatch", "fetch",
-                            "overlap", "schedule"]
+                            "schedule"]
           and all(c["count"] == 1 for c in kids8.values()),
           f"host-phase histogram children wrong: {sorted(kids8)}")
     segs8 = reg8.counter("serve_work_segments_total", labels=("event",))

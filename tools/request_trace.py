@@ -34,13 +34,13 @@ def _fmt_args(args):
 
 
 _HOST_PHASES = ("host_sched_us", "host_build_us", "host_dispatch_us",
-                "host_overlap_us", "host_fetch_us")
+                "host_fetch_us")
 
 
 def _render_host_phases(engine_spans, out):
     """Host-side phase split of the serve_step lane: where each step's
     wall time went around the device dispatch (scheduler admit/preempt,
-    work-list build, dispatch, overlapped host work, token fetch) —
+    work-list build, dispatch, token fetch) —
     one rollup line answering "is the host the bottleneck" without
     grepping span args. Dumps predating the args render nothing."""
     steps = [s for s in engine_spans if s["name"] == "serve_step"

@@ -197,18 +197,15 @@ def chaos_leg(config=None, flight_dir=None):
     declare_warm -> pass 3 (the steady-state gate)."""
     import tempfile
 
-    import jax
     import numpy as np
 
+    from paddle_tpu.framework.platform import init_platform
     from paddle_tpu.incubate.nn import ContinuousBatchingEngine
     from paddle_tpu.observability import tracing
-    from paddle_tpu.ops.pallas import flash_attention as fa
     from tools.serve_bench import _tiny_cpu_engine
 
     config = config or DEFAULT_CONFIG
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not on_tpu:
-        fa._INTERPRET = True
+    on_tpu = init_platform() == "tpu"
     ecfg = config["engine"]
     rng = np.random.default_rng(ecfg["seed"])
     eng, V = _tiny_cpu_engine(rng, max_seq_len=ecfg["max_seq_len"])

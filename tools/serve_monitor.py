@@ -442,19 +442,15 @@ def monitor_leg(config=None, dashboard_every=0):
     percentiles + the final SLO report."""
     import time as _time
 
-    import jax
-
     from paddle_tpu import observability as obs
+    from paddle_tpu.framework.platform import init_platform
     from paddle_tpu.incubate.nn import ContinuousBatchingEngine
-    from paddle_tpu.ops.pallas import flash_attention as fa
     from tools.serve_bench import _tiny_cpu_engine
 
     import numpy as np
 
     config = config or DEFAULT_CONFIG
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not on_tpu:
-        fa._INTERPRET = True
+    on_tpu = init_platform() == "tpu"
     ecfg = config["engine"]
     rng = np.random.default_rng(ecfg["seed"])
     eng, V = _tiny_cpu_engine(rng, max_seq_len=ecfg["max_seq_len"])

@@ -356,17 +356,14 @@ def _crash_leg(config, crefs, cprompts, tracer):
 
 
 def replica_leg(config=None):
-    import jax
     import numpy as np
 
+    from paddle_tpu.framework.platform import init_platform
     from paddle_tpu.observability import tracing
-    from paddle_tpu.ops.pallas import flash_attention as fa
     from tools.serve_bench import _tiny_cpu_engine
 
     config = config or DEFAULT_CONFIG
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not on_tpu:
-        fa._INTERPRET = True
+    on_tpu = init_platform() == "tpu"
     ecfg = config["engine"]
     wl = config["workload"]
     rng = np.random.default_rng(ecfg["seed"])

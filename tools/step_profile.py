@@ -102,11 +102,13 @@ def main():
     import jax
     import numpy as np
 
+    from paddle_tpu.framework.platform import init_platform
     from paddle_tpu.models import LlamaForCausalLM, pretrain
-    on_tpu = jax.devices()[0].platform == "tpu"
+    if init_platform() != "tpu":
+        raise SystemExit("step_profile profiles the device: needs the TPU")
     # the SAME flagship shape bench.py benchmarks — shared helper so the
     # profile always describes the headline step
-    cfg, batch, seq = pretrain.flagship_config(on_tpu)
+    cfg, batch, seq = pretrain.flagship_config()
     model = LlamaForCausalLM(cfg)
     mesh = pretrain.make_mesh(1, dp=1, fsdp=1, mp=1, sp=1)
     params, opt_state, meta = pretrain.make_train_state(model, mesh)

@@ -56,17 +56,13 @@ BATCH, SEQ, VOCAB = 8, 16, 128
 
 
 def _force_virtual_devices(n=8):
-    """The dryrun_multichip pattern: must run before jax initializes."""
+    """Ask the CPU backend (JAX_PLATFORMS=cpu) for n virtual devices:
+    must run before jax initializes."""
     flag = f"--xla_force_host_platform_device_count={n}"
     if "xla_force_host_platform_device_count" not in os.environ.get(
             "XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
-    import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
 
 
 def _fresh_run(telemetry=False, monitor=None):
