@@ -1627,11 +1627,27 @@ class ContinuousBatchingEngine:
     def step(self):
         """One scheduler tick + one compiled mixed prefill/decode step.
         Returns the number of requests still in flight (active +
-        queued)."""
+        queued).
+
+        Its host phases tile the calling thread for the profiler
+        (`tracing.PhaseMarks`): `serve.schedule`, `serve.build`,
+        `serve.dispatch <bucket>`, `serve.fetch <bucket>` (the wait for
+        the device) and `serve.commit` with `serve.telemetry` nested in
+        it. Their boundaries are the stamps the `serve_step` span,
+        `_last_host_phases` and `serve_host_phase_seconds` are fed from;
+        `serve.commit` alone runs on past the last stamp to the return,
+        so that nothing of a step lies outside them."""
+        marks = _tracing.PhaseMarks()
+        try:
+            return self._step(marks)
+        finally:
+            marks.end()
+
+    def _step(self, marks):
         import jax
 
         t_begin = time.monotonic()
-        pc_begin = time.perf_counter()
+        pc_begin = marks.mark("serve.schedule")
         tr = _tracing.get_tracer()
         self._retire()
         self._admit()
@@ -1666,7 +1682,7 @@ class ContinuousBatchingEngine:
             if self.memory_watch is not None:
                 self.memory_watch.tick()
             return len(self.queue) + self.num_active
-        pc_sched = time.perf_counter()
+        pc_sched = marks.mark("serve.build")
         # token slab [B, C]: C is the widest span this step, bucketed to
         # a power of two (1 for an all-decode step) so slab shapes — and
         # the programs they key — stay off the per-prompt-length
@@ -1686,10 +1702,12 @@ class ContinuousBatchingEngine:
         else:
             slab = np.zeros((self.max_batch, c), np.int32)
             self._count_input_bytes(slab.nbytes)
+        prefilling = False      # some slot consumes prompt this step
         for i in active:
             req = self.slots[i]
             n = int(q_lens[i])
             if req.progress < req._resume_len:
+                prefilling = prefilling or n > 0
                 slab[i, :n] = \
                     req._prefill_src[req.progress:req.progress + n]
             elif n:
@@ -1779,7 +1797,10 @@ class ContinuousBatchingEngine:
             comm_task = self._comm_tasks.start_task(
                 "psum", group="tp",
                 nbytes=self.engine.tp_step_comm_bytes(self.max_batch, c))
-        pc_step = time.perf_counter()
+        # the bucket rides the two device-facing annotations' names: at
+        # most one name per compiled program (64 on the chat cell)
+        bucket = f"w{t_total}c{c}"
+        pc_step = marks.mark("serve.dispatch " + bucket)
         # tables/lens (and the slab/sel/work buffers) go in as the
         # persistent scheduler arrays themselves. jit does NOT snapshot
         # a numpy argument at dispatch: a 64-byte-aligned buffer is
@@ -1790,13 +1811,13 @@ class ContinuousBatchingEngine:
             self.engine._w, self.caches, slab, q_arr, sel,
             self.tables, self.lens, tuple(work),
             pack, np.float32(self._temp), np.float32(self._topp), sub)
-        pc_disp = time.perf_counter()
+        pc_disp = marks.mark("serve.fetch " + bucket)
         emitted = 0
         rewinds = []    # (slot, new_end, old_end): rejected draft spans
         slot_spans = []  # (slot, request_id, span name, args) this step
         toks2 = np.asarray(toks2)      # [B, W]: a sample per sel column
         t_done = time.monotonic()
-        pc_done = time.perf_counter()
+        pc_done = marks.mark("serve.commit")
         if comm_task is not None:
             # end AFTER the host read above synced the program: the
             # collective span covers real execution, not async enqueue
@@ -1932,7 +1953,8 @@ class ContinuousBatchingEngine:
             if blocks_freed.get(i):
                 args["blocks_freed"] = blocks_freed[i]
             tr.record_span(name, pc_step * 1e6,
-                           (pc_done - pc_step) * 1e6, request=rid, **args)
+                           (pc_done - pc_step) * 1e6, request=rid,
+                           step=self._step_count, **args)
         # span BEFORE the increment: its step label must match the
         # step= the flight-recorder triggers above stamped, so a dump's
         # context cross-references the right serve_step on the timeline
@@ -1945,57 +1967,74 @@ class ContinuousBatchingEngine:
                        host_dispatch_us=int((pc_disp - pc_step) * 1e6),
                        host_fetch_us=int((pc_done - pc_disp) * 1e6))
         self._step_count += 1
-        _metrics.serve_step_seconds().observe(dur)
-        if emitted:
-            _metrics.serve_tokens_total().inc(emitted)
-            _metrics.serve_tokens_per_s().set(
-                emitted / dur if dur > 0 else 0.0)
-        # set even at 0 (a prefill-bound step emits nothing): a stale
-        # nonzero reading would overstate throughput exactly when the
-        # engine is prompt-bound
-        _metrics.serve_effective_tokens_per_step().set(emitted)
         self._maybe_shrink_chunk()
-        # host-side cadence hooks: registry sample + burn-rate pass
-        # when the monitor's cadence elapsed, a monotonic compare
-        # otherwise — AFTER the step's own metrics landed, so a
-        # breach evaluation always sees this step's samples
-        if self.monitor is not None:
-            self.monitor.tick()
-        if self.memory_watch is not None:
-            # same cadence contract: HBM/census + hbm_pressure
-            self.memory_watch.tick()
-        pc_end = time.perf_counter()
-        phases = {"schedule": pc_sched - pc_begin,
-                  "build": pc_step - pc_sched,
-                  "dispatch": pc_disp - pc_step,
-                  "fetch": pc_done - pc_disp,
-                  "commit": pc_end - pc_done}
-        self._last_host_phases = phases
-        hp = _metrics.serve_host_phase_seconds()
-        hp.labels(phase="schedule").observe(phases["schedule"])
-        hp.labels(phase="build").observe(phases["build"])
-        hp.labels(phase="dispatch").observe(phases["dispatch"])
-        hp.labels(phase="fetch").observe(phases["fetch"])
-        hp.labels(phase="commit").observe(phases["commit"])
-        wb = self._work_builder
-        if wb is not None:
-            # registry mirror of the builder's monotonic counters: inc
-            # by this step's delta so the process-wide families stay
-            # exact sums across engines
-            last = self._wb_last
-            cur = (wb.segments_reused, wb.segments_rebuilt,
-                   wb.assemblies_incremental, wb.assemblies_full)
-            segs = _metrics.serve_work_segments()
-            if cur[0] > last[0]:
-                segs.labels(event="reused").inc(cur[0] - last[0])
-            if cur[1] > last[1]:
-                segs.labels(event="rebuilt").inc(cur[1] - last[1])
-            asm = _metrics.serve_work_assemblies()
-            if cur[2] > last[2]:
-                asm.labels(mode="incremental").inc(cur[2] - last[2])
-            if cur[3] > last[3]:
-                asm.labels(mode="full").inc(cur[3] - last[3])
-            self._wb_last = cur
+        # what step() pays for its own instrumentation (ROADMAP D7):
+        # histogram observes, gauges, the monitor's and the memory
+        # watch's ticks, the work-builder mirror
+        with _tracing.annotation("serve.telemetry"):
+            _metrics.serve_step_seconds().observe(dur)
+            # decode against chunk steps, which the tail of the gap
+            # between tokens follows: dispatch to tokens on the host
+            if c <= 1 + self.spec_k and not prefilling:
+                kind = "decode"
+            else:
+                kind = "chunk"
+                # how full the padded [max_batch, c] slab ran
+                slab_tokens = _metrics.serve_slab_tokens()
+                slab_tokens.labels(kind="live").inc(int(q_lens.sum()))
+                slab_tokens.labels(kind="capacity").inc(
+                    self.max_batch * c)
+            _metrics.serve_step_kind_seconds().labels(kind=kind).observe(
+                pc_done - pc_step)
+            if emitted:
+                _metrics.serve_tokens_total().inc(emitted)
+                _metrics.serve_tokens_per_s().set(
+                    emitted / dur if dur > 0 else 0.0)
+            # set even at 0 (a prefill-bound step emits nothing): a stale
+            # nonzero reading would overstate throughput exactly when the
+            # engine is prompt-bound
+            _metrics.serve_effective_tokens_per_step().set(emitted)
+            # host-side cadence hooks: registry sample + burn-rate pass
+            # when the monitor's cadence elapsed, a monotonic compare
+            # otherwise — AFTER the step's own metrics landed, so a
+            # breach evaluation always sees this step's samples
+            if self.monitor is not None:
+                self.monitor.tick()
+            if self.memory_watch is not None:
+                # same cadence contract: HBM/census + hbm_pressure
+                self.memory_watch.tick()
+            pc_end = time.perf_counter()
+            phases = {"schedule": pc_sched - pc_begin,
+                      "build": pc_step - pc_sched,
+                      "dispatch": pc_disp - pc_step,
+                      "fetch": pc_done - pc_disp,
+                      "commit": pc_end - pc_done}
+            self._last_host_phases = phases
+            hp = _metrics.serve_host_phase_seconds()
+            hp.labels(phase="schedule").observe(phases["schedule"])
+            hp.labels(phase="build").observe(phases["build"])
+            hp.labels(phase="dispatch").observe(phases["dispatch"])
+            hp.labels(phase="fetch").observe(phases["fetch"])
+            hp.labels(phase="commit").observe(phases["commit"])
+            wb = self._work_builder
+            if wb is not None:
+                # registry mirror of the builder's monotonic counters: inc
+                # by this step's delta so the process-wide families stay
+                # exact sums across engines
+                last = self._wb_last
+                cur = (wb.segments_reused, wb.segments_rebuilt,
+                       wb.assemblies_incremental, wb.assemblies_full)
+                segs = _metrics.serve_work_segments()
+                if cur[0] > last[0]:
+                    segs.labels(event="reused").inc(cur[0] - last[0])
+                if cur[1] > last[1]:
+                    segs.labels(event="rebuilt").inc(cur[1] - last[1])
+                asm = _metrics.serve_work_assemblies()
+                if cur[2] > last[2]:
+                    asm.labels(mode="incremental").inc(cur[2] - last[2])
+                if cur[3] > last[3]:
+                    asm.labels(mode="full").inc(cur[3] - last[3])
+                self._wb_last = cur
         return len(self.queue) + self.num_active
 
     def _rewind_blocks(self, i, new_end):

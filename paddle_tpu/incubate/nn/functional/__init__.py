@@ -667,29 +667,39 @@ def fused_multi_transformer(
                 from ....ops.pallas.paged_attention import (
                     ragged_paged_attention, update_paged_kv_cache,
                     update_paged_kv_cache_chunk)
+                # named for the device trace: `kv_write` is everything
+                # the append costs — the K and V halves sliced out of
+                # the layer's cache, the scatter, the halves stacked
+                # back — and `attention` the ragged kernel
                 cache = caches[li]             # [2, KVH, NB, BS, D]
                 ln = jnp.asarray(slens).reshape(-1)
                 if qlens is None:
-                    kc, vc = update_paged_kv_cache(
-                        cache[0], cache[1], k[:, 0], v[:, 0], tables_a,
-                        ln)
-                    ctx = ragged_paged_attention(
-                        q[:, 0], kc, vc, tables_a, ln + 1, scale=scale,
-                        work=(tuple(rwork), None, rwork[0].shape[0],
-                              ragged_pack),
-                        buffer_depth=kv_buffer_depth)
-                    ctx = ctx[:, None].astype(xa.dtype)   # [B, 1, H, D]
+                    with jax.named_scope("kv_write"):
+                        kc, vc = update_paged_kv_cache(
+                            cache[0], cache[1], k[:, 0], v[:, 0],
+                            tables_a, ln)
+                    with jax.named_scope("attention"):
+                        ctx = ragged_paged_attention(
+                            q[:, 0], kc, vc, tables_a, ln + 1,
+                            scale=scale,
+                            work=(tuple(rwork), None, rwork[0].shape[0],
+                                  ragged_pack),
+                            buffer_depth=kv_buffer_depth)
+                        ctx = ctx[:, None].astype(xa.dtype)  # [B,1,H,D]
                 else:
                     ql = jnp.asarray(qlens).reshape(-1)
-                    kc, vc = update_paged_kv_cache_chunk(
-                        cache[0], cache[1], k, v, tables_a, ln, ql)
-                    ctx = ragged_paged_attention(
-                        q, kc, vc, tables_a, ln + ql, scale=scale,
-                        work=(tuple(rwork), None, rwork[0].shape[0],
-                              ragged_pack), q_lens=ql,
-                        buffer_depth=kv_buffer_depth
-                        ).astype(xa.dtype)                # [B, C, H, D]
-                new_caches.append(jnp.stack([kc, vc]))
+                    with jax.named_scope("kv_write"):
+                        kc, vc = update_paged_kv_cache_chunk(
+                            cache[0], cache[1], k, v, tables_a, ln, ql)
+                    with jax.named_scope("attention"):
+                        ctx = ragged_paged_attention(
+                            q, kc, vc, tables_a, ln + ql, scale=scale,
+                            work=(tuple(rwork), None, rwork[0].shape[0],
+                                  ragged_pack), q_lens=ql,
+                            buffer_depth=kv_buffer_depth
+                            ).astype(xa.dtype)            # [B, C, H, D]
+                with jax.named_scope("kv_write"):
+                    new_caches.append(jnp.stack([kc, vc]))
             elif tstep is not None and caches:
                 # decode: append the new token, attend over the valid cache
                 cache = caches[li]                 # [2, B, g, S_max, D]
@@ -786,35 +796,37 @@ def fused_multi_transformer(
             h = resid * residual_alpha + attn
             if not pre_layer_norm:
                 h = norm(h, lns[li], lnb[li] if lnb else None)
-            resid2 = h
-            z2 = norm(h, flns[li], flnb[li] if flnb else None) \
-                if pre_layer_norm else h
-            if _mm is not None:
-                f1 = _mm(z2.reshape(b * s, -1), f1w[li], "f1",
-                         li).reshape(b, s, -1)
-            else:
-                f1 = z2 @ dq(f1w[li], "f1", li)
-            if f1b and f1b[li] is not None:
-                f1 = f1 + f1b[li]
-            if activation.endswith("glu"):
-                a, g = jnp.split(f1, 2, axis=-1)
-                act = jax.nn.silu if activation == "swiglu" else jax.nn.gelu
-                f1 = act(a) * g
-            elif activation == "relu":
-                f1 = jax.nn.relu(f1)
-            else:
-                f1 = jax.nn.gelu(f1)
-            if _mm is not None:
-                f2 = _mm(f1.reshape(b * s, -1), f2w[li], "f2",
-                         li).reshape(b, s, -1)
-            else:
-                f2 = f1 @ dq(f2w[li], "f2", li)
-            f2 = tp_red(f2)
-            if f2b and f2b[li] is not None:
-                f2 = f2 + f2b[li]
-            h = resid2 * residual_alpha + f2
-            if not pre_layer_norm:
-                h = norm(h, flns[li], flnb[li] if flnb else None)
+            with jax.named_scope("ffn"):
+                resid2 = h
+                z2 = norm(h, flns[li], flnb[li] if flnb else None) \
+                    if pre_layer_norm else h
+                if _mm is not None:
+                    f1 = _mm(z2.reshape(b * s, -1), f1w[li], "f1",
+                             li).reshape(b, s, -1)
+                else:
+                    f1 = z2 @ dq(f1w[li], "f1", li)
+                if f1b and f1b[li] is not None:
+                    f1 = f1 + f1b[li]
+                if activation.endswith("glu"):
+                    a, g = jnp.split(f1, 2, axis=-1)
+                    act = jax.nn.silu if activation == "swiglu" \
+                        else jax.nn.gelu
+                    f1 = act(a) * g
+                elif activation == "relu":
+                    f1 = jax.nn.relu(f1)
+                else:
+                    f1 = jax.nn.gelu(f1)
+                if _mm is not None:
+                    f2 = _mm(f1.reshape(b * s, -1), f2w[li], "f2",
+                             li).reshape(b, s, -1)
+                else:
+                    f2 = f1 @ dq(f2w[li], "f2", li)
+                f2 = tp_red(f2)
+                if f2b and f2b[li] is not None:
+                    f2 = f2 + f2b[li]
+                h = resid2 * residual_alpha + f2
+                if not pre_layer_norm:
+                    h = norm(h, flns[li], flnb[li] if flnb else None)
         return tuple([h] + new_caches)
 
     out = apply_op(

@@ -767,10 +767,13 @@ class FusedMultiTransformerEngine:
                 rotary_embs=w.get("rotary_embs"),
                 block_tables=tables, ragged_work=rwork,
                 ragged_pack=rpack, **paged_kw)
-            bidx = jnp.arange(out.data.shape[0])
-            picked = out.data[bidx[:, None], sel]        # [B, W, E]
-            logits = picked @ w["lm_head"]               # [B, W, V]
-            return select(logits, temp, topp, key), [c.data for c in cts]
+            with jax.named_scope("head"):
+                bidx = jnp.arange(out.data.shape[0])
+                picked = out.data[bidx[:, None], sel]    # [B, W, E]
+                logits = picked @ w["lm_head"]           # [B, W, V]
+            with jax.named_scope("sampler"):
+                toks_out = select(logits, temp, topp, key)
+            return toks_out, [c.data for c in cts]
 
         def paged_copy(caches, src_block, dst_block):
             """Duplicate one physical cache block across every layer in

@@ -28,6 +28,7 @@ __all__ = [
     "serve_preemptions", "serve_cancelled", "serve_shed",
     "serve_deadline_exceeded", "serve_failed", "serve_rejected",
     "gateway_request_seconds", "gateway_stream_seconds",
+    "gateway_handoff_seconds", "gateway_emit_to_wire_seconds",
     "gateway_responses", "gateway_live_connections",
     "gateway_live_streams", "gateway_sse_pending_events",
     "gateway_sse_events", "gateway_health_transitions",
@@ -38,7 +39,8 @@ __all__ = [
     "train_tokens_per_s", "train_host_seconds",
     "autotune_trials", "autotune_cache_hits", "autotune_cache_misses",
     "autotune_winner",
-    "serve_host_phase_seconds", "serve_work_segments",
+    "serve_host_phase_seconds", "serve_step_kind_seconds",
+    "serve_slab_tokens", "serve_work_segments",
     "serve_work_assemblies", "serve_input_copy_bytes",
 ]
 
@@ -78,6 +80,25 @@ def serve_host_phase_seconds():
              "(block on sampled tokens), commit (accept/rewind/"
              "emission bookkeeping)",
         labels=("phase",))     # bounded: the five phases above
+
+
+def serve_step_kind_seconds():
+    return get_registry().histogram(
+        "serve_step_kind_seconds",
+        help="dispatch of the compiled step to its tokens on the host "
+             "(the step as the synchronous scheduler waits for it), by "
+             "kind: decode (slab no wider than 1 + spec_k and no slot "
+             "prefilling) vs chunk (everything else)",
+        labels=("kind",))      # bounded: decode | chunk
+
+
+def serve_slab_tokens():
+    return get_registry().counter(
+        "serve_slab_tokens_total",
+        help="token slab of the chunk steps: live (sum of the slots' "
+             "q_lens) vs capacity (max_batch x slab width) — live over "
+             "capacity is how full the padded slab ran",
+        labels=("kind",))      # bounded: live | capacity
 
 
 def serve_work_segments():
@@ -281,6 +302,21 @@ def gateway_stream_seconds():
         "gateway_stream_seconds",
         help="SSE stream lifetime: headers sent -> terminal event "
              "flushed (or client gone)")
+
+
+def gateway_handoff_seconds():
+    return get_registry().histogram(
+        "gateway_handoff_seconds",
+        help="stepper.submit() called with a validated request -> "
+             "engine.submit() ran on the stepper thread: the wait for "
+             "the step in flight, per request")
+
+
+def gateway_emit_to_wire_seconds():
+    return get_registry().histogram(
+        "gateway_emit_to_wire_seconds",
+        help="token event emitted on the stepper thread -> its SSE "
+             "frame drained on the loop thread, per token event")
 
 
 def gateway_responses():

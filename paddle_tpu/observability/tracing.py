@@ -34,14 +34,19 @@ stdlib-only at import, lock-protected):
   as per-request timelines; ``tools/metrics_snapshot.py --selfcheck``
   validates the schema stdlib-only.
 
-Span timebase is ``time.perf_counter()`` microseconds — the same clock
-the profiler stamps host ranges and the metrics timeline with, so all
-three streams land on one chrome timeline without skew.
+Span timebase is ``time.perf_counter()`` microseconds — the clock the
+repo's own ``paddle_tpu.profiler`` stamps host ranges and the metrics
+timeline with, so those three streams land on one chrome timeline
+without skew. It is NOT the clock of the jax profiler's device trace
+(the xplane): what must be laid against the device's ``XLA Ops`` goes
+through ``annotation()`` / ``PhaseMarks`` below, which put the interval
+into that trace as a host event on its own clock.
 """
 import collections
 import contextlib
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -50,7 +55,8 @@ from .metrics import _host_float, get_registry
 
 __all__ = [
     "SpanRecorder", "FlightRecorder", "get_tracer", "get_flight_recorder",
-    "span", "event", "chrome_span_events", "request_summary",
+    "span", "event", "annotation", "PhaseMarks",
+    "chrome_span_events", "request_summary",
     "requests_seen", "load_dump", "write_dump", "arm_default",
     "load_manifest", "operator_abort_dump", "run_with_abort_evidence",
     "DUMP_SCHEMA", "MANIFEST_SCHEMA", "MANIFEST_NAME",
@@ -180,6 +186,59 @@ def event(name, request=None, **args):
     _tracer.event(name, request=request, **args)
 
 
+# -- the profiler's clock ----------------------------------------------------
+# The one seam between host code and the jax profiler. Host-side only,
+# like span(): never inside a jitted function (GL105) — names on the
+# device are `jax.named_scope` and a kernel's `name=`.
+
+_NO_ANNOTATION = contextlib.nullcontext()
+_trace_annotation = None    # jax.profiler.TraceAnnotation, once jax is here
+
+
+def annotation(name):
+    """`with tracing.annotation("serve.schedule"):` — the interval as a
+    host event of the jax profiler's trace, on the calling thread's
+    line, in the same xplane and on the same clock as the device's
+    `XLA Ops`. While no profiler session records (always, outside a
+    traced run) this is one flag test and a shared no-op: no object is
+    made. jax is looked up, never imported: a process that has not
+    imported it cannot have a session."""
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        if "jax" not in sys.modules:
+            return _NO_ANNOTATION
+        from jax.profiler import TraceAnnotation as cls
+        _trace_annotation = cls
+    if not cls.is_enabled():
+        return _NO_ANNOTATION
+    return cls(name)
+
+
+class PhaseMarks:
+    """Back-to-back annotations that tile one thread's time. `mark(name)`
+    closes the open annotation, reads `time.perf_counter()`, opens
+    `name`, and returns the reading: the host's own records (span args,
+    registry) and the profiler's events share their boundaries instead
+    of keeping clocks side by side. `end()` closes the last one."""
+
+    __slots__ = ("_open",)
+
+    def __init__(self):
+        self._open = _NO_ANNOTATION
+
+    def mark(self, name):
+        self._open.__exit__(None, None, None)
+        t = time.perf_counter()
+        self._open = annotation(name)
+        self._open.__enter__()
+        return t
+
+    def end(self):
+        self._open.__exit__(None, None, None)
+        self._open = _NO_ANNOTATION
+
+
 # -- chrome export ---------------------------------------------------------
 
 def chrome_span_events(recorder=None, pid=None, since_us=None,
@@ -243,9 +302,11 @@ def requests_seen(recorder=None, limit=None):
 
 def request_summary(request, spans=None, recorder=None):
     """`request.explain()`-style digest of one request's lifecycle from
-    its spans: queue wait, TTFT, chunk grants (granted vs requested),
-    stalls, decode/spec accounting, effective TPOT. Works on live rings
-    and on flight-recorder dumps (pass the dump's `spans` list)."""
+    its spans: the gateway's two hand-offs (`handoff_s` in, the first
+    token's `first_byte_s` out), queue wait, TTFT, chunk grants (granted
+    vs requested), stalls, decode/spec accounting, effective TPOT. Works
+    on live rings and on flight-recorder dumps (pass the dump's `spans`
+    list)."""
     if spans is None:
         spans = (recorder if recorder is not None
                  else get_tracer()).spans(request=request)
@@ -254,8 +315,10 @@ def request_summary(request, spans=None, recorder=None):
     out = {
         "request": request,
         "spans": len(spans),
+        "handoff_s": None,
         "queue_wait_s": None,
         "ttft_s": None,
+        "first_byte_s": None,
         "tpot_s": None,
         "prefill_chunks": [],
         "prompt_tokens": None,
@@ -277,8 +340,17 @@ def request_summary(request, spans=None, recorder=None):
         name, args = s["name"], s.get("args") or {}
         if name == "submit":
             out["prompt_tokens"] = args.get("prompt_tokens")
+        elif name == "handoff":
+            # the wait for the step in flight: stepper.submit() called
+            # -> engine.submit() ran between two steps
+            out["handoff_s"] = s["dur_us"] / 1e6
         elif name == "queue_wait":
             out["queue_wait_s"] = s["dur_us"] / 1e6
+        elif name == "emit_to_wire":
+            # the way back, recorded for a request's FIRST token event:
+            # emitted on the stepper thread -> its SSE frame drained on
+            # the loop thread
+            out["first_byte_s"] = s["dur_us"] / 1e6
         elif name == "prefill_chunk":
             out["prefill_chunks"].append(
                 {"granted": args.get("granted"),

@@ -209,6 +209,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, rope_cos=None,
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ] + ([pltpu.VMEM((block_q, d), q.dtype)] if rope else []),
+        name="flash_attn_fwd",
         interpret=_interpret_mode(),
         compiler_params=_cparams(),
     )(*operands)
@@ -475,6 +476,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ] + ([pltpu.VMEM((block_k, d), k.dtype)] if rope else []),
+        name="flash_attn_bwd_fused",
         interpret=_interpret_mode(),
         compiler_params=_cparams(),
     )(*operands)
@@ -516,6 +518,7 @@ def _flash_bwd_twopass(q, k, v, o, lse, do, scale, causal, block_q,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_attn_bwd_dq",
         interpret=_interpret_mode(),
         compiler_params=_cparams(),
     )(q, k, v, o, do, lse)
@@ -544,6 +547,7 @@ def _flash_bwd_twopass(q, k, v, o, lse, do, scale, causal, block_q,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="flash_attn_bwd_dkv",
         interpret=_interpret_mode(),
         compiler_params=_cparams(),
     )(q, k, v, o, do, lse)

@@ -47,7 +47,14 @@ Gateway telemetry (all label values from small FIXED literal sets —
 the GL112 contract): per-route request/stream duration histograms,
 per-(route, code) response counters, live-connection / live-stream /
 SSE-backpressure gauges, per-type SSE event counters, and /healthz
-state-transition counters.
+state-transition counters. The two waits a request meets between this
+loop and the stepper thread are on the ring and in the registry: the
+`handoff` span (stepper.py) on the way in, and on the way out a
+`gateway_emit_to_wire_seconds` observation per token event (and an
+`emit_to_wire` span for the request's first), from a stamp that rides
+BESIDE the event through the bridge's queue (the SSE payload is
+untouched); `gateway.sse_write` marks the loop thread's writes for the
+profiler.
 """
 import asyncio
 import json
@@ -486,10 +493,13 @@ class ServingGateway:
 
         def bridge(ev):
             # stepper thread -> asyncio loop; the registry is lock-
-            # protected, so the backpressure gauge moves from here
+            # protected, so the backpressure gauge moves from here. The
+            # stamp rides BESIDE the event (never in it: the dict is the
+            # SSE payload): the stepper emitted it now
             pending.inc()
             try:
-                loop.call_soon_threadsafe(q.put_nowait, ev)
+                loop.call_soon_threadsafe(
+                    q.put_nowait, (ev, time.perf_counter()))
             except RuntimeError:
                 pending.dec()   # loop shut down mid-stream
 
@@ -502,19 +512,20 @@ class ServingGateway:
                                      "reason": str(e)})
 
         async def next_event():
-            ev = await q.get()
+            """(event, perf_counter stamp of its emission)."""
+            item = await q.get()
             pending.dec()
-            return ev
+            return item
 
         if status == "rejected":
-            ev = await next_event()     # the structured `end` record
+            ev, _ = await next_event()  # the structured `end` record
             return await self._respond(
                 writer, route, STATUS_HTTP["rejected"],
                 {"request": rid, "status": "rejected",
                  "reason": ev.get("reason"), "tokens": []})
         if not spec["stream"]:
             while True:
-                ev = await next_event()
+                ev, _ = await next_event()
                 if ev["type"] == "end":
                     break
             return await self._respond(
@@ -552,11 +563,27 @@ class ServingGateway:
             writer.write(sse.format_event("accepted", {"request": rid}))
             await writer.drain()
             _metrics.gateway_sse_events().labels(event="accepted").inc()
+            first_token = True
             while True:
-                ev = await next_event()
+                ev, t_emit = await next_event()
                 etype = ev.pop("type")
-                writer.write(sse.format_event(etype, ev))
-                await writer.drain()
+                # the loop thread's share of the interpreter lock, to
+                # lay against serve.* on the stepper thread
+                with _tracing.annotation("gateway.sse_write"):
+                    writer.write(sse.format_event(etype, ev))
+                    await writer.drain()
+                if etype == "token":
+                    # the hand-off out: emitted on the stepper thread
+                    # -> drained to the socket here. Every token event
+                    # is observed; the ring keeps the request's first
+                    # (request_summary's first_byte_s)
+                    took = time.perf_counter() - t_emit
+                    _metrics.gateway_emit_to_wire_seconds().observe(took)
+                    if first_token:
+                        first_token = False
+                        _tracing.get_tracer().record_span(
+                            "emit_to_wire", t_emit * 1e6, took * 1e6,
+                            request=rid)
                 _metrics.gateway_sse_events().labels(event=etype).inc()
                 if etype == "end":
                     return "closed"
@@ -587,7 +614,8 @@ class ServingGateway:
         terminal; the timeout is a backstop against a dead stepper."""
         try:
             while True:
-                ev = await asyncio.wait_for(next_event(), timeout=60.0)
+                ev, _ = await asyncio.wait_for(next_event(),
+                                               timeout=60.0)
                 if ev["type"] == "end":
                     return
         except asyncio.TimeoutError:

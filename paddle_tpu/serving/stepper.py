@@ -25,12 +25,24 @@ gets a structured ``end`` event (status ``failed``, reason
 every later command future fails with it. Silence is the one
 forbidden outcome.
 
+The loop's own states tile this thread for the profiler
+(``tracing.annotation``: in a traced run only, the ring holds no copy):
+``stepper.idle`` (parked: no command, no request in flight),
+``stepper.commands`` (submits, cancels and control-plane calls between
+steps), then ``engine.step()``'s ``serve.*`` phases — so an idle gap of
+the device falls under exactly one of them. A submit's wait for the step
+in flight is the ``handoff`` span and ``gateway_handoff_seconds``.
+
 stdlib-only at import (threading + collections); the engine itself is
 constructed by the caller, jax and all.
 """
 import collections
 import concurrent.futures
 import threading
+import time
+
+from ..observability import instrument as _metrics
+from ..observability import tracing as _tracing
 
 __all__ = ["EngineStepper"]
 
@@ -115,7 +127,8 @@ class EngineStepper:
         callable taking one dict) subscribes to the request's token /
         terminal fanout — registered BEFORE submit runs, so even a
         structured rejection delivers its ``end`` event."""
-        return self._command(("submit", request, on_event))
+        return self._command(("submit", request, on_event,
+                              time.perf_counter()))
 
     def cancel(self, request_id):
         """Queue a cancel; future resolves with engine.cancel()'s
@@ -179,8 +192,14 @@ class EngineStepper:
         try:
             kind = cmd[0]
             if kind == "submit":
-                _, request, on_event = cmd
+                _, request, on_event, t_called = cmd
                 rid = request.request_id
+                # the hand-off in: how long the submit waited for the
+                # step in flight (commands run only between steps)
+                waited = time.perf_counter() - t_called
+                _tracing.get_tracer().record_span(
+                    "handoff", t_called * 1e6, waited * 1e6, request=rid)
+                _metrics.gateway_handoff_seconds().observe(waited)
                 if on_event is not None:
                     if rid in self._subs:
                         # refuse up front: overwriting would orphan the
@@ -208,20 +227,28 @@ class EngineStepper:
             if not fut.done():
                 fut.set_exception(e)
 
+    def _parked(self):
+        """Nothing to do (under `_cond`): no command, not stopping, and
+        held or no request queued or in flight."""
+        return (not self._commands and not self._stopping
+                and (self._hold or not (self.engine.queue
+                                        or self.engine.num_active)))
+
     def _run(self):
         while True:
             with self._cond:
-                while (not self._commands and not self._stopping
-                       and (self._hold
-                            or not (self.engine.queue
-                                    or self.engine.num_active))):
-                    self._cond.wait()
+                if self._parked():
+                    with _tracing.annotation("stepper.idle"):
+                        while self._parked():
+                            self._cond.wait()
                 cmds = list(self._commands)
                 self._commands.clear()
                 stopping = self._stopping
                 held = self._hold
-            for cmd, fut in cmds:
-                self._execute(cmd, fut)
+            if cmds:
+                with _tracing.annotation("stepper.commands"):
+                    for cmd, fut in cmds:
+                        self._execute(cmd, fut)
             if stopping:
                 return
             if held:

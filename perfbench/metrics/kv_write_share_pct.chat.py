@@ -1,0 +1,23 @@
+"""Share of the device's busy time IN DECODE STEPS spent in ops under
+the scope `kv_write`: the cache's halves sliced out, the append, the
+halves stacked back. The scope is in the op's metadata, which
+lib/xplane.py reads from the trace file itself; a decode step is one the
+stepper thread dispatched with a slab one column wide (`serve.dispatch
+w..c1`: chat decodes without drafts), and its ops are those that start
+between that dispatch and the end of its `serve.fetch`. Over all steps
+the share would follow the slice's mix of chunk and decode steps (the
+copies cost the same in both, the rest of a chunk step five times more),
+not the cache writer."""
+import annotations
+import xplane
+
+DECODE_SLAB = 1
+
+
+def read(ctx):
+    decode = [(a, b) for a, b, slab in annotations.step_windows(ctx["trace"])
+              if slab == DECODE_SLAB]
+    if not decode:
+        return None
+    return xplane.scope_share_pct(ctx["trace_dir"], "/kv_write/",
+                                  within=decode)
