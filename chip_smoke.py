@@ -174,6 +174,7 @@ def kernels_leg(k):
         return jnp.asarray(c, dt)
 
     kc, vc = cache(), cache()
+    kv = jnp.stack([kc, vc])    # one layer's cache as the engine holds it
     tables = (1 + np.arange(B * max_nb, dtype=np.int32)).reshape(B, max_nb)
 
     # ragged paged attention: decode rows, then a chunked-prefill slab
@@ -181,7 +182,7 @@ def kernels_leg(k):
     q = jnp.asarray(rng.standard_normal((B, H, D)), dt)
     ref = pa.ragged_paged_attention_reference(q, kc, vc, tables, lens)
     for depth in (1, 2):
-        out = pa.ragged_paged_attention(q, kc, vc, tables, lens,
+        out = pa.ragged_paged_attention(q, kv, tables, lens,
                                         buffer_depth=depth)
         _close(f"ragged decode depth={depth}", out, ref, tol)
         check(not np.asarray(out, np.float32)[lens == 0].any(),
@@ -193,11 +194,12 @@ def kernels_leg(k):
     ref = pa.ragged_paged_attention_reference(qc, kc, vc, tables, ctx,
                                               q_lens=qlens)
     for depth in (1, 2):
-        out = pa.ragged_paged_attention(qc, kc, vc, tables, ctx,
+        out = pa.ragged_paged_attention(qc, kv, tables, ctx,
                                         q_lens=qlens, buffer_depth=depth)
         _close(f"ragged chunk C={C} depth={depth}", out, ref, tol)
 
-    # the paged cache writers, against numpy (pure data movement: exact)
+    # the paged cache writers on the stacked cache, as the engine's
+    # programs call them, against numpy (pure data movement: exact)
     cap = max_nb * BS
     kn, vn = np.asarray(kc, np.float32), np.asarray(vc, np.float32)
     lens_w = np.asarray(k["decode_lens"], np.int32)
@@ -205,8 +207,8 @@ def kernels_leg(k):
     k1 = rng.standard_normal((B, KVH, D)).astype(np.float32)
     v1 = rng.standard_normal((B, KVH, D)).astype(np.float32)
     k1, v1 = (np.asarray(jnp.asarray(a, dt), np.float32) for a in (k1, v1))
-    got_k, got_v = pa.update_paged_kv_cache(
-        kc, vc, jnp.asarray(k1, dt), jnp.asarray(v1, dt),
+    got_k, got_v = pa.append_paged_kv(
+        kv, jnp.asarray(k1, dt), jnp.asarray(v1, dt),
         jnp.asarray(tables), jnp.asarray(lens_w))
     want_k, want_v = kn.copy(), vn.copy()
     for b in range(B):
@@ -216,11 +218,11 @@ def kernels_leg(k):
             want_v[:, tables[b, p // BS], p % BS, :D] = v1[b]
     check(np.array_equal(np.asarray(got_k, np.float32), want_k)
           and np.array_equal(np.asarray(got_v, np.float32), want_v),
-          "update_paged_kv_cache differs from numpy")
+          "append_paged_kv differs from numpy")
     kchunk = rng.standard_normal((B, C, KVH, D)).astype(np.float32)
     kchunk = np.asarray(jnp.asarray(kchunk, dt), np.float32)
-    got_k, got_v = pa.update_paged_kv_cache_chunk(
-        kc, vc, jnp.asarray(kchunk, dt), jnp.asarray(kchunk, dt),
+    got_k, got_v = pa.append_paged_kv_chunk(
+        kv, jnp.asarray(kchunk, dt), jnp.asarray(kchunk, dt),
         jnp.asarray(tables), jnp.asarray(lens_w), jnp.asarray(qlens))
     want_k = kn.copy()
     for b in range(B):
@@ -229,26 +231,25 @@ def kernels_leg(k):
             if p < cap:
                 want_k[:, tables[b, p // BS], p % BS, :D] = kchunk[b, j]
     check(np.array_equal(np.asarray(got_k, np.float32), want_k),
-          "update_paged_kv_cache_chunk differs from numpy")
+          "append_paged_kv_chunk differs from numpy")
     span = 8
     new_l = np.maximum(lens_w - rng.integers(0, span + 1, B), 0) \
         .astype(np.int32)
-    got_k, _ = pa.truncate_paged_kv_cache(
-        kc, vc, jnp.asarray(tables), jnp.asarray(new_l),
+    got_k, _ = pa.truncate_paged_kv(
+        kv, jnp.asarray(tables), jnp.asarray(new_l),
         jnp.asarray(lens_w), span)
     want_k = kn.copy()
     for b in range(B):
         for p in range(int(new_l[b]), min(int(lens_w[b]), cap)):
             want_k[:, tables[b, p // BS], p % BS] = 0
     check(np.array_equal(np.asarray(got_k, np.float32), want_k),
-          "truncate_paged_kv_cache differs from numpy")
-    got_k, got_v = pa.copy_paged_kv_block(kc, vc, jnp.int32(3),
-                                          jnp.int32(NB - 1))
+          "truncate_paged_kv differs from numpy")
+    got_k, got_v = pa.copy_paged_kv(kv, jnp.int32(3), jnp.int32(NB - 1))
     want_k, want_v = kn.copy(), vn.copy()
     want_k[:, NB - 1], want_v[:, NB - 1] = kn[:, 3], vn[:, 3]
     check(np.array_equal(np.asarray(got_k, np.float32), want_k)
           and np.array_equal(np.asarray(got_v, np.float32), want_v),
-          "copy_paged_kv_block differs from numpy")
+          "copy_paged_kv differs from numpy")
     print("  paged cache writers: equal to numpy", flush=True)
 
     # flash forward + fused backward, against _sdpa_ref

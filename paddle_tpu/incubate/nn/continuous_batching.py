@@ -22,7 +22,7 @@ intra-chunk causal mask makes position j's sample exactly the
 sequential decode's choice), and the host accepts the longest matching
 prefix — token-exact vs non-speculative greedy decoding by
 construction. Rejected suffixes roll back through a paged-KV rewind
-(host block free + `truncate_paged_kv_cache` zeroing), so the cache
+(host block free + `truncate_paged_kv` zeroing), so the cache
 stays bit-identical to a never-speculated one.
 
 Host/device split: the allocator, block tables, lengths, and scheduling
@@ -2042,7 +2042,7 @@ class ContinuousBatchingEngine:
         list to cover `new_end` tokens, freeing (and zeroing out of the
         table) every block past that — the block-boundary case where a
         rejection hands cache capacity straight back to the pool. The
-        device half (`truncate_paged_kv_cache`) already zeroed the
+        device half (`truncate_paged_kv`) already zeroed the
         rejected positions, so a freed-then-reallocated block carries no
         stale KV (a SHARED dropped block is the exception: its
         zero-write was retargeted at the parking block, because the

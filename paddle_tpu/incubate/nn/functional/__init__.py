@@ -665,41 +665,42 @@ def fused_multi_transformer(
                 # not B x max_blocks, and a whole prompt chunk rides one
                 # kernel invocation next to the decode rows
                 from ....ops.pallas.paged_attention import (
-                    ragged_paged_attention, update_paged_kv_cache,
-                    update_paged_kv_cache_chunk)
+                    append_paged_kv, append_paged_kv_chunk,
+                    ragged_paged_attention)
                 # named for the device trace: `kv_write` is everything
-                # the append costs — the K and V halves sliced out of
-                # the layer's cache, the scatter, the halves stacked
-                # back — and `attention` the ragged kernel
+                # the append costs — the new rows stacked and scattered
+                # into the layer's [2, KVH, NB, BS, D] cache where it
+                # lies — and `attention` the ragged kernel, which reads
+                # its blocks out of that same buffer. The buffer is the
+                # layer's result: nothing here slices a half out of it
+                # or stacks two back (either is a copy of the cache).
+                # (On the v5e the chunk writer's fused scatter keeps no
+                # op metadata, so the trace shows it under no scope.)
                 cache = caches[li]             # [2, KVH, NB, BS, D]
                 ln = jnp.asarray(slens).reshape(-1)
+                work = (tuple(rwork), None, rwork[0].shape[0], ragged_pack)
                 if qlens is None:
                     with jax.named_scope("kv_write"):
-                        kc, vc = update_paged_kv_cache(
-                            cache[0], cache[1], k[:, 0], v[:, 0],
-                            tables_a, ln)
+                        cache = append_paged_kv(
+                            cache, k[:, 0], v[:, 0], tables_a, ln)
                     with jax.named_scope("attention"):
                         ctx = ragged_paged_attention(
-                            q[:, 0], kc, vc, tables_a, ln + 1,
-                            scale=scale,
-                            work=(tuple(rwork), None, rwork[0].shape[0],
-                                  ragged_pack),
+                            q[:, 0], cache, tables_a, ln + 1,
+                            scale=scale, work=work,
                             buffer_depth=kv_buffer_depth)
                         ctx = ctx[:, None].astype(xa.dtype)  # [B,1,H,D]
                 else:
                     ql = jnp.asarray(qlens).reshape(-1)
                     with jax.named_scope("kv_write"):
-                        kc, vc = update_paged_kv_cache_chunk(
-                            cache[0], cache[1], k, v, tables_a, ln, ql)
+                        cache = append_paged_kv_chunk(
+                            cache, k, v, tables_a, ln, ql)
                     with jax.named_scope("attention"):
                         ctx = ragged_paged_attention(
-                            q, kc, vc, tables_a, ln + ql, scale=scale,
-                            work=(tuple(rwork), None, rwork[0].shape[0],
-                                  ragged_pack), q_lens=ql,
+                            q, cache, tables_a, ln + ql, scale=scale,
+                            work=work, q_lens=ql,
                             buffer_depth=kv_buffer_depth
                             ).astype(xa.dtype)            # [B, C, H, D]
-                with jax.named_scope("kv_write"):
-                    new_caches.append(jnp.stack([kc, vc]))
+                new_caches.append(cache)
             elif tstep is not None and caches:
                 # decode: append the new token, attend over the valid cache
                 cache = caches[li]                 # [2, B, g, S_max, D]

@@ -782,13 +782,8 @@ class FusedMultiTransformerEngine:
             into a block other requests still read writes into a
             private copy instead). Block ids are traced scalars, so one
             compile covers every (src, dst) pair ever copied."""
-            from ..ops.pallas.paged_attention import copy_paged_kv_block
-            out = []
-            for c in caches:
-                kc, vc = copy_paged_kv_block(c[0], c[1], src_block,
-                                             dst_block)
-                out.append(jnp.stack([kc, vc]))
-            return out
+            from ..ops.pallas.paged_attention import copy_paged_kv
+            return [copy_paged_kv(c, src_block, dst_block) for c in caches]
 
         def paged_rewind(caches, tables, new_lens, old_lens, span):
             """Roll every layer's paged cache back from old_lens to
@@ -796,13 +791,9 @@ class FusedMultiTransformerEngine:
             program; `span` is static, the serving engine passes its
             bucketed slab width so the compile keys stay on the same
             O(log chunk) treadmill as the step itself."""
-            from ..ops.pallas.paged_attention import truncate_paged_kv_cache
-            out = []
-            for c in caches:
-                kc, vc = truncate_paged_kv_cache(
-                    c[0], c[1], tables, new_lens, old_lens, span)
-                out.append(jnp.stack([kc, vc]))
-            return out
+            from ..ops.pallas.paged_attention import truncate_paged_kv
+            return [truncate_paged_kv(c, tables, new_lens, old_lens, span)
+                    for c in caches]
 
         import jax
         self._prefill = jax.jit(prefill, donate_argnums=(1,))

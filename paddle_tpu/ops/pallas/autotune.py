@@ -494,6 +494,7 @@ def _make_bucket_runner(kv_heads, group_q, head_dim, block_size, lens,
         (kv_heads, num_blocks, block_size, head_dim)) * 0.1, dtype), dc)
     vc = _lane_pad(jnp.asarray(rng.standard_normal(
         (kv_heads, num_blocks, block_size, head_dim)) * 0.1, dtype), dc)
+    kv = jnp.stack([kc, vc])    # one layer's cache as the engine holds it
     if chunk is None:
         q = jnp.asarray(rng.standard_normal(
             (batch, h, head_dim)) * 0.1, dtype)
@@ -510,7 +511,7 @@ def _make_bucket_runner(kv_heads, group_q, head_dim, block_size, lens,
 
         def call():
             return ragged_paged_attention(
-                q, kc, vc, tables, jnp.asarray(lens, jnp.int32),
+                q, kv, tables, jnp.asarray(lens, jnp.int32),
                 work=work, q_lens=q_lens,
                 buffer_depth=cand["buffer_depth"])
 

@@ -6,8 +6,13 @@ attention) behind python/paddle/incubate/nn/functional
 block_multihead_attention (SURVEY.md §2.9).
 
 TPU-native shape: the KV cache lives in HBM as fixed-size blocks
-[KVH, num_blocks, block_size, Dc]; each sequence owns a list of block ids
-(block_tables [B, max_blocks]). Dc is the head dim rounded up to the
+[KVH, num_blocks, block_size, Dc] per half; each sequence owns a list of
+block ids (block_tables [B, max_blocks]). The serving engine keeps a
+layer's K and V halves in ONE buffer [2, KVH, num_blocks, block_size, Dc]
+that its step program donates: the writers at the end of this file
+scatter new rows into it and the ragged kernel DMAs blocks out of it, so
+no program slices a half out or stacks two back. Dc is the head dim
+rounded up to the
 128-lane tile (`paged_head_dim`; the pad lanes hold zeros): Mosaic DMAs
 whole (sublane, 128) tiles, so a 64-wide row cannot be sliced out of HBM
 ("Slice shape along dimension 3 must be aligned to tiling (128)"), and
@@ -40,7 +45,7 @@ Two kernels:
   the same span: a decode sequence verifying K prompt-lookup drafts asks
   for a 1+K span (its last real token plus the drafts), pays ONE kernel
   invocation for all K+1 positions, and the host rolls rejected suffixes
-  back with `truncate_paged_kv_cache`. The packed tile grows
+  back with `truncate_paged_kv`. The packed tile grows
   to [pack*C*G, D] (C query positions per sequence) and each query row
   is causally masked to its own absolute position, so a 512-token prompt
   costs ceil(512/C) steps at C-row MXU intensity instead of 512 steps
@@ -257,7 +262,7 @@ def build_ragged_work(block_tables, context_lens, block_size, pack,
     no-op.
 
     A length past the table capacity (max_blocks * block_size) walks only
-    the blocks that exist: this pairs with `update_paged_kv_cache`
+    the blocks that exist: this pairs with `append_paged_kv`
     dropping the write a full row has no slot for — the row attends over
     its capacity tokens instead of indexing past its table row.
     """
@@ -507,26 +512,28 @@ class RaggedWorkBuilder:
 
 
 def _ragged_kernel(ws, wg, wr, wblk, wpos, wfirst, wlast, wqs, wql,
-                   q_ref, k_hbm, v_hbm, o_ref,
+                   q_ref, kv_hbm, o_ref,
                    kbuf, vbuf, ksem, vsem, m_scr, l_scr, acc,
                    *, block_size, scale, group_q, chunk, depth=2):
     hh = pl.program_id(0)
     t = pl.program_id(1)
     nt = pl.num_programs(1)
 
-    def kdma(slot, idx):
+    # kv_hbm is the layer's whole [2, KVH, NB, BS, Dc] cache, left in
+    # HBM: the K and the V half are told apart in the DMA's index, so no
+    # half is ever sliced out (a custom call cannot read a view, and a
+    # materialised half is a copy of half the cache)
+    def dma(half, buf, sem, slot, idx):
         # a valid work list only holds live block ids, but the list is
         # host-built data: clamp both ends before the HBM DMA — an OOB id
         # (including a -1 free-slot sentinel) doesn't fault on TPU, it
         # reads whatever block aliases (graftlint GL301)
-        blk = jnp.clip(wblk[idx], 0, k_hbm.shape[1] - 1)
+        blk = jnp.clip(wblk[idx], 0, kv_hbm.shape[2] - 1)
         return pltpu.make_async_copy(
-            k_hbm.at[hh, blk], kbuf.at[slot], ksem.at[slot])
+            kv_hbm.at[half, hh, blk], buf.at[slot], sem.at[slot])
 
-    def vdma(slot, idx):
-        blk = jnp.clip(wblk[idx], 0, v_hbm.shape[1] - 1)
-        return pltpu.make_async_copy(
-            v_hbm.at[hh, blk], vbuf.at[slot], vsem.at[slot])
+    kdma = functools.partial(dma, 0, kbuf, ksem)
+    vdma = functools.partial(dma, 1, vbuf, vsem)
 
     # multi-buffering, `depth` slots (depth=2 is classic double
     # buffering): t == 0 warms entries 0..depth-2, then every step
@@ -628,7 +635,7 @@ def default_pack(batch, group_q):
     return max(1, min(batch, -(-8 // group_q)))
 
 
-def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
+def ragged_paged_attention(q, kv_cache, block_tables, context_lens,
                            scale=None, pack=None, work=None, q_lens=None,
                            buffer_depth=2):
     """Mixed decode/prefill attention over a paged KV cache, ragged grid.
@@ -636,11 +643,15 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     q:            [B, H, D] — one query token per sequence (decode), or
                   [B, C, H, D] — a chunk of up to C query tokens per
                   sequence (chunked prefill; rows past q_lens[b] ignored)
-    k/v_cache:    [KVH, num_blocks, block_size, Dc], Dc >= D (the
-                  engine allocates Dc = paged_head_dim(D); q is
-                  zero-padded to Dc for the kernel and the output sliced
-                  back to D — zeros add nothing to q.k, and the pad
-                  lanes of p.v are dropped)
+    kv_cache:     [2, KVH, num_blocks, block_size, Dc] — one layer's K
+                  and V halves in the one buffer the engine allocates
+                  and the writers append into (`jnp.stack([k, v])` of
+                  separate halves); the kernel reads blocks out of it
+                  where it lies. Dc >= D (the engine allocates
+                  Dc = paged_head_dim(D); q is zero-padded to Dc for
+                  the kernel and the output sliced back to D — zeros
+                  add nothing to q.k, and the pad lanes of p.v are
+                  dropped)
     block_tables: [B, max_blocks_per_seq] int32 cache-block ids
     context_lens: [B] int32 valid cache length per sequence INCLUDING
                   this call's query span (0 allowed: the row costs zero
@@ -676,7 +687,11 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     if squeeze:
         q = q[:, None]
     b, c, h, d_q = q.shape
-    kvh, _, block_size, d = k_cache.shape
+    if kv_cache.ndim != 5 or kv_cache.shape[0] != 2:
+        raise ValueError(
+            "ragged_paged_attention takes one [2, KVH, NB, BS, Dc] cache "
+            f"(K and V stacked), got shape {tuple(kv_cache.shape)}")
+    _, kvh, _, block_size, d = kv_cache.shape
     g = h // kvh
     if scale is None:
         scale = 1.0 / math.sqrt(d_q)
@@ -715,14 +730,13 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         in_specs=[
             pl.BlockSpec((1, 1, pg, d),
                          lambda hh, t, ws, wg, *_: (wg[t], hh, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),   # K/V stay in HBM;
-            pl.BlockSpec(memory_space=pl.ANY),   # blocks DMA'd by hand
-        ],
+            pl.BlockSpec(memory_space=pl.ANY),   # the cache stays in
+        ],                                       # HBM; blocks DMA'd by hand
         out_specs=pl.BlockSpec(
             (1, 1, pg, d), lambda hh, t, ws, wg, *_: (wg[t], hh, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((buffer_depth, block_size, d), k_cache.dtype),
-            pltpu.VMEM((buffer_depth, block_size, d), v_cache.dtype),
+            pltpu.VMEM((buffer_depth, block_size, d), kv_cache.dtype),
+            pltpu.VMEM((buffer_depth, block_size, d), kv_cache.dtype),
             pltpu.SemaphoreType.DMA((buffer_depth,)),
             pltpu.SemaphoreType.DMA((buffer_depth,)),
             pltpu.VMEM((pg, LANES), jnp.float32),
@@ -743,7 +757,7 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         name="paged_step_ragged_attn",
         interpret=_interpret_mode(),
     )(*[jnp.asarray(a, jnp.int32) for a in work_arrs],
-      qp, k_cache, v_cache)
+      qp, kv_cache)
     out = _unpack_outputs(out, b, c, h, g, pack)[..., :d_q]
     # rows whose group was never visited (len 0 / q_len 0) carry
     # uninitialised VMEM — mask every invalid (seq, chunk-pos) row off
@@ -835,135 +849,150 @@ def ragged_paged_attention_reference(q, k_cache, v_cache, block_tables,
     return out[:, 0] if squeeze else out
 
 
-def update_paged_kv_cache(k_cache, v_cache, k_new, v_new, block_tables,
-                          context_lens):
-    """Append one decode step's K/V ([B, KVH, D]) into the paged cache at
-    position context_lens (the slot the new token occupies). Returns the
-    updated caches. Pure scatter — XLA keeps it in-place under jit when
-    the caches are donated. Rows narrower than the cache's Dc are
-    zero-padded (`paged_head_dim`).
+# ---------------------------------------------------------------------------
+# the cache writers
+# ---------------------------------------------------------------------------
+#
+# Two operand forms, one contract. The ENGINE's form is one layer's
+# stacked cache [2, KVH, NB, BS, Dc] (`append_paged_kv`,
+# `append_paged_kv_chunk`, `truncate_paged_kv`, `copy_paged_kv`): rows
+# are scattered into that buffer itself and the buffer is the result, so
+# a jitted program that donates it never reads or writes a whole cache
+# to append a row — `tests/test_attention_ragged_paged.py`
+# `TestStepNeverCopiesTheCache` pins that for the engine's paged step
+# (no slice, stack or pad of a cache or a half in its jaxpr, its cache
+# results aliased to its donated arguments). The REFERENCE API's form is
+# the separate halves [KVH, NB, BS, Dc] (`update_paged_kv_cache` and its
+# three siblings, behind `block_multihead_attention` /
+# `block_kv_cache_rewind`). Both go through `_write_span`, where the
+# index arithmetic and the boundary contract are written once:
+#   * a position at or past its row's `stop`, or at/after the table's
+#     capacity (max_blocks * block_size), is DROPPED — its block id is
+#     set to NB, past the pool, and the scatter's mode="drop" discards
+#     it — never aliased onto whatever block a clamped gather would hand
+#     back;
+#   * the block-table column read is clamped into the table.
 
-    Boundary contract: a row whose context_lens already equals the table
-    capacity (max_blocks * block_size) has nowhere to append — its write
-    is DROPPED (and the would-be out-of-bounds block-table column read is
-    clamped) instead of aliasing whatever XLA's clamped gather happened
-    to hand back."""
-    kvh, nb, bs, d = k_cache.shape
-    k_new, v_new = _lane_pad(k_new, d), _lane_pad(v_new, d)
-    b = k_new.shape[0]
+def _write_span(cache, rows, block_tables, start, stop, span):
+    """Write rows[..., b, j, h, :] into cache at position start[b] + j
+    of sequence b (kv head h), for j < span (a static int) — dropped
+    where the position is at/after stop[b] or the table's capacity.
+    cache [KVH, NB, BS, Dc] takes rows [B, span, KVH, Dc]; a stacked
+    [2, KVH, NB, BS, Dc] cache takes rows [2, B, span, KVH, Dc], the
+    half's index joining the same one scatter. `rows` may be a scalar.
+    Positions are distinct per (b, j), so writes never collide."""
+    kvh, nb, bs, _ = cache.shape[-4:]
     max_nb = block_tables.shape[1]
-    full = context_lens >= max_nb * bs                # [B] no slot left
-    blk_idx = jnp.minimum(context_lens // bs, max_nb - 1)
-    blk_ids = jnp.take_along_axis(
-        block_tables, blk_idx[:, None], axis=1)[:, 0]  # [B]
-    # scatter mode="drop": full rows aim past the cache and vanish
-    blk_ids = jnp.where(full, nb, blk_ids)
-    offs = context_lens % bs                          # [B]
-
-    def upd(cache, new):
-        # scatter [B, KVH, D] into [KVH, NB, BS, D] at (h, blk_ids[b], offs[b])
-        hidx = jnp.arange(kvh)
-        bidx = jnp.arange(b)
-        return cache.at[hidx[None, :], blk_ids[:, None], offs[:, None]].set(
-            new[bidx[:, None], hidx[None, :]], mode="drop")
-
-    return upd(k_cache, k_new), upd(v_cache, v_new)
+    pos = jnp.reshape(start, (-1, 1)) + jnp.arange(span)[None, :]  # [B, S]
+    valid = (pos < jnp.reshape(stop, (-1, 1))) & (pos < max_nb * bs)
+    blk_col = jnp.minimum(pos // bs, max_nb - 1)    # clamp the table read
+    blk_ids = jnp.take_along_axis(block_tables, blk_col, axis=1)
+    # scatter mode="drop": invalid rows aim past the cache and vanish
+    blk_ids = jnp.where(valid, blk_ids, nb)
+    idx = (jnp.arange(kvh), blk_ids[:, :, None], (pos % bs)[:, :, None])
+    if cache.ndim == 5:
+        idx = (jnp.arange(2)[:, None, None, None],) + idx
+    return cache.at[idx].set(rows, mode="drop")
 
 
-def truncate_paged_kv_cache(k_cache, v_cache, block_tables, new_lens,
-                            old_lens, max_span):
-    """Rewind a paged cache: ZERO positions new_lens[b] .. old_lens[b]-1
-    of every sequence — the KV a rejected speculative draft span left
-    behind. `max_span` (static python int) bounds old_lens - new_lens, so
-    the scatter keeps a jit-compatible static shape; rows where
-    new_lens == old_lens are a no-op. Returns the updated caches; pure
-    scatter, in-place under jit when the caches are donated.
+def append_paged_kv_chunk(cache, k_new, v_new, block_tables, context_lens,
+                          valid_counts):
+    """Append a CHUNK of new K/V rows ([B, C, KVH, D]) into one layer's
+    stacked cache [2, KVH, NB, BS, Dc]: sequence b's row j lands at
+    position context_lens[b] + j for j < valid_counts[b]. The chunk may
+    span block boundaries (the caller grew the block table first). Rows
+    narrower than Dc are zero-padded (`paged_head_dim`). Returns the
+    updated cache: one scatter into the operand, whose new rows
+    ([2, B, C, KVH, Dc]) are all that is stacked.
+
+    Boundary contract: rows past valid_counts[b] and rows whose position
+    falls at/after the table capacity are DROPPED (see above)."""
+    d = cache.shape[-1]
+    rows = jnp.stack([_lane_pad(k_new, d), _lane_pad(v_new, d)])
+    return _write_span(cache, rows, block_tables, context_lens,
+                       context_lens + valid_counts, k_new.shape[1])
+
+
+def append_paged_kv(cache, k_new, v_new, block_tables, context_lens):
+    """Append one decode step's K/V ([B, KVH, D]) into one layer's
+    stacked cache at position context_lens (the slot the new token
+    occupies): a one-column chunk. A row whose context_lens already
+    equals the table capacity has nowhere to append — its write is
+    DROPPED."""
+    return append_paged_kv_chunk(
+        cache, k_new[:, None], v_new[:, None], block_tables, context_lens,
+        jnp.ones_like(context_lens))
+
+
+def truncate_paged_kv(cache, block_tables, new_lens, old_lens, max_span):
+    """Rewind one layer's stacked cache: ZERO positions new_lens[b] ..
+    old_lens[b]-1 of every sequence — the KV a rejected speculative
+    draft span left behind. `max_span` (static python int) bounds
+    old_lens - new_lens, so the scatter keeps a jit-compatible static
+    shape; rows where new_lens == old_lens are a no-op. (A separate
+    half [KVH, NB, BS, Dc] is served as well.)
 
     Zeroing (rather than just rolling the host length back) keeps the
     strong invariant the serving tests lean on: a speculated-then-rewound
     cache is BIT-IDENTICAL to one that never speculated, so token-exact
     claims never rest on overwrite-before-attend reasoning.
 
-    Boundary contract (same family as `update_paged_kv_cache_chunk`):
-    positions past the span, past old_lens, or at/after the table
-    capacity are DROPPED, never aliased through a clamped gather."""
-    kvh, nb, bs, d = k_cache.shape
-    b = block_tables.shape[0]
-    max_nb = block_tables.shape[1]
-    span = int(max_span)
-    pos = new_lens.reshape(-1, 1) + jnp.arange(span)[None, :]     # [B, S]
-    valid = (pos < old_lens.reshape(-1, 1)) & (pos < max_nb * bs)
-    blk_col = jnp.minimum(pos // bs, max_nb - 1)    # clamp the table read
-    blk_ids = jnp.take_along_axis(block_tables, blk_col, axis=1)  # [B, S]
-    # scatter mode="drop": invalid rows aim past the cache and vanish
-    blk_ids = jnp.where(valid, blk_ids, nb)
-    offs = pos % bs                                               # [B, S]
-
-    def upd(cache):
-        hidx = jnp.arange(kvh)
-        zeros = jnp.zeros((b, span, kvh, d), cache.dtype)
-        return cache.at[hidx[None, None, :], blk_ids[:, :, None],
-                        offs[:, :, None]].set(zeros, mode="drop")
-
-    return upd(k_cache), upd(v_cache)
+    Boundary contract: positions past the span, past old_lens, or
+    at/after the table capacity are DROPPED (see above)."""
+    return _write_span(cache, jnp.zeros((), cache.dtype), block_tables,
+                       new_lens, old_lens, int(max_span))
 
 
-def copy_paged_kv_block(k_cache, v_cache, src_block, dst_block):
-    """Duplicate ONE physical cache block: copy every (kv_head, slot, d)
-    row of `src_block` into `dst_block` — the device half of the serving
-    engine's copy-on-write. A request that must append into a block other
-    requests still read gets a private copy first; the shared original
-    stays byte-identical for its remaining readers, so prefix sharing
-    never rests on overwrite-ordering reasoning. Returns the updated
-    caches; pure gather+scatter, in-place under jit when donated.
+def copy_paged_kv(cache, src_block, dst_block):
+    """Duplicate ONE physical block of one layer's stacked cache: copy
+    every (half, kv_head, slot, d) row of `src_block` into `dst_block` —
+    the device half of the serving engine's copy-on-write. A request
+    that must append into a block other requests still read gets a
+    private copy first; the shared original stays byte-identical for its
+    remaining readers, so prefix sharing never rests on
+    overwrite-ordering reasoning. (Blocks are the third axis from the
+    end, so a separate half [KVH, NB, BS, Dc] is served as well.)
 
-    Boundary contract (same family as `truncate_paged_kv_cache`): both
-    block ids are data from the host allocator, so the gather side is
-    CLAMPED into the pool and the scatter side uses mode="drop" — an
-    out-of-pool id copies garbage nowhere instead of aliasing another
-    sequence's KV."""
-    nb = k_cache.shape[1]
-    src = jnp.minimum(src_block, nb - 1)           # clamp the gather
-
-    def upd(cache):
-        row = jax.lax.dynamic_index_in_dim(cache, src, axis=1,
-                                           keepdims=False)
-        return cache.at[:, dst_block].set(row, mode="drop")
-
-    return upd(k_cache), upd(v_cache)
+    Boundary contract: both block ids are data from the host allocator,
+    so the gather side is CLAMPED into the pool and the scatter side
+    uses mode="drop" — an out-of-pool id copies garbage nowhere instead
+    of aliasing another sequence's KV."""
+    ax = cache.ndim - 3
+    src = jnp.minimum(src_block, cache.shape[ax] - 1)   # clamp the gather
+    row = jax.lax.dynamic_index_in_dim(cache, src, axis=ax, keepdims=False)
+    return cache.at[(slice(None),) * ax + (dst_block,)].set(
+        row, mode="drop")
 
 
 def update_paged_kv_cache_chunk(k_cache, v_cache, k_new, v_new,
                                 block_tables, context_lens, valid_counts):
-    """Append a CHUNK of new K/V rows ([B, C, KVH, D]) into the paged
-    cache: sequence b's row j lands at position context_lens[b] + j for
-    j < valid_counts[b]. The chunk may span block boundaries (the caller
-    grew the block table first). Returns the updated caches; pure
-    scatter, in-place under jit when the caches are donated.
+    """`append_paged_kv_chunk` over separate halves
+    [KVH, NB, BS, Dc]: the reference API's operand form. Returns the
+    two updated halves."""
+    d = k_cache.shape[-1]
+    stop = context_lens + valid_counts
+    return tuple(
+        _write_span(cache, _lane_pad(new, d), block_tables, context_lens,
+                    stop, new.shape[1])
+        for cache, new in ((k_cache, k_new), (v_cache, v_new)))
 
-    Boundary contract (same as `update_paged_kv_cache`): rows past
-    valid_counts[b] and rows whose position falls at/after the table
-    capacity (max_blocks * block_size) are DROPPED — never aliased onto
-    whatever block a clamped gather would hand back."""
-    kvh, nb, bs, d = k_cache.shape
-    k_new, v_new = _lane_pad(k_new, d), _lane_pad(v_new, d)
-    b, c = k_new.shape[0], k_new.shape[1]
-    max_nb = block_tables.shape[1]
-    pos = context_lens.reshape(-1, 1) + jnp.arange(c)[None, :]    # [B, C]
-    valid = ((jnp.arange(c)[None, :] < valid_counts.reshape(-1, 1))
-             & (pos < max_nb * bs))
-    blk_col = jnp.minimum(pos // bs, max_nb - 1)    # clamp the table read
-    blk_ids = jnp.take_along_axis(block_tables, blk_col, axis=1)  # [B, C]
-    # scatter mode="drop": invalid rows aim past the cache and vanish
-    blk_ids = jnp.where(valid, blk_ids, nb)
-    offs = pos % bs                                               # [B, C]
 
-    def upd(cache, new):
-        # scatter [B, C, KVH, D] into [KVH, NB, BS, D] at
-        # (h, blk_ids[b, j], offs[b, j]); positions are distinct per
-        # (b, j) so writes never collide
-        hidx = jnp.arange(kvh)
-        return cache.at[hidx[None, None, :], blk_ids[:, :, None],
-                        offs[:, :, None]].set(new, mode="drop")
+def update_paged_kv_cache(k_cache, v_cache, k_new, v_new, block_tables,
+                          context_lens):
+    """`append_paged_kv` over separate halves."""
+    return update_paged_kv_cache_chunk(
+        k_cache, v_cache, k_new[:, None], v_new[:, None], block_tables,
+        context_lens, jnp.ones_like(context_lens))
 
-    return upd(k_cache, k_new), upd(v_cache, v_new)
+
+def truncate_paged_kv_cache(k_cache, v_cache, block_tables, new_lens,
+                            old_lens, max_span):
+    """`truncate_paged_kv` over separate halves."""
+    return tuple(truncate_paged_kv(c, block_tables, new_lens, old_lens,
+                                   max_span) for c in (k_cache, v_cache))
+
+
+def copy_paged_kv_block(k_cache, v_cache, src_block, dst_block):
+    """`copy_paged_kv` over separate halves."""
+    return tuple(copy_paged_kv(c, src_block, dst_block)
+                 for c in (k_cache, v_cache))

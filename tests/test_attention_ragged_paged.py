@@ -76,7 +76,7 @@ class TestRaggedKernel:
     def test_bit_exact_vs_reference(self, h, kvh):
         q, kc, vc, tables, lens = _setup(h, kvh, RAGGED_LENS)
         out = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens))
         ref = pa.ragged_paged_attention_reference(q, kc, vc, tables, lens)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -85,7 +85,7 @@ class TestRaggedKernel:
     def test_close_to_dense_softmax(self, h, kvh):
         q, kc, vc, tables, lens = _setup(h, kvh, RAGGED_LENS, seed=1)
         out = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens))
         ref = _dense_softmax_ref(q, kc, vc, tables, lens)
         np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
@@ -107,7 +107,7 @@ class TestRaggedKernel:
     def test_pack_variants_bit_exact(self, pack):
         q, kc, vc, tables, lens = _setup(8, 4, RAGGED_LENS, seed=3)
         out = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), pack=pack)
         ref = pa.ragged_paged_attention_reference(
             q, kc, vc, tables, lens, pack=pack)
@@ -117,7 +117,7 @@ class TestRaggedKernel:
         q, kc, vc, tables, lens = _setup(8, 4, RAGGED_LENS, seed=4)
         to16 = lambda a: jnp.asarray(a, jnp.bfloat16)
         out = pa.ragged_paged_attention(
-            to16(q), to16(kc), to16(vc), jnp.asarray(tables),
+            to16(q), jnp.stack([to16(kc), to16(vc)]), jnp.asarray(tables),
             jnp.asarray(lens))
         ref = _dense_softmax_ref(
             np.asarray(to16(q), np.float32), np.asarray(to16(kc), np.float32),
@@ -147,13 +147,13 @@ class TestRaggedKernel:
     def test_bucketed_work_same_output(self):
         q, kc, vc, tables, lens = _setup(8, 4, RAGGED_LENS, seed=5)
         plain = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), pack=2)
         work = pa.build_ragged_work(tables, lens, kc.shape[2], 2,
                                     bucket_to=pa.next_pow2)
         assert work[2] > work[1]  # really padded
         bucketed = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), pack=2, work=work)
         np.testing.assert_array_equal(np.asarray(plain),
                                       np.asarray(bucketed))
@@ -161,7 +161,7 @@ class TestRaggedKernel:
         # silently mis-pack the query tiles
         with pytest.raises(ValueError):
             pa.ragged_paged_attention(
-                jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+                jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
                 jnp.asarray(tables), jnp.asarray(lens), pack=4, work=work)
 
     def test_full_capacity_row_attends_over_table(self):
@@ -178,7 +178,7 @@ class TestRaggedKernel:
         q, kc, vc, tables2, _ = _setup(8, 4, [0] * 3, d=16, bs=bs,
                                        max_nb=max_nb)
         out = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables2), jnp.asarray(lens))
         # equivalent to attending over the capacity tokens
         ref = _dense_softmax_ref(q, kc, vc, tables2,
@@ -189,7 +189,7 @@ class TestRaggedKernel:
     def test_all_empty_batch(self):
         q, kc, vc, tables, lens = _setup(8, 4, [0, 0, 0])
         out = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens))
         np.testing.assert_array_equal(np.asarray(out), np.zeros_like(q))
 
@@ -201,7 +201,7 @@ class TestRaggedKernel:
         @jax.jit
         def run(q, kc, vc, tables, lens, arrs):
             return pa.ragged_paged_attention(
-                q, kc, vc, tables, lens,
+                q, jnp.stack([kc, vc]), tables, lens,
                 work=(arrs, t_real, t_total, pack))
 
         out = run(jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
@@ -250,6 +250,115 @@ class TestCacheUpdateBoundary:
         kc2 = np.asarray(kc2)
         for b in range(3):
             np.testing.assert_array_equal(kc2[:, tables[b, 1], 3], kn[b])
+
+
+def _writer_case(writer, dtype, seed=11):
+    """Random tables and rows for one writer: (stacked call, per-half
+    call, numpy oracle), each a thunk over the same data. The rows cover
+    the boundary contract: a slot whose length equals the table's
+    capacity (its write drops), a chunk with valid_counts 0 (parked), a
+    chunk that crosses a block boundary and one that runs into the
+    capacity mid-chunk."""
+    rng = np.random.default_rng(seed)
+    kvh, nb, bs, d, b, max_nb, c = 2, 17, 4, 8, 4, 3, 6
+    cap = max_nb * bs
+    half = lambda: np.asarray(jnp.asarray(
+        rng.standard_normal((kvh, nb, bs, d)), dtype), np.float32)
+    kc, vc = half(), half()
+    rows = lambda *shape: np.asarray(jnp.asarray(
+        rng.standard_normal(shape), dtype), np.float32)
+    tables = rng.permutation(nb - 1)[:b * max_nb].reshape(b, max_nb) \
+        .astype(np.int32)
+    j = lambda a: jnp.asarray(a, dtype)
+    ji = lambda a: jnp.asarray(a, jnp.int32)
+    kv = jnp.stack([j(kc), j(vc)])
+    want_k, want_v = kc.copy(), vc.copy()
+
+    def put(bb, p, krow, vrow):
+        if p < cap:
+            want_k[:, tables[bb, p // bs], p % bs] = krow
+            want_v[:, tables[bb, p // bs], p % bs] = vrow
+
+    if writer == "decode":
+        lens = np.asarray([cap, 3, cap - 1, 0], np.int32)
+        kn, vn = rows(b, kvh, d), rows(b, kvh, d)
+        for bb in range(b):
+            put(bb, int(lens[bb]), kn[bb], vn[bb])
+        stacked = lambda: pa.append_paged_kv(
+            kv, j(kn), j(vn), ji(tables), ji(lens))
+        halves = lambda: pa.update_paged_kv_cache(
+            j(kc), j(vc), j(kn), j(vn), ji(tables), ji(lens))
+    elif writer == "chunk":
+        lens = np.asarray([2, 5, cap - 2, cap], np.int32)
+        valid = np.asarray([c, 0, 4, 3], np.int32)
+        kn, vn = rows(b, c, kvh, d), rows(b, c, kvh, d)
+        for bb in range(b):
+            for jj in range(int(valid[bb])):
+                put(bb, int(lens[bb]) + jj, kn[bb, jj], vn[bb, jj])
+        stacked = lambda: pa.append_paged_kv_chunk(
+            kv, j(kn), j(vn), ji(tables), ji(lens), ji(valid))
+        halves = lambda: pa.update_paged_kv_cache_chunk(
+            j(kc), j(vc), j(kn), j(vn), ji(tables), ji(lens), ji(valid))
+    elif writer == "rewind":
+        old = np.asarray([cap, 7, 5, cap + 2], np.int32)
+        new_l = np.asarray([cap - 3, 7, 1, cap - 1], np.int32)
+        for bb in range(b):
+            for p in range(int(new_l[bb]), int(old[bb])):
+                put(bb, p, 0.0, 0.0)
+        stacked = lambda: pa.truncate_paged_kv(
+            kv, ji(tables), ji(new_l), ji(old), c)
+        halves = lambda: pa.truncate_paged_kv_cache(
+            j(kc), j(vc), ji(tables), ji(new_l), ji(old), c)
+    else:
+        src, dst = int(tables[1, 0]), nb - 1
+        want_k[:, dst], want_v[:, dst] = kc[:, src], vc[:, src]
+        stacked = lambda: pa.copy_paged_kv(kv, ji(src), ji(dst))
+        halves = lambda: pa.copy_paged_kv_block(
+            j(kc), j(vc), ji(src), ji(dst))
+    return stacked, halves, (want_k, want_v)
+
+
+class TestStackedWriters:
+    """The engine's writers take one layer's [2, KVH, NB, BS, Dc] cache
+    and return it: bit for bit what the per-half writers of the
+    reference API give on the two halves, and what numpy gives."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("writer",
+                             ["decode", "chunk", "rewind", "copy"])
+    def test_equals_per_half_writers_and_numpy(self, writer, dtype):
+        stacked, halves, want = _writer_case(writer, jnp.dtype(dtype))
+        got = stacked()
+        k2, v2 = halves()
+        assert got.shape == (2,) + k2.shape and got.dtype == k2.dtype
+        for h, (per_half, oracle) in enumerate(zip((k2, v2), want)):
+            np.testing.assert_array_equal(
+                np.asarray(got[h], np.float32),
+                np.asarray(per_half, np.float32))
+            np.testing.assert_array_equal(
+                np.asarray(got[h], np.float32), oracle)
+
+    def test_copy_out_of_pool_ids_touch_nothing(self):
+        # the host allocator's ids are data: a destination past the pool
+        # drops, a source past the pool is clamped (and then dropped)
+        stacked, _, _ = _writer_case("copy", jnp.float32)
+        kv = np.asarray(stacked())
+        nb = kv.shape[2]
+        out = pa.copy_paged_kv(jnp.asarray(kv), jnp.int32(1),
+                               jnp.int32(nb))
+        np.testing.assert_array_equal(np.asarray(out), kv)
+        out = pa.copy_paged_kv(jnp.asarray(kv), jnp.int32(nb + 5),
+                               jnp.int32(2))
+        exp = kv.copy()
+        exp[:, :, 2] = kv[:, :, nb - 1]
+        np.testing.assert_array_equal(np.asarray(out), exp)
+
+    def test_kernel_refuses_a_single_half(self):
+        q, kc, vc, tables, lens = _setup(8, 4, RAGGED_LENS)
+        with pytest.raises(ValueError, match="stacked"):
+            pa.ragged_paged_attention(
+                jnp.asarray(q), jnp.asarray(kc), jnp.asarray(tables),
+                jnp.asarray(lens))
 
 
 class TestBlockAllocator:
@@ -337,3 +446,73 @@ class TestContinuousBatching:
         out = cb.run()
         assert [len(v) for v in out.values()] == [5]
         assert cb.allocator.num_free == 8
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its
+    equations' parameters (pjit, shard_map, custom calls, branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+class TestStepNeverCopiesTheCache:
+    """To append one row a step must not read or write a layer's whole
+    cache: the rows are scattered into the donated [2, KVH, NB, BS, Dc]
+    buffer, the ragged kernel reads blocks out of that buffer, and the
+    buffer is the step's result. A slice of a half (the parent's
+    `cache[0]`), or two halves stacked back (`jnp.stack([kc, vc])`),
+    is a copy of the cache on the chip: 16 ms of a 28 ms decode step at
+    Mistral-7B widths (PERF.md, PR 24 and PR 25)."""
+
+    COPYING = ("concatenate", "slice", "pad", "squeeze")
+
+    def _traced(self, width):
+        from paddle_tpu.incubate.nn import (ContinuousBatchingEngine,
+                                            GenerationRequest)
+        eng, V = _tiny_engine()
+        cb = ContinuousBatchingEngine(eng, num_blocks=9, block_size=8,
+                                      max_batch=2, prefill_chunk=8)
+        real, seen = eng._paged_step, {}
+
+        def record(*args):
+            seen["args"] = args
+            return real(*args)
+
+        eng._paged_step = record    # one real step, to see its arguments
+        try:
+            cb.submit(GenerationRequest(np.arange(1, 4), 1))
+            cb.step()
+        finally:
+            eng._paged_step = real
+        (w, _, slab, q_arr, sel, tables, lens, work, pack, temp, topp,
+         key) = seen["args"]
+        return cb, real.__wrapped__.trace(
+            w, cb.caches, np.zeros((slab.shape[0], width), slab.dtype),
+            q_arr, sel, tables, lens, work, pack, temp, topp, key)
+
+    @pytest.mark.parametrize("width", [1, 8], ids=["decode", "chunk"])
+    def test_no_cache_sized_copy_and_results_alias_arguments(self, width):
+        cb, traced = self._traced(width)
+        cache = tuple(cb.caches[0].shape)
+        assert len(cache) == 5 and cache[0] == 2
+        sized = (cache, cache[1:])
+        seen_scatter = 0
+        for eqn in _eqns(traced.jaxpr.jaxpr):
+            shapes = [tuple(getattr(v.aval, "shape", ()))
+                      for v in list(eqn.invars) + list(eqn.outvars)]
+            if eqn.primitive.name in self.COPYING:
+                assert not any(s in sized for s in shapes), (
+                    f"{eqn.primitive.name} over a cache or a cache half: "
+                    f"{shapes}")
+            if eqn.primitive.name == "scatter" and cache in shapes:
+                seen_scatter += 1
+        # the walk did reach the writers: one scatter per layer, into
+        # the stacked buffer itself
+        assert seen_scatter == len(cb.caches)
+        text = traced.lower().as_text()
+        assert text.count("tf.aliasing_output") == len(cb.caches)

@@ -81,7 +81,7 @@ class TestChunkedKernel:
         q, kc, vc, tables, lens, qls = _setup(h, kvh, MIXED_LENS,
                                               MIXED_QLENS)
         out = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), q_lens=qls)
         ref = pa.ragged_paged_attention_reference(
             q, kc, vc, tables, lens, q_lens=qls)
@@ -91,7 +91,7 @@ class TestChunkedKernel:
         q, kc, vc, tables, lens, qls = _setup(8, 4, MIXED_LENS,
                                               MIXED_QLENS, seed=1)
         out = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), q_lens=qls)
         ref = _dense_causal_oracle(q, kc, vc, tables, lens, qls)
         np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
@@ -102,12 +102,12 @@ class TestChunkedKernel:
         # output: causal masking inside the chunk, not just vs the cache
         q, kc, vc, tables, lens, qls = _setup(4, 2, [12], [4], seed=2)
         out1 = np.asarray(pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), q_lens=qls))
         q2 = q.copy()
         q2[0, 3] += 100.0
         out2 = np.asarray(pa.ragged_paged_attention(
-            jnp.asarray(q2), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q2), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), q_lens=qls))
         np.testing.assert_array_equal(out1[0, :3], out2[0, :3])
         assert not np.array_equal(out1[0, 3], out2[0, 3])
@@ -116,7 +116,7 @@ class TestChunkedKernel:
         q, kc, vc, tables, lens, qls = _setup(4, 2, MIXED_LENS,
                                               MIXED_QLENS, seed=3)
         out = np.asarray(pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), q_lens=qls))
         for bb, ql in enumerate(qls):
             np.testing.assert_array_equal(out[bb, ql:], 0.0)
@@ -149,13 +149,13 @@ class TestChunkedKernel:
         q, kc, vc, tables, lens, qls = _setup(8, 4, MIXED_LENS,
                                               MIXED_QLENS, seed=4)
         plain = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), pack=2, q_lens=qls)
         work = pa.build_ragged_work(tables, lens, kc.shape[2], 2,
                                     bucket_to=pa.next_pow2, q_lens=qls)
         assert work[2] > work[1]  # really padded
         bucketed = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
             jnp.asarray(tables), jnp.asarray(lens), work=work, q_lens=qls)
         np.testing.assert_array_equal(np.asarray(plain),
                                       np.asarray(bucketed))
@@ -376,8 +376,8 @@ class TestTokenBudgetScheduler:
             (2, c, eng.num_heads, eng.head_dim)).astype(np.float32)
         layer_cache = np.asarray(cb.caches[0])
         out = pa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(layer_cache[0]),
-            jnp.asarray(layer_cache[1]), jnp.asarray(cb.tables),
+            jnp.asarray(q), jnp.asarray(layer_cache),
+            jnp.asarray(cb.tables),
             jnp.asarray(attn), work=work,
             q_lens=jnp.asarray(q_lens, jnp.int32))
         ref = pa.ragged_paged_attention_reference(

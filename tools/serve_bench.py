@@ -226,8 +226,9 @@ def ragged_leg(iters=4):
 
     t_legacy, o_l = timed(lambda: pa.paged_attention(
         q, kc, vc, tables, lens_j))
+    kv = jnp.stack([kc, vc])
     t_ragged, o_r = timed(lambda: pa.ragged_paged_attention(
-        q, kc, vc, tables, lens_j, work=(work, t_real, t_total, pack)))
+        q, kv, tables, lens_j, work=(work, t_real, t_total, pack)))
     np.testing.assert_allclose(
         np.asarray(o_l, np.float32), np.asarray(o_r, np.float32),
         rtol=2e-2, atol=2e-2)
