@@ -1,13 +1,16 @@
 """Share of the device's busy time IN DECODE STEPS spent in ops under
-the scope `kv_write`: the cache's halves sliced out, the append, the
-halves stacked back. The scope is in the op's metadata, which
+the scope `kv_write`: since PR 25 the new rows stacked and one scatter
+per layer into the donated cache buffer (3.4-3.7%); until then also the
+cache's halves sliced out and stacked back, two whole-cache copies a
+layer (52.6-57.7%). The scope is in the op's metadata, which
 lib/xplane.py reads from the trace file itself; a decode step is one the
 stepper thread dispatched with a slab one column wide (`serve.dispatch
 w..c1`: chat decodes without drafts), and its ops are those that start
 between that dispatch and the end of its `serve.fetch`. Over all steps
-the share would follow the slice's mix of chunk and decode steps (the
-copies cost the same in both, the rest of a chunk step five times more),
-not the cache writer."""
+the share would follow the slice's mix of chunk and decode steps (a
+chunk step is ten times a decode step, and its writer's scatter of the
+whole slab carries no scope: PERF.md, Open questions), not the decode
+writer."""
 import annotations
 import xplane
 

@@ -1,15 +1,21 @@
 """Shared by the harness's tests: a throw-away checkout-shaped directory
 whose BENCHMARK.json names tiny configurations (tests/perfbench/data/tiny)
-beside the real benchmark directory, and the CPU rehearsal of one cell."""
+beside the real benchmark directory, a copy of the committed
+BENCHMARK.json with a foreign configuration appended as a later PR would
+(tests/perfbench/data/foreign), and the CPU rehearsal of one cell."""
 import importlib.util
 import json
 import os
 import sys
 
+import pytest
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 BENCH = os.path.join(REPO, "perfbench")
 sys.path.insert(0, os.path.join(BENCH, "lib"))
+
+import manifest as mf  # noqa: E402
 
 
 def load_run():
@@ -34,16 +40,20 @@ E2E = [
 ]
 
 
+def _link(tmp_path, **targets):
+    """The directories a throw-away manifest's `paths` name, as links."""
+    for name, target in targets.items():
+        link = os.path.join(tmp_path, name)
+        if not os.path.exists(link):
+            os.symlink(target, link)
+
+
 def tiny_manifest(tmp_path, extra_paths=(), configs=(), cells=(),
                   per_layer=(), e2e=()):
     """Write a BENCHMARK.json into tmp_path whose paths are the real
     benchmark directory, the tiny data, and `extra_paths` (directories
     under tmp_path)."""
-    for name, target in (("perfbench", BENCH),
-                         ("tiny", os.path.join(HERE, "data", "tiny"))):
-        link = os.path.join(tmp_path, name)
-        if not os.path.exists(link):
-            os.symlink(target, link)
+    _link(tmp_path, perfbench=BENCH, tiny=os.path.join(HERE, "data", "tiny"))
     man = {
         "command": ["python3", "perfbench/run.py"],
         "paths": ["perfbench", "tiny", *extra_paths],
@@ -72,6 +82,62 @@ def tiny_manifest(tmp_path, extra_paths=(), configs=(), cells=(),
     with open(path, "w") as f:
         json.dump(man, f)
     return path
+
+
+FOREIGN_CELL = "foreign-serve.foreign-chat"
+
+
+def foreign_manifest(tmp_path, edit=None):
+    """Write into tmp_path a copy of the COMMITTED BENCHMARK.json with
+    what a `model_config` PR adds and nothing edited: a directory of its
+    own among `paths`, a configuration of another family (none of the
+    committed widths, a layer pattern, experts held beside the published
+    count, a sliced vocabulary), one cell of it, the cell's name on
+    `itl_ms.p95`, and one per-layer metric at the END of `per_layer`.
+    `edit(cfg, entry)` spoils the configuration and its manifest entry
+    first; the spoilt file then lies in tmp_path."""
+    foreign = os.path.join(HERE, "data", "foreign")
+    _link(tmp_path, perfbench=BENCH, foreign=foreign)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        man = json.load(f)
+    with open(os.path.join(foreign, "configs", "foreign-serve.json")) as f:
+        cfg = json.load(f)
+    entry = {"name": "foreign-serve", "source": "tests",
+             "file": "foreign/configs/foreign-serve.json",
+             "reduced": list(cfg["reduced"]),
+             "why": "another family's shapes, at a width the CPU holds"}
+    man["paths"].append("foreign")
+    if edit is not None:
+        edit(cfg, entry)
+        man["paths"].append("spoilt")
+        entry["file"] = "spoilt/foreign-serve.json"
+        os.makedirs(os.path.join(tmp_path, "spoilt"))
+        with open(os.path.join(tmp_path, entry["file"]), "w") as f:
+            json.dump(cfg, f)
+    man["configs"].append(entry)
+    man["workloads"].append(
+        {"name": FOREIGN_CELL, "config": "foreign-serve",
+         "traffic": "foreign-chat", "chips": 1, "why": "open loop"})
+    itl = [m for m in man["end_to_end"] if m["name"] == "itl_ms.p95"]
+    itl[0]["workloads"].append(FOREIGN_CELL)
+    man["per_layer"].append(
+        {"name": "foreign_steps", "unit": "steps", "better": "higher",
+         "source": "program_counter", "layer": "scheduler",
+         "moves": "itl_ms.p95", "workloads": [FOREIGN_CELL]})
+    path = os.path.join(tmp_path, "BENCHMARK.json")
+    with open(path, "w") as f:
+        json.dump(man, f)
+    return path
+
+
+@pytest.fixture(scope="module", params=["committed", "with_a_foreign_cell"])
+def real(request, tmp_path_factory):
+    """The committed manifest, and the same with a later PR's additions:
+    what holds for the one has to hold for the other."""
+    if request.param == "committed":
+        return mf.Manifest(os.path.join(REPO, "BENCHMARK.json"))
+    return mf.Manifest(foreign_manifest(
+        str(tmp_path_factory.mktemp("foreign"))))
 
 
 def rehearse(capsys, manifest, workload, seed=3000000019, seconds=2,

@@ -9,7 +9,7 @@ import struct
 
 import pytest
 
-from perfbench_fixtures import HERE, REPO
+from perfbench_fixtures import HERE, REPO, real  # noqa: F401
 
 import annotations
 import manifest as mf
@@ -282,13 +282,15 @@ def test_no_trace_file_reads_as_nothing(tmp_path):
 
 # -- the manifest --------------------------------------------------------------------------------
 
-def test_the_nine_metrics_are_in_the_manifest_and_it_is_sound():
-    assert mf.validate(MANIFEST) == []
-    names = [m["name"] for m in MANIFEST.per_layer]
-    assert names[-9:] == NEW
-    chat = {m["name"] for m in MANIFEST.per_layer_of(
+def test_the_nine_metrics_are_in_the_manifest_and_it_is_sound(real):
+    """Asked for by name, wherever a later PR's metrics leave them: on
+    the committed manifest and on the copy with a metric appended."""
+    assert mf.validate(real) == []
+    names = [m["name"] for m in real.per_layer]
+    assert set(NEW) <= set(names)
+    chat = {m["name"] for m in real.per_layer_of(
         "mistral7b-serve-1chip.chat")}
-    train = {m["name"] for m in MANIFEST.per_layer_of(
+    train = {m["name"] for m in real.per_layer_of(
         "mistral7b-train-1chip.seq4k")}
     assert set(NEW[:8]) <= chat and NEW[8] in train
     assert not set(NEW[:8]) & train and NEW[8] not in chat

@@ -1,5 +1,9 @@
 """The manifest, the traffic generator, the result line, the peaks table,
-and that a family, a mix and a metric are added by files alone."""
+and that a family, a mix and a metric are added by files alone. Every
+test that takes `real` runs on the committed BENCHMARK.json and on a copy
+of it to which another family's configuration, cell and metric have been
+appended, as a later PR appends them (perfbench_fixtures.foreign_manifest).
+"""
 import io
 import json
 import os
@@ -8,16 +12,12 @@ from contextlib import redirect_stdout
 import numpy as np
 import pytest
 
-from perfbench_fixtures import BENCH, REPO, rehearse, tiny_manifest
+from perfbench_fixtures import (BENCH, FOREIGN_CELL, foreign_manifest,
+                                real, rehearse, tiny_manifest)  # noqa: F401
 
 import manifest as mf
 import measure
 import traffic
-
-
-@pytest.fixture(scope="module")
-def real():
-    return mf.Manifest(os.path.join(REPO, "BENCHMARK.json"))
 
 
 def test_the_committed_manifest_keeps_the_contract(real):
@@ -60,15 +60,131 @@ def test_every_cell_resolves_by_name(real):
         assert hasattr(real.driver(cfg), "run")
 
 
+def is_width(key):
+    """A key that `reduced` may never name (the contract's widths): a
+    hidden, intermediate, latent, state or head size, a rank, and the
+    number of experts per token."""
+    return (key.endswith(("hidden_size", "intermediate_size", "_dim",
+                          "_rank")) or key == "num_experts_per_tok")
+
+
+def is_number(v):
+    return isinstance(v, (int, float)) and not isinstance(v, bool)
+
+
+def width_faults(cfg, entry):
+    """What holds a configuration file to ITS OWN source: the file states
+    under `published` what the source's config.json gives for the keys it
+    carries, and under `reduced` why each key it changed is changed. The
+    complaints, none for a sound file; `entry` is the manifest's."""
+    pub, red = cfg.get("published"), cfg.get("reduced")
+    if not isinstance(pub, dict) or not pub or not isinstance(red, dict):
+        return ["the file needs a `published` and a `reduced` group"]
+    bad = []
+    if sorted(entry["reduced"]) != sorted(red):
+        bad.append(f"the manifest lists {sorted(entry['reduced'])} as "
+                   f"reduced, the file {sorted(red)}")
+    for key, value in pub.items():
+        if key not in red and (key not in cfg or cfg[key] != value):
+            bad.append(f"{key} is {cfg.get(key)!r}, published {value!r}, "
+                       "and not listed in `reduced`")
+    for key, why in red.items():
+        if is_width(key):
+            bad.append(f"{key} is a width: it may never be reduced")
+        if not str(why).strip():
+            bad.append(f"`reduced` does not say why {key} is changed")
+        if key not in pub or key not in cfg:
+            bad.append(f"reduced {key} is not in `published` and the file")
+            continue
+        here, there = cfg[key], pub[key]
+        if here == there:
+            bad.append(f"{key} is listed in `reduced` and is unchanged")
+        elif is_number(here) and is_number(there) and not here < there:
+            bad.append(f"reduced {key} is {here}, above the published "
+                       f"{there}")
+        elif isinstance(here, dict) and isinstance(there, dict):
+            bad += [f"{key}.{k} is a width: it may never be reduced"
+                    for k in there if is_width(k) and here.get(k) != there[k]]
+    # a number the family can read comes from the source or is owned up to
+    bad += [f"{key} = {value} is neither in `published` nor in `assumed`"
+            for key, value in cfg.items()
+            if is_number(value) and key not in pub
+            and key not in cfg.get("assumed", {})]
+    return bad
+
+
 def test_widths_are_the_published_ones(real):
     for cell in real.cells.values():
         cfg = real.config(cell)
-        assert (cfg["hidden_size"], cfg["intermediate_size"],
-                cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                cfg["head_dim"], cfg["vocab_size"], cfg["rope_theta"],
-                cfg["rms_norm_eps"]) == (4096, 14336, 32, 8, 128, 32768,
-                                         1e6, 1e-5)
-        assert list(cfg["reduced"]) == ["num_hidden_layers"]
+        assert width_faults(cfg, real.configs[cell["config"]]) == [], \
+            cell["name"]
+
+
+def _set(key, value, group=None):
+    def edit(cfg, entry):
+        (cfg if group is None else cfg[group])[key] = value
+    return edit
+
+
+def _list_as_reduced(key):
+    def edit(cfg, entry):
+        cfg["reduced"][key] = "for room"
+        entry["reduced"].append(key)
+    return edit
+
+
+def _shrink_nested_width(cfg, entry):
+    _list_as_reduced("linear_attn_config")(cfg, entry)
+    cfg["linear_attn_config"] = dict(cfg["linear_attn_config"], head_dim=8)
+
+
+@pytest.mark.parametrize("edit, complaint", [
+    (_set("hidden_size", 40), "hidden_size is 40, published 48"),
+    (_set("moe_intermediate_size", 16), "not listed in `reduced`"),
+    (_set("linear_attn_config", {"short_conv_kernel_size": 2}),
+     "linear_attn_config is"),
+    (_list_as_reduced("head_dim"), "head_dim is a width"),
+    (_list_as_reduced("moe_intermediate_size"), "is a width"),
+    (_list_as_reduced("num_experts_per_tok"), "is a width"),
+    (_shrink_nested_width, "linear_attn_config.head_dim is a width"),
+    (_list_as_reduced("n_shared_experts"), "is unchanged"),
+    (_set("n_routed_experts", 128), "above the published 64"),
+    (_set("vocab_size", "", "reduced"), "does not say why vocab_size"),
+    (_set("state_size", 16), "neither in `published` nor in `assumed`"),
+    (lambda cfg, entry: entry["reduced"].remove("vocab_size"),
+     "the manifest lists"),
+    (lambda cfg, entry: cfg.pop("published"), "needs a `published`"),
+])
+def test_a_configuration_that_leaves_its_source_unsaid_is_caught(
+        tmp_path, edit, complaint):
+    """The foreign file is sound; each edit is one way a later PR could
+    cut a configuration without saying so. The widths test fails on the
+    manifest that holds the spoilt file, and the fault is named."""
+    m = mf.Manifest(foreign_manifest(str(tmp_path), edit=edit))
+    cell = m.cell(FOREIGN_CELL)
+    faults = width_faults(m.config(cell), m.configs[cell["config"]])
+    assert any(complaint in f for f in faults), faults
+    with pytest.raises(AssertionError):
+        test_widths_are_the_published_ones(m)
+
+
+def test_the_foreign_cell_rehearses_through_the_committed_manifest(
+        tmp_path, capsys):
+    """The appended cell resolves by name beside the committed cells
+    (family, mix, driver, its per-layer metric and no other's) and its
+    CPU rehearsal comes out correct."""
+    path = foreign_manifest(str(tmp_path))
+    m = mf.Manifest(path)
+    assert mf.validate(m) == []
+    assert m.family(m.config(m.cell(FOREIGN_CELL))).FAMILY_NAME == "foreign"
+    assert [x["name"] for x in m.end_to_end_of(FOREIGN_CELL)] \
+        == ["setup_s", "itl_ms.p95"]
+    assert [x["name"] for x in m.per_layer_of(FOREIGN_CELL)] \
+        == ["foreign_steps"]
+    assert m.per_layer[-1]["name"] == "foreign_steps"
+    rc, line, out = rehearse(capsys, path, FOREIGN_CELL)
+    assert rc == 0 and line["correct"] is True, out
+    assert line["attempted"] > 0 and line["failed"] == 0
 
 
 def test_traffic_is_reproducible_and_every_seed_gets_the_same_work():
