@@ -58,7 +58,7 @@ from ...observability import instrument as _metrics
 from ...observability import tracing as _tracing
 from ...ops.pallas.paged_attention import (RaggedWorkBuilder,
                                            build_ragged_work, default_pack,
-                                           next_pow2)
+                                           next_pow2, step_rows)
 
 __all__ = ["BlockAllocator", "GenerationRequest", "RequestResult",
            "KVAllocFailure", "ContinuousBatchingEngine",
@@ -1789,14 +1789,16 @@ class ContinuousBatchingEngine:
         if self._comm_tasks is not None:
             # the TP step's per-layer reduces, attributed through the
             # PR-9 collective path: payload bytes are pure aval math
-            # (tp_step_comm_bytes — 2 psums/layer over the [B, C, E]
-            # partial activations), the window is the dispatch-to-sync
-            # span that CONTAINS the reduces, so the (psum, tp)
-            # bandwidth gauge is a floor and collective_bytes_total
-            # attributes the comms cost exactly
+            # (tp_step_comm_bytes — 2 psums/layer over the partial
+            # activations of the rows the step computes: the [B, C]
+            # slab, or a wide slab's live row tiles), the window is the
+            # dispatch-to-sync span that CONTAINS the reduces, so the
+            # (psum, tp) bandwidth gauge is a floor and
+            # collective_bytes_total attributes the comms cost exactly
             comm_task = self._comm_tasks.start_task(
                 "psum", group="tp",
-                nbytes=self.engine.tp_step_comm_bytes(self.max_batch, c))
+                nbytes=self.engine.tp_step_comm_bytes(
+                    self.max_batch, c, int(q_lens.sum())))
         # the bucket rides the two device-facing annotations' names: at
         # most one name per compiled program (64 on the chat cell)
         bucket = f"w{t_total}c{c}"
@@ -1979,11 +1981,14 @@ class ContinuousBatchingEngine:
                 kind = "decode"
             else:
                 kind = "chunk"
-                # how full the padded [max_batch, c] slab ran
+                # live tokens over the rows the row-wise layers
+                # computed for them: the [max_batch, c] slab, or a wide
+                # slab's live row tiles (host arithmetic, no device read)
+                live = int(q_lens.sum())
                 slab_tokens = _metrics.serve_slab_tokens()
-                slab_tokens.labels(kind="live").inc(int(q_lens.sum()))
+                slab_tokens.labels(kind="live").inc(live)
                 slab_tokens.labels(kind="capacity").inc(
-                    self.max_batch * c)
+                    step_rows(self.max_batch, c, live))
             _metrics.serve_step_kind_seconds().labels(kind=kind).observe(
                 pc_done - pc_step)
             if emitted:

@@ -7,7 +7,9 @@ SURVEY.md §2.9).
 On TPU the "fusion" is either a Pallas kernel (attention family) or a
 jnp composition XLA fuses on its own (rope/bias_act/dropout_add — the MXU
 epilogue fusions the reference hand-writes in CUDA)."""
+import functools
 import math
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -415,6 +417,23 @@ def _apply_rope_pair(q, k, cos, sin, neox):
     return q * cos + rot(q) * sin, k * cos + rot(k) * sin
 
 
+class _LayerWeights(typing.NamedTuple):
+    """One decoder layer's slice of `fused_multi_transformer`'s twelve
+    weight lists (an absent bias is None)."""
+    ln: object
+    ln_b: object
+    qkv: object
+    qkv_b: object
+    lin: object
+    lin_b: object
+    fln: object
+    fln_b: object
+    f1: object
+    f1_b: object
+    f2: object
+    f2_b: object
+
+
 def _ragged_group_q(qkv_weights, gqa_group_size, trans_qkvw):
     """Queries per kv head, recovered from the packed qkv weight layout
     (needed to pick the ragged kernel's default pack factor)."""
@@ -438,7 +457,7 @@ def fused_multi_transformer(
         use_neox_rotary_style=False, gqa_group_size=-1, name=None,
         block_tables=None, ragged_work=None, ragged_pack=None,
         chunk_lens=None, kv_buffer_depth=2, _dequant=None, _mm=None,
-        _tp_reduce=None):
+        _tp_reduce=None, _live_rows=None):
     """Whole-decoder-stack fused transformer (reference
     fused_multi_transformer op: python/paddle/incubate/nn/functional/
     fused_transformer.py:1053 over
@@ -477,6 +496,14 @@ def fused_multi_transformer(
     `build_ragged_work(tables, seq_lens + chunk_lens, ...,
     q_lens=chunk_lens)`). chunk_lens[b] == 0 parks the row: nothing
     written, nothing attended, output rows zero.
+
+    A WIDE paged step (B x C > ROW_TILE rows) computes its live rows
+    only: the slab's chunk_lens.sum() live tokens are packed to the front
+    of a [B x C]-row buffer and every row-wise layer, the cache append
+    among them, walks ceil(live / ROW_TILE) row tiles of it
+    (ops/pallas/paged_attention.py `live_rows`). A caller that has the
+    packing already (`_live_rows`, the engine's paged step) passes x and
+    takes the result as the packed [1, R, E] buffer itself.
 
     Returns the output hidden states [B, S, E]; caches are updated
     in place (dygraph reference semantics).
@@ -571,85 +598,238 @@ def fused_multi_transformer(
 
     def impl(xa, lns, lnb, qkvw, qkvb, linw, linb, flns, flnb, f1w, f1b,
              f2w, f2b, caches, pres, rotary, tstep, mask, slens, qlens,
-             tables_a, rwork, dkeys):
+             tables_a, rwork, dkeys, rows):
         b, s, e = xa.shape
         norm = (lambda h, sc, bi: _rms(h, epsilon, sc)) \
             if norm_type == "rmsnorm" else \
             (lambda h, sc, bi: _ln(h, epsilon, sc, bi))
-        h = xa
-        new_caches = []
-        for li in range(n_layers):
-            resid = h
-            z = norm(h, lns[li], lnb[li] if lnb else None) \
-                if pre_layer_norm else h
+
+        def layer(li):
+            """Layer li's weights, in the op's argument order."""
+            return _LayerWeights(*(
+                xs[li] if xs else None
+                for xs in (lns, lnb, qkvw, qkvb, linw, linb, flns, flnb,
+                           f1w, f1b, f2w, f2b)))
+
+        # The layer's row-wise halves, over any [b, s] of rows: the whole
+        # slab, or one tile of a wide paged step's packed rows ([1,
+        # ROW_TILE]). Attention, between them, is what knows sequences.
+        # They take the layer's weights as `lw` and its index only for
+        # the quantized engines' hooks.
+        def project(h, lw, li):
+            """norm + qkv projection + bias: h [b, s, E] -> q [b, s, H, D]
+            and k, v [b, s, KVH, D]."""
+            z = norm(h, lw.ln, lw.ln_b) if pre_layer_norm else h
             if _mm is not None and trans_qkvw:
-                qkv = _mm(z.reshape(b * s, e), qkvw[li], "qkv",
-                          li).reshape((b, s) + _mm.qkv_out)
-                if qkvb and qkvb[li] is not None:
-                    qkv = qkv + qkvb[li][None, None]
+                qkv = _mm(z.reshape(-1, e), lw.qkv, "qkv",
+                          li).reshape(z.shape[:2] + _mm.qkv_out)
+                if lw.qkv_b is not None:
+                    qkv = qkv + lw.qkv_b[None, None]
                 if G:
-                    ht, hd = _mm.qkv_out
-                    nh = ht - 2 * G
-                    q = qkv[:, :, :nh]
-                    k = qkv[:, :, nh:nh + G]
-                    v = qkv[:, :, nh + G:]
-                else:
-                    nh, hd = _mm.qkv_out[1], _mm.qkv_out[2]
-                    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            elif G:
-                w = dq(qkvw[li], "qkv", li)
+                    nh = _mm.qkv_out[0] - 2 * G
+                    return (qkv[:, :, :nh], qkv[:, :, nh:nh + G],
+                            qkv[:, :, nh + G:])
+                return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            w = dq(lw.qkv, "qkv", li)
+            if G:
                 # GQA packing (reference fused_transformer.py:1009 /
                 # infermeta/fusion.cc gqa branch): weight [H + 2G, D, E]
                 # — H query heads, then G key heads, then G value heads
                 if not trans_qkvw:
                     w = jnp.transpose(w, (1, 2, 0))      # [E,H+2G,D] packed
-                ht, hd = w.shape[0], w.shape[1]
-                nh = ht - 2 * G
+                nh = w.shape[0] - 2 * G
                 qkv = jnp.einsum("bse,hde->bshd", z.astype(w.dtype), w)
-                if qkvb and qkvb[li] is not None:
-                    qkv = qkv + qkvb[li][None, None]
-                q = qkv[:, :, :nh]                       # [B,S,H,D]
-                k = qkv[:, :, nh:nh + G]                 # [B,S,G,D]
-                v = qkv[:, :, nh + G:]
-            else:
-                w = dq(qkvw[li], "qkv", li)
-                if not trans_qkvw:
-                    # [E, 3, H, D] layout -> [3, H, D, E]
-                    w = jnp.transpose(w, (1, 2, 3, 0))
-                nh, hd = w.shape[1], w.shape[2]
-                qkv = jnp.einsum("bse,thde->bsthd", z.astype(w.dtype), w)
-                if qkvb and qkvb[li] is not None:
-                    qkv = qkv + qkvb[li][None, None]
-                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            if rotary is not None:
-                cos = rotary[0][:, 0][:, :, None, :]    # [B, S_rope, 1, D]
-                sin = rotary[1][:, 0][:, :, None, :]
-                if tstep is not None and slens is not None:
-                    # ragged decode: each sequence sits at its OWN position
-                    # (its current length), not a shared time step
-                    ln = jnp.asarray(slens).reshape(-1)
-                    bidx = jnp.arange(cos.shape[0]) \
-                        if cos.shape[0] > 1 else jnp.zeros_like(ln)
-                    if s == 1:
-                        cos = cos[bidx, ln][:, None]    # [B, 1, 1, D]
-                        sin = sin[bidx, ln][:, None]
-                    else:
-                        # chunked prefill: token column j of sequence b
-                        # rotates at position lens[b] + j (clamped into
-                        # the table for the padding columns past qlens)
-                        posr = jnp.minimum(
-                            ln[:, None] + jnp.arange(s)[None, :],
-                            cos.shape[1] - 1)           # [B, C]
-                        cos = cos[bidx[:, None], posr]  # [B, C, 1, D]
-                        sin = sin[bidx[:, None], posr]
-                elif tstep is not None:
-                    pos = jnp.asarray(tstep).reshape(())
-                    cos = jax.lax.dynamic_slice_in_dim(cos, pos, 1, 1)
-                    sin = jax.lax.dynamic_slice_in_dim(sin, pos, 1, 1)
+                if lw.qkv_b is not None:
+                    qkv = qkv + lw.qkv_b[None, None]
+                return (qkv[:, :, :nh],                  # [B,S,H,D]
+                        qkv[:, :, nh:nh + G],            # [B,S,G,D]
+                        qkv[:, :, nh + G:])
+            if not trans_qkvw:
+                # [E, 3, H, D] layout -> [3, H, D, E]
+                w = jnp.transpose(w, (1, 2, 3, 0))
+            qkv = jnp.einsum("bse,thde->bsthd", z.astype(w.dtype), w)
+            if lw.qkv_b is not None:
+                qkv = qkv + lw.qkv_b[None, None]
+            return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+
+        def rope(q, k, table, packed=None):
+            """Rotate q and k by `table` (`rotary_embs`), each row at its
+            position. `packed` = (slot, pos) [R] names them for one tile
+            of a wide paged step's packed rows."""
+            if table is None:
+                return q, k
+            cos = table[0][:, 0][:, :, None, :]     # [B, S_rope, 1, D]
+            sin = table[1][:, 0][:, :, None, :]
+            if packed is not None:
+                slot, pos = packed
+                bidx = slot if cos.shape[0] > 1 else jnp.zeros_like(slot)
+                pos = jnp.minimum(pos, cos.shape[1] - 1)
+                cos, sin = cos[bidx, pos][None], sin[bidx, pos][None]
+            elif tstep is not None and slens is not None:
+                # ragged decode: each sequence sits at its OWN position
+                # (its current length), not a shared time step
+                ln = jnp.asarray(slens).reshape(-1)
+                bidx = jnp.arange(cos.shape[0]) \
+                    if cos.shape[0] > 1 else jnp.zeros_like(ln)
+                if s == 1:
+                    cos = cos[bidx, ln][:, None]    # [B, 1, 1, D]
+                    sin = sin[bidx, ln][:, None]
                 else:
-                    cos, sin = cos[:, :s], sin[:, :s]
-                q, k = _apply_rope_pair(q, k, cos, sin,
-                                        use_neox_rotary_style)
+                    # chunked prefill: token column j of sequence b
+                    # rotates at position lens[b] + j (clamped into
+                    # the table for the padding columns past qlens)
+                    posr = jnp.minimum(
+                        ln[:, None] + jnp.arange(s)[None, :],
+                        cos.shape[1] - 1)           # [B, C]
+                    cos = cos[bidx[:, None], posr]  # [B, C, 1, D]
+                    sin = sin[bidx[:, None], posr]
+            elif tstep is not None:
+                pos = jnp.asarray(tstep).reshape(())
+                cos = jax.lax.dynamic_slice_in_dim(cos, pos, 1, 1)
+                sin = jax.lax.dynamic_slice_in_dim(sin, pos, 1, 1)
+            else:
+                cos, sin = cos[:, :s], sin[:, :s]
+            return _apply_rope_pair(q, k, cos, sin, use_neox_rotary_style)
+
+        def finish(resid, ctx, lw, li, dkey):
+            """Output projection, residual and feed-forward: the layer's
+            input `resid` [b, s, E] and its attention output ctx
+            [b, s, H, D] -> the layer's output [b, s, E]."""
+            b, s = ctx.shape[:2]    # this call's rows, not the slab's
+            if _mm is not None:
+                attn = _mm(ctx.reshape(b * s, -1), lw.lin,
+                           "lin", li).reshape(b, s, -1)
+            else:
+                attn = ctx.reshape(b, s, -1) @ dq(lw.lin, "lin", li)
+            attn = tp_red(attn)
+            if lw.lin_b is not None:
+                attn = attn + lw.lin_b
+            if dkey is not None:
+                keep = jax.random.bernoulli(
+                    dkey, 1.0 - dropout_rate, attn.shape)
+                attn = jnp.where(keep, attn / (1.0 - dropout_rate), 0.0) \
+                    if mode == "upscale_in_train" else \
+                    jnp.where(keep, attn, 0.0)
+            h = resid * residual_alpha + attn
+            if not pre_layer_norm:
+                h = norm(h, lw.ln, lw.ln_b)
+            with jax.named_scope("ffn"):
+                resid2 = h
+                z2 = norm(h, lw.fln, lw.fln_b) if pre_layer_norm else h
+                if _mm is not None:
+                    f1 = _mm(z2.reshape(b * s, -1), lw.f1, "f1",
+                             li).reshape(b, s, -1)
+                else:
+                    f1 = z2 @ dq(lw.f1, "f1", li)
+                if lw.f1_b is not None:
+                    f1 = f1 + lw.f1_b
+                if activation.endswith("glu"):
+                    a, g = jnp.split(f1, 2, axis=-1)
+                    act = jax.nn.silu if activation == "swiglu" \
+                        else jax.nn.gelu
+                    f1 = act(a) * g
+                elif activation == "relu":
+                    f1 = jax.nn.relu(f1)
+                else:
+                    f1 = jax.nn.gelu(f1)
+                if _mm is not None:
+                    f2 = _mm(f1.reshape(b * s, -1), lw.f2, "f2",
+                             li).reshape(b, s, -1)
+                else:
+                    f2 = f1 @ dq(lw.f2, "f2", li)
+                f2 = tp_red(f2)
+                if lw.f2_b is not None:
+                    f2 = f2 + lw.f2_b
+                h = resid2 * residual_alpha + f2
+                if not pre_layer_norm:
+                    h = norm(h, lw.fln, lw.fln_b)
+            return h
+
+        @functools.partial(jax.jit, static_argnums=0)
+        def packed_paged_layer(li, lw, table, tables, ln, ql, rows, work,
+                               dkey, hp, qp, cache):
+            """One layer of a WIDE paged step, over its live rows only:
+            hp [R, E] holds the slab's live tokens packed at its front
+            (`rows`), and each row-wise half runs ROW_TILE rows at a
+            time, rows.n_tiles times (a trip count read on the device).
+            The layer's cache rides the first loop as a carry: a tile's
+            K/V rows are appended where the buffer lies. Attention keeps
+            the slab's [B, C] geometry: one gather lays the packed q
+            rows into it (a dead cell reads some other row; the kernel
+            masks it by q_lens) and one reads ctx back. qp [R, H, D] is
+            the packed q rows' buffer, any layer's.
+
+            Jitted, with everything traced among its arguments: the
+            layers of a dense engine differ in `lw` only, so one trace
+            and one lowered function serve them all (the hooks of a
+            quantized engine key their scales on `li`, which is why it
+            is static: there each layer is its own)."""
+            pos = ln[rows.slot] + rows.col                     # [R]
+
+            def before(r0, carry):
+                qp, cache = carry
+                slot, at = row_tile(rows.slot, r0), row_tile(pos, r0)
+                q, k, v = project(row_tile(hp, r0)[None], lw, li)
+                q, k = rope(q, k, table, (slot, at))
+                with jax.named_scope("kv_write"):
+                    cache = append_paged_kv_rows(
+                        cache, k[0], v[0], tables, slot, at,
+                        row_tile(rows.live, r0))
+                return put_row_tile(qp, q[0], r0), cache
+
+            qp, cache = over_row_tiles(rows.n_tiles, before, (qp, cache))
+            with jax.named_scope("attention"):
+                ctx = ragged_paged_attention(
+                    qp[rows.back], cache, tables, ln + ql,
+                    scale=1.0 / math.sqrt(qp.shape[-1]),
+                    work=(work, None, work[0].shape[0], ragged_pack),
+                    q_lens=ql, buffer_depth=kv_buffer_depth)
+                ctx = ctx.astype(hp.dtype)[rows.slot, rows.col]  # [R,H,D]
+
+            def after(r0, hp):
+                return put_row_tile(hp, finish(
+                    row_tile(hp, r0)[None], row_tile(ctx, r0)[None], lw,
+                    li, None if dkey is None
+                    else jax.random.fold_in(dkey, r0))[0], r0)
+
+            return over_row_tiles(rows.n_tiles, after, hp), qp, cache
+
+        padded = False
+        if tables_a is not None:
+            from ....ops.pallas.paged_attention import (
+                ROW_TILE, append_paged_kv, append_paged_kv_chunk,
+                append_paged_kv_rows, live_rows, over_row_tiles,
+                put_row_tile, ragged_paged_attention, row_tile)
+            padded = (rows is None and qlens is not None
+                      and b * s > ROW_TILE)
+        if padded:
+            # a wide slab handed over as [B, C, E]: packed here, and
+            # handed back in the slab's geometry
+            rows = live_rows(qlens, s)
+            xa = xa[rows.slot, rows.col][None]
+        new_caches = []
+        if rows is not None:
+            # a wide paged step: xa is [1, R, E], the packed live rows
+            q_rows = jax.eval_shape(
+                lambda z: project(z, layer(0), 0)[0], xa)
+            hp, qp = xa[0], jnp.zeros(q_rows.shape[1:], q_rows.dtype)
+            ln = jnp.asarray(slens).reshape(-1)
+            ql = jnp.asarray(qlens).reshape(-1)
+            for li in range(n_layers):
+                hp, qp, cache = packed_paged_layer(
+                    li if _dequant or _mm else 0, layer(li), rotary,
+                    tables_a, ln, ql, rows, tuple(rwork),
+                    dkeys[li] if dkeys else None, hp, qp, caches[li])
+                new_caches.append(cache)
+            return tuple([hp[rows.back] if padded else hp[None]]
+                         + new_caches)
+        h = xa
+        for li in range(n_layers):
+            lw = layer(li)
+            resid = h
+            q, k, v = project(h, lw, li)
+            q, k = rope(q, k, rotary)
+            nh, hd = q.shape[2:]
             scale = 1.0 / math.sqrt(hd)
             # grouped-attention geometry: kv heads g, queries-per-group r
             # (r == 1 and g == nh for MHA; the einsums below serve both —
@@ -664,9 +844,6 @@ def fused_multi_transformer(
                 # scales with the sum of ACTUAL per-sequence KV blocks,
                 # not B x max_blocks, and a whole prompt chunk rides one
                 # kernel invocation next to the decode rows
-                from ....ops.pallas.paged_attention import (
-                    append_paged_kv, append_paged_kv_chunk,
-                    ragged_paged_attention)
                 # named for the device trace: `kv_write` is everything
                 # the append costs — the new rows stacked and scattered
                 # into the layer's [2, KVH, NB, BS, D] cache where it
@@ -780,54 +957,7 @@ def fused_multi_transformer(
                     vc = jax.lax.dynamic_update_slice_in_dim(
                         cache[1], vv.transpose(0, 2, 1, 3), 0, axis=2)
                     new_caches.append(jnp.stack([kc, vc]))
-            if _mm is not None:
-                attn = _mm(ctx.reshape(b * s, nh * hd), linw[li],
-                           "lin", li).reshape(b, s, -1)
-            else:
-                attn = ctx.reshape(b, s, nh * hd) @ dq(linw[li], "lin", li)
-            attn = tp_red(attn)
-            if linb and linb[li] is not None:
-                attn = attn + linb[li]
-            if training and dropout_rate:
-                keep = jax.random.bernoulli(
-                    dkeys[li], 1.0 - dropout_rate, attn.shape)
-                attn = jnp.where(keep, attn / (1.0 - dropout_rate), 0.0) \
-                    if mode == "upscale_in_train" else \
-                    jnp.where(keep, attn, 0.0)
-            h = resid * residual_alpha + attn
-            if not pre_layer_norm:
-                h = norm(h, lns[li], lnb[li] if lnb else None)
-            with jax.named_scope("ffn"):
-                resid2 = h
-                z2 = norm(h, flns[li], flnb[li] if flnb else None) \
-                    if pre_layer_norm else h
-                if _mm is not None:
-                    f1 = _mm(z2.reshape(b * s, -1), f1w[li], "f1",
-                             li).reshape(b, s, -1)
-                else:
-                    f1 = z2 @ dq(f1w[li], "f1", li)
-                if f1b and f1b[li] is not None:
-                    f1 = f1 + f1b[li]
-                if activation.endswith("glu"):
-                    a, g = jnp.split(f1, 2, axis=-1)
-                    act = jax.nn.silu if activation == "swiglu" \
-                        else jax.nn.gelu
-                    f1 = act(a) * g
-                elif activation == "relu":
-                    f1 = jax.nn.relu(f1)
-                else:
-                    f1 = jax.nn.gelu(f1)
-                if _mm is not None:
-                    f2 = _mm(f1.reshape(b * s, -1), f2w[li], "f2",
-                             li).reshape(b, s, -1)
-                else:
-                    f2 = f1 @ dq(f2w[li], "f2", li)
-                f2 = tp_red(f2)
-                if f2b and f2b[li] is not None:
-                    f2 = f2 + f2b[li]
-                h = resid2 * residual_alpha + f2
-                if not pre_layer_norm:
-                    h = norm(h, flns[li], flnb[li] if flnb else None)
+            h = finish(resid, ctx, lw, li, dkeys[li] if dkeys else None)
         return tuple([h] + new_caches)
 
     out = apply_op(
@@ -843,7 +973,7 @@ def fused_multi_transformer(
          # per-layer dropout keys as input leaves (vjp-cacheable +
          # trace-safe, like the other fused ops)
          [_random.fresh_key_tensor() for _ in range(n_layers)]
-         if training and dropout_rate else []),
+         if training and dropout_rate else [], _live_rows),
         {}, differentiable=bool(training) and not caches_in)
     outs = out if isinstance(out, tuple) else (out,)
     h = outs[0]

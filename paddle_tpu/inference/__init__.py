@@ -751,13 +751,48 @@ class FusedMultiTransformerEngine:
             by 1 + spec_k, NOT the chunk width, so a 256-token prefill
             chunk still pays for one lm_head position per slot.
             Padding columns of sel repeat a valid index; their samples
-            are computed and ignored."""
+            are computed and ignored.
+
+            A WIDE slab (B x C > ROW_TILE rows; one or two slots
+            prefill a chunk, the others decode one token) computes its
+            live rows only: `live_rows(qlens, C)` packs them to the
+            front of a [B x C]-row buffer, and the embedding gather
+            here, like every row-wise layer of the stack, walks
+            ceil(qlens.sum() / ROW_TILE) tiles of it — a trip count
+            read on the device, so arguments, shapes and buckets are
+            what they were. A slab of at most ROW_TILE rows is one tile
+            whatever is live: straight-line code, no packing."""
+            logits, caches = paged_logits(w, caches, toks, qlens, sel,
+                                          tables, lens, rwork, rpack)
+            with jax.named_scope("sampler"):
+                toks_out = select(logits, temp, topp, key)
+            return toks_out, caches
+
+        def paged_logits(w, caches, toks, qlens, sel, tables, lens, rwork,
+                         rpack):
+            """`paged_step` up to its sampler: the logits [B, W, V] at
+            the slab columns `sel` names, and the appended caches."""
+            from ..ops.pallas.paged_attention import (
+                ROW_TILE, live_rows, over_row_tiles, put_row_tile,
+                row_tile)
             if tp_dequant is not None:
                 # quantized tensor-parallel serving: reconstruct this
                 # device's dense weight shards from the packed bytes +
                 # scales (runs inside the shard_map body, on shards)
                 w = tp_dequant(w)
-            h = w["embedding"][toks]             # [B, C, E]
+            emb = w["embedding"]
+            rows = None
+            if toks.shape[0] * toks.shape[1] > ROW_TILE:
+                rows = live_rows(qlens, toks.shape[1])
+                ids = toks[rows.slot, rows.col]              # [R]
+                h = over_row_tiles(
+                    rows.n_tiles,
+                    lambda r0, h: put_row_tile(
+                        h, emb[row_tile(ids, r0)], r0),
+                    jnp.zeros((ids.shape[0], emb.shape[1]),
+                              emb.dtype))[None]              # [1, R, E]
+            else:
+                h = emb[toks]                                # [B, C, E]
             from ..core.tensor import Tensor
             cts = [Tensor(c) for c in caches]
             out = fused_multi_transformer(
@@ -766,14 +801,15 @@ class FusedMultiTransformerEngine:
                 seq_lens=Tensor(lens), chunk_lens=Tensor(qlens),
                 rotary_embs=w.get("rotary_embs"),
                 block_tables=tables, ragged_work=rwork,
-                ragged_pack=rpack, **paged_kw)
+                ragged_pack=rpack, _live_rows=rows, **paged_kw)
             with jax.named_scope("head"):
-                bidx = jnp.arange(out.data.shape[0])
-                picked = out.data[bidx[:, None], sel]    # [B, W, E]
-                logits = picked @ w["lm_head"]           # [B, W, V]
-            with jax.named_scope("sampler"):
-                toks_out = select(logits, temp, topp, key)
-            return toks_out, [c.data for c in cts]
+                bidx = jnp.arange(toks.shape[0])[:, None]
+                if rows is None:
+                    picked = out.data[bidx, sel]             # [B, W, E]
+                else:
+                    picked = out.data[0][rows.back[bidx, sel]]
+                logits = picked @ w["lm_head"]               # [B, W, V]
+            return logits, [c.data for c in cts]
 
         def paged_copy(caches, src_block, dst_block):
             """Duplicate one physical cache block across every layer in
@@ -874,6 +910,9 @@ class FusedMultiTransformerEngine:
             jit_paged_copy = jax.jit(paged_copy_tp, donate_argnums=(0,))
         self._paged_step = _dispatch_span(
             "paged_step", jit_paged_step, static_argnums=(8,))
+        # the step up to its sampler, as traced (per device under tp):
+        # what tests/test_paged_live_rows.py holds to a plain computation
+        self._paged_logits = paged_logits
         self._paged_rewind = _dispatch_span(
             "paged_rewind", jit_paged_rewind, static_argnums=(4,))
         self._paged_copy = _dispatch_span("paged_copy", jit_paged_copy)
@@ -980,20 +1019,23 @@ class FusedMultiTransformerEngine:
         return (self._n_layers * 2 * (kvh // self.tp) * int(block_size)
                 * paged_head_dim(self.head_dim) * itemsize)
 
-    def tp_step_comm_bytes(self, batch, width):
+    def tp_step_comm_bytes(self, batch, width, live=None):
         """Analytic per-step collective payload of the TP paged step:
-        two row-parallel psums per layer, each reducing a
-        [batch, width, E] partial activation — the aval math the
-        serving loop hands the comm-task registry so
-        `collective_bytes_total{op="psum",axis="tp"}` attributes the
+        two row-parallel psums per layer, each reducing the partial
+        activations of the rows the step computes — the [batch, width]
+        slab, or a wide slab's `live` tokens' row tiles (`step_rows`)
+        — the aval math the serving loop hands the comm-task registry
+        so `collective_bytes_total{op="psum",axis="tp"}` attributes the
         step's comms cost without a device round trip. 0 when tp == 1
         (no collectives in the program)."""
         if self.tp <= 1:
             return 0
         import jax.numpy as jnp
+        from ..ops.pallas.paged_attention import step_rows
         e = int(self._w["embedding"].shape[1])
         itemsize = jnp.dtype(self._dtype).itemsize
-        return 2 * self._n_layers * int(batch) * int(width) * e * itemsize
+        return (2 * self._n_layers * step_rows(int(batch), int(width), live)
+                * e * itemsize)
 
     def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
                  top_p=1.0, seed=None, prompt_lens=None):

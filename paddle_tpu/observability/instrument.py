@@ -96,8 +96,9 @@ def serve_slab_tokens():
     return get_registry().counter(
         "serve_slab_tokens_total",
         help="token slab of the chunk steps: live (sum of the slots' "
-             "q_lens) vs capacity (max_batch x slab width) — live over "
-             "capacity is how full the padded slab ran",
+             "q_lens) vs capacity (the rows the row-wise layers computed: "
+             "max_batch x slab width, or a wide slab's live row tiles) — "
+             "live over capacity is how full the computed rows ran",
         labels=("kind",))      # bounded: live | capacity
 
 

@@ -70,6 +70,7 @@ shard_map'd paged programs).
 """
 import functools
 import math
+import typing
 
 import numpy as np
 
@@ -850,12 +851,95 @@ def ragged_paged_attention_reference(q, k_cache, v_cache, block_tables,
 
 
 # ---------------------------------------------------------------------------
+# the live rows of a wide step
+# ---------------------------------------------------------------------------
+#
+# A chunk step's slab is [B, C] tokens of which qlens[b] columns per slot
+# are live: one or two slots prefill a chunk while the others decode one
+# token, so a 16 x 128 slab carries ~160 live tokens. Everything a step
+# does per token (embedding, norms, projections, rope, the cache append,
+# the feed-forward) is row-wise, so a wide step packs its live tokens to
+# the front of a [B * C]-row buffer and walks ROW_TILE rows at a time,
+# ceil(n_live / ROW_TILE) times: a trip count read on the device, so the
+# step's arguments, shapes and compile buckets do not know about it. Only
+# attention keeps the slab's [B, C] geometry (`LiveRows.back` lays the
+# packed q rows into it, `slot`/`col` read ctx back).
+
+# Rows per tile: the v5e's ridge. A bf16 weight streamed once from HBM at
+# 819 GB/s pays for ~240 rows of matmul at 197 TFLOP/s, so a 256-row tile
+# costs about what one row costs, and more tiles cost no more than the
+# padded matmul did. A slab of at most ROW_TILE rows (every decode bucket,
+# every speculative span) is one tile whatever is live in it: such a step
+# is straight-line code with no packing and no loop.
+ROW_TILE = 256
+
+
+class LiveRows(typing.NamedTuple):
+    """The packed order of a [B, C] slab's live tokens: slot-major,
+    column-ascending, padded to whole tiles (R = ceil(B*C / ROW_TILE)
+    tiles' rows). Indices of dead rows are clamped into range: they read
+    some other row's data and their results are never used."""
+    n_tiles: jax.Array   # [] tiles that hold a live row
+    slot: jax.Array      # [R] the slab row of packed row r
+    col: jax.Array       # [R] its slab column
+    live: jax.Array      # [R] bool, r < n_live
+    back: jax.Array      # [B, C] the packed row of slab cell (b, j)
+
+
+def live_rows(q_lens, width):
+    """`LiveRows` of a [B, width] slab whose slot b holds q_lens[b] live
+    columns (0 parks the slot)."""
+    ql = jnp.asarray(q_lens, jnp.int32).reshape(-1)
+    b = ql.shape[0]
+    r = jnp.arange(-(-b * width // ROW_TILE) * ROW_TILE)
+    ends = jnp.cumsum(ql)
+    offset = ends - ql
+    slot = jnp.minimum(jnp.sum(r[:, None] >= ends[None, :], axis=1), b - 1)
+    return LiveRows(
+        n_tiles=-(-ends[-1] // ROW_TILE), slot=slot,
+        col=jnp.clip(r - offset[slot], 0, width - 1), live=r < ends[-1],
+        back=jnp.minimum(offset[:, None] + jnp.arange(width)[None, :],
+                         r.shape[0] - 1))
+
+
+def step_rows(batch, width, n_live=None):
+    """Rows the row-wise layers of one paged step compute for a
+    [batch, width] slab holding n_live live tokens (None: every cell):
+    the whole slab where it is one tile, else the tiles `live_rows`
+    finds a live row in. Host arithmetic, for the scheduler's counters."""
+    rows = batch * width
+    if rows <= ROW_TILE:
+        return rows
+    return -(-(rows if n_live is None else n_live) // ROW_TILE) * ROW_TILE
+
+
+def over_row_tiles(n_tiles, body, carry):
+    """carry = body(r0, carry) for each tile that holds a live row, r0
+    the tile's first packed row. Tiles past n_tiles are never computed."""
+    return jax.lax.fori_loop(
+        0, n_tiles, lambda i, c: body(i * ROW_TILE, c), carry)
+
+
+def row_tile(a, r0):
+    """The ROW_TILE rows of a packed [R, ...] array from row r0."""
+    return jax.lax.dynamic_slice_in_dim(a, r0, ROW_TILE)
+
+
+def put_row_tile(buf, new, r0):
+    """`buf` with the tile from row r0 replaced by `new` (in place where
+    `buf` is a loop carry)."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        buf, new.astype(buf.dtype), r0, 0)
+
+
+# ---------------------------------------------------------------------------
 # the cache writers
 # ---------------------------------------------------------------------------
 #
 # Two operand forms, one contract. The ENGINE's form is one layer's
 # stacked cache [2, KVH, NB, BS, Dc] (`append_paged_kv`,
-# `append_paged_kv_chunk`, `truncate_paged_kv`, `copy_paged_kv`): rows
+# `append_paged_kv_chunk`, `append_paged_kv_rows` for a wide step's
+# packed tiles, `truncate_paged_kv`, `copy_paged_kv`): rows
 # are scattered into that buffer itself and the buffer is the result, so
 # a jitted program that donates it never reads or writes a whole cache
 # to append a row — `tests/test_attention_ragged_paged.py`
@@ -911,6 +995,21 @@ def append_paged_kv_chunk(cache, k_new, v_new, block_tables, context_lens,
     rows = jnp.stack([_lane_pad(k_new, d), _lane_pad(v_new, d)])
     return _write_span(cache, rows, block_tables, context_lens,
                        context_lens + valid_counts, k_new.shape[1])
+
+
+def append_paged_kv_rows(cache, k_rows, v_rows, block_tables, slot, pos,
+                         live):
+    """Append one tile of a wide step's PACKED rows ([R, KVH, D], in
+    `live_rows` order) into one layer's stacked cache: row r lands at
+    position pos[r] of sequence slot[r]. A one-column span per row, so
+    the scatter walks 2 x R x KVH index rows however wide the slab is.
+
+    Boundary contract: a row that is not `live`, or whose position falls
+    at/after the table capacity, is DROPPED (see above)."""
+    d = cache.shape[-1]
+    new = jnp.stack([_lane_pad(k_rows, d), _lane_pad(v_rows, d)])
+    return _write_span(cache, new[:, :, None],
+                       jnp.asarray(block_tables)[slot], pos, pos + live, 1)
 
 
 def append_paged_kv(cache, k_new, v_new, block_tables, context_lens):

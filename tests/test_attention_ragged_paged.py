@@ -448,16 +448,19 @@ class TestContinuousBatching:
         assert cb.allocator.num_free == 8
 
 
-def _eqns(jaxpr):
+def _eqns(jaxpr, path=None):
     """Every equation of a jaxpr and of the jaxprs nested in its
-    equations' parameters (pjit, shard_map, custom calls, branches)."""
+    equations' parameters (pjit, shard_map, custom calls, branches).
+    With `path` given (start it at ()), pairs of an equation and the
+    names of the primitives that enclose it."""
     for eqn in jaxpr.eqns:
-        yield eqn
+        yield eqn if path is None else (eqn, path)
+        inner = None if path is None else path + (eqn.primitive.name,)
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
+                    yield from _eqns(sub, inner)
 
 
 class TestStepNeverCopiesTheCache:
@@ -471,12 +474,12 @@ class TestStepNeverCopiesTheCache:
 
     COPYING = ("concatenate", "slice", "pad", "squeeze")
 
-    def _traced(self, width):
+    def _traced(self, width, max_batch=2):
         from paddle_tpu.incubate.nn import (ContinuousBatchingEngine,
                                             GenerationRequest)
         eng, V = _tiny_engine()
         cb = ContinuousBatchingEngine(eng, num_blocks=9, block_size=8,
-                                      max_batch=2, prefill_chunk=8)
+                                      max_batch=max_batch, prefill_chunk=8)
         real, seen = eng._paged_step, {}
 
         def record(*args):
@@ -495,9 +498,14 @@ class TestStepNeverCopiesTheCache:
             w, cb.caches, np.zeros((slab.shape[0], width), slab.dtype),
             q_arr, sel, tables, lens, work, pack, temp, topp, key)
 
-    @pytest.mark.parametrize("width", [1, 8], ids=["decode", "chunk"])
-    def test_no_cache_sized_copy_and_results_alias_arguments(self, width):
-        cb, traced = self._traced(width)
+    # 8 slots x 64 columns = 512 rows, two row tiles: a WIDE step, whose
+    # row-wise layers loop over the live rows' tiles
+    WIDE = dict(width=64, max_batch=8)
+
+    @pytest.mark.parametrize("shape", [dict(width=1), dict(width=8), WIDE],
+                             ids=["decode", "chunk", "wide"])
+    def test_no_cache_sized_copy_and_results_alias_arguments(self, shape):
+        cb, traced = self._traced(**shape)
         cache = tuple(cb.caches[0].shape)
         assert len(cache) == 5 and cache[0] == 2
         sized = (cache, cache[1:])
@@ -516,3 +524,47 @@ class TestStepNeverCopiesTheCache:
         assert seen_scatter == len(cb.caches)
         text = traced.lower().as_text()
         assert text.count("tf.aliasing_output") == len(cb.caches)
+
+    def test_wide_step_has_no_slab_sized_matmul_or_scatter(self):
+        """Outside the two loops of a layer nothing multiplies or
+        scatters B x C rows (or the 2 x B x C x KVH index rows of the
+        padded writer); inside them a tile's ROW_TILE rows do. The
+        layers are calls of one traced function."""
+        cb, traced = self._traced(**self.WIDE)
+        slab = self.WIDE["max_batch"] * self.WIDE["width"]
+        kvh = cb.caches[0].shape[1]
+        heavy = {"dot_general", "scatter"}
+        rows_in_loop, loops, layer_fns = set(), 0, set()
+        for eqn, path in _eqns(traced.jaxpr.jaxpr, ()):
+            loops += eqn.primitive.name == "while"
+            if eqn.params.get("name") == "packed_paged_layer":
+                layer_fns.add(id(eqn.params["jaxpr"]))
+            if eqn.primitive.name not in heavy or "pallas_call" in path:
+                continue    # the ragged kernel keeps the slab's geometry
+            shapes = [tuple(getattr(v.aval, "shape", ()))
+                      for v in list(eqn.invars) + list(eqn.outvars)]
+            if "while" in path:
+                rows_in_loop.update(d for s in shapes for d in s)
+                continue
+            for s in shapes:
+                assert slab not in s and 2 * slab * kvh not in s, (
+                    f"{eqn.primitive.name} over the whole slab: {shapes}")
+                assert s[:2] != (self.WIDE["max_batch"],
+                                 self.WIDE["width"]), shapes
+        assert pa.ROW_TILE in rows_in_loop
+        assert slab not in rows_in_loop
+        # the embedding's loop and two a layer (the walk enters each
+        # call), all of them the same function
+        assert loops == 1 + 2 * len(cb.caches) and len(layer_fns) == 1
+
+    @pytest.mark.parametrize("width", [1, 8, 16])
+    def test_one_tile_step_is_straight_line(self, width):
+        """A slab of at most ROW_TILE rows is one tile whatever is live
+        in it: no packing, no loop, one scatter a layer."""
+        cb, traced = self._traced(width, max_batch=8)
+        assert 8 * width <= pa.ROW_TILE
+        names = [e.primitive.name for e in _eqns(traced.jaxpr.jaxpr)]
+        assert "while" not in names
+        assert names.count("scatter") == len(cb.caches)
+        assert not any(e.params.get("name") == "packed_paged_layer"
+                       for e in _eqns(traced.jaxpr.jaxpr))

@@ -302,6 +302,13 @@ class TestMeshAccounting:
         eng = _engine(2)
         assert eng.tp_step_comm_bytes(4, 8) == 2 * L * 4 * 8 * E * 4
         assert _engine(1).tp_step_comm_bytes(4, 8) == 0
+        # a slab of more than ROW_TILE rows reduces its live tokens' row
+        # tiles only: 70 live of 8 x 64 is one tile, 257 are two
+        tile = pa.ROW_TILE
+        assert eng.tp_step_comm_bytes(4, 8, 3) == 2 * L * 4 * 8 * E * 4
+        assert eng.tp_step_comm_bytes(8, 64, 70) == 2 * L * tile * E * 4
+        assert eng.tp_step_comm_bytes(8, 64, tile + 1) \
+            == eng.tp_step_comm_bytes(8, 64) == 2 * L * 2 * tile * E * 4
 
     def test_collective_telemetry_lands(self):
         from paddle_tpu import observability as obs
