@@ -600,7 +600,7 @@ class ContinuousBatchingEngine:
                  tpot_slo=None, min_prefill_chunk=64, prefix_cache=False,
                  monitor=None, memory_watch=None, shed_on_pressure=False,
                  shed_priority_min=1, autotune_cache=None,
-                 host_fastpath=True, host_debug_check=False):
+                 host_debug_check=False):
         import jax
 
         self.engine = engine
@@ -754,42 +754,33 @@ class ContinuousBatchingEngine:
                 self.prefill_chunk = max(1, int(cfg["prefill_chunk"]))
         # host fast path (ISSUE 20): incremental work lists + in-place
         # step inputs. Built AFTER autotune so the builder bakes in the
-        # final pack. ON by default — every array it hands the compiled
-        # step is elementwise identical to the from-scratch build (the
-        # committed serving baselines stay byte-stable); OFF keeps the
-        # legacy per-step-rebuild path alive as the reference the debug
-        # cross-check and the host bench leg compare against.
-        self._host_fastpath = bool(host_fastpath)
+        # final pack. Every array it hands the compiled step is
+        # elementwise identical to the from-scratch `build_ragged_work`,
+        # which `host_debug_check` holds it to on every step.
         self._host_debug = bool(host_debug_check) or bool(
             os.environ.get("PADDLE_TPU_HOST_DEBUG_CHECK"))
         self._work_builder = RaggedWorkBuilder(
-            self.max_batch, self.max_blocks, self.block_size,
-            self._pack) if self._host_fastpath else None
+            self.max_batch, self.max_blocks, self.block_size, self._pack)
         # persistent step-input buffers, keyed by the same bucketed
         # widths that key the compiles — steady state allocates nothing
         self._slab_bufs = {}        # c -> [B, c] int32
         self._sel_bufs = {}         # w_sel -> [B, w_sel] int32
         self._q_arr_buf = np.zeros(self.max_batch, np.int32)
         self._attn_buf = np.zeros(self.max_batch, np.int32)
-        self._input_copy_bytes = 0  # engine-local mirror of the counter
         self._last_host_phases = {}
-        self._wb_last = (0, 0, 0, 0)    # registry-mirrored builder state
 
     def host_stats(self):
-        """Engine-local host-fast-path accounting (the process registry
+        """Engine-local host-step accounting (the process registry
         aggregates across engines; tests and serve_bench want THIS
         engine's numbers): work-segment reuse/rebuild and assembly-mode
-        counts from the work-list builder, step-input copy bytes, and
-        the last step's host-phase split in seconds."""
+        counts from the work-list builder, and the last step's
+        host-phase split in seconds."""
         wb = self._work_builder
         return {
-            "fastpath": self._host_fastpath,
-            "segments_reused": wb.segments_reused if wb else 0,
-            "segments_rebuilt": wb.segments_rebuilt if wb else 0,
-            "assemblies_full": wb.assemblies_full if wb else 0,
-            "assemblies_incremental":
-                wb.assemblies_incremental if wb else 0,
-            "input_copy_bytes": self._input_copy_bytes,
+            "segments_reused": wb.segments_reused,
+            "segments_rebuilt": wb.segments_rebuilt,
+            "assemblies_full": wb.assemblies_full,
+            "assemblies_incremental": wb.assemblies_incremental,
             "phases": dict(self._last_host_phases),
         }
 
@@ -940,13 +931,6 @@ class ContinuousBatchingEngine:
                 return True
         return False
 
-    def _count_input_bytes(self, n):
-        # the legacy per-step-rebuild path's copy bill: bytes freshly
-        # allocated for compiled-step inputs. The fast path never calls
-        # this — the "copy bytes drop to 0" half of the ISSUE-20 gate.
-        self._input_copy_bytes += int(n)
-        _metrics.serve_input_copy_bytes().inc(int(n))
-
     def _check_host_state(self, attn_lens, q_arr, work, t_total, pack):
         """Debug cross-check (host_debug_check=True, or the
         PADDLE_TPU_HOST_DEBUG_CHECK env var): the incremental work list
@@ -970,8 +954,7 @@ class ContinuousBatchingEngine:
         # segment is stale. Every table-writing site funnels through
         # here (admit / prefix match / COW / grow / rewind / preempt /
         # retire) — the dirty-slot schedule the host bench leg pins.
-        if self._work_builder is not None:
-            self._work_builder.mark_dirty(i)
+        self._work_builder.mark_dirty(i)
 
     def _finish_slot(self, i, status, reason=None):
         """Terminal retirement of slot i, whatever the cause: free its
@@ -1688,20 +1671,16 @@ class ContinuousBatchingEngine:
         # the programs they key — stay off the per-prompt-length
         # treadmill. Idle slots and budget-starved prefill slots have
         # q_len 0: zero slab tokens, zero work entries, output ignored.
-        # Fast path: per-width persistent buffers zero-filled in place —
-        # a steady-state step allocates nothing (a fresh width keys a
+        # Per-width persistent buffers zero-filled in place — a
+        # steady-state step allocates nothing (a fresh width keys a
         # fresh compile anyway, so buffer creation rides warmup).
         c = int(next_pow2(int(q_lens.max())))
-        if self._host_fastpath:
-            slab = self._slab_bufs.get(c)
-            if slab is None:
-                slab = np.zeros((self.max_batch, c), np.int32)
-                self._slab_bufs[c] = slab
-            else:
-                slab.fill(0)
-        else:
+        slab = self._slab_bufs.get(c)
+        if slab is None:
             slab = np.zeros((self.max_batch, c), np.int32)
-            self._count_input_bytes(slab.nbytes)
+            self._slab_bufs[c] = slab
+        else:
+            slab.fill(0)
         prefilling = False      # some slot consumes prompt this step
         for i in active:
             req = self.slots[i]
@@ -1725,16 +1704,12 @@ class ContinuousBatchingEngine:
         # function of c and the engine-static spec_k, so the (t_total,
         # c) bucket pair still keys every compile.
         w_sel = min(c, 1 + self.spec_k)
-        if self._host_fastpath:
-            sel = self._sel_bufs.get(w_sel)
-            if sel is None:
-                sel = np.zeros((self.max_batch, w_sel), np.int32)
-                self._sel_bufs[w_sel] = sel
-            else:
-                sel.fill(0)
-        else:
+        sel = self._sel_bufs.get(w_sel)
+        if sel is None:
             sel = np.zeros((self.max_batch, w_sel), np.int32)
-            self._count_input_bytes(sel.nbytes)
+            self._sel_bufs[w_sel] = sel
+        else:
+            sel.fill(0)
         for i in active:
             req = self.slots[i]
             n = int(q_lens[i])
@@ -1744,28 +1719,18 @@ class ContinuousBatchingEngine:
                 sel[i, 0] = n - 1
             else:
                 sel[i, :n] = np.arange(n)
-        if self._host_fastpath:
-            # in-place step inputs: the persistent int32 views mutate
-            # under np.copyto/np.add, and the work list assembles
-            # incrementally — only slots the dirty schedule touched
-            # rebuild their segments (RaggedWorkBuilder)
-            q_arr = self._q_arr_buf
-            q_arr[:] = q_lens
-            attn_lens = self._attn_buf
-            np.add(self.lens, q_arr, out=attn_lens)
-            work, _, t_total, pack = self._work_builder.build(
-                self.tables, attn_lens, q_arr)
-            if self._host_debug:
-                self._check_host_state(attn_lens, q_arr, work, t_total,
-                                       pack)
-        else:
-            q_arr = q_lens.astype(np.int32)
-            attn_lens = (self.lens + q_arr).astype(np.int32)
-            work, _, t_total, pack = build_ragged_work(
-                self.tables, attn_lens, self.block_size, self._pack,
-                bucket_to=next_pow2, q_lens=q_arr)
-            self._count_input_bytes(q_arr.nbytes + attn_lens.nbytes
-                                    + sum(a.nbytes for a in work))
+        # in-place step inputs: the persistent int32 views mutate
+        # under np.copyto/np.add, and the work list assembles
+        # incrementally — only slots the dirty schedule touched
+        # rebuild their segments (RaggedWorkBuilder)
+        q_arr = self._q_arr_buf
+        q_arr[:] = q_lens
+        attn_lens = self._attn_buf
+        np.add(self.lens, q_arr, out=attn_lens)
+        work, _, t_total, pack = self._work_builder.build(
+            self.tables, attn_lens, q_arr)
+        if self._host_debug:
+            self._check_host_state(attn_lens, q_arr, work, t_total, pack)
         # the (padded work-list length, slab width) pair is the ONLY
         # shape the scheduler varies step to step — a pair not seen
         # before keys a fresh compile of the step program
@@ -1972,7 +1937,7 @@ class ContinuousBatchingEngine:
         self._maybe_shrink_chunk()
         # what step() pays for its own instrumentation (ROADMAP D7):
         # histogram observes, gauges, the monitor's and the memory
-        # watch's ticks, the work-builder mirror
+        # watch's ticks
         with _tracing.annotation("serve.telemetry"):
             _metrics.serve_step_seconds().observe(dur)
             # decode against chunk steps, which the tail of the gap
@@ -1993,12 +1958,6 @@ class ContinuousBatchingEngine:
                 pc_done - pc_step)
             if emitted:
                 _metrics.serve_tokens_total().inc(emitted)
-                _metrics.serve_tokens_per_s().set(
-                    emitted / dur if dur > 0 else 0.0)
-            # set even at 0 (a prefill-bound step emits nothing): a stale
-            # nonzero reading would overstate throughput exactly when the
-            # engine is prompt-bound
-            _metrics.serve_effective_tokens_per_step().set(emitted)
             # host-side cadence hooks: registry sample + burn-rate pass
             # when the monitor's cadence elapsed, a monotonic compare
             # otherwise — AFTER the step's own metrics landed, so a
@@ -2021,25 +1980,6 @@ class ContinuousBatchingEngine:
             hp.labels(phase="dispatch").observe(phases["dispatch"])
             hp.labels(phase="fetch").observe(phases["fetch"])
             hp.labels(phase="commit").observe(phases["commit"])
-            wb = self._work_builder
-            if wb is not None:
-                # registry mirror of the builder's monotonic counters: inc
-                # by this step's delta so the process-wide families stay
-                # exact sums across engines
-                last = self._wb_last
-                cur = (wb.segments_reused, wb.segments_rebuilt,
-                       wb.assemblies_incremental, wb.assemblies_full)
-                segs = _metrics.serve_work_segments()
-                if cur[0] > last[0]:
-                    segs.labels(event="reused").inc(cur[0] - last[0])
-                if cur[1] > last[1]:
-                    segs.labels(event="rebuilt").inc(cur[1] - last[1])
-                asm = _metrics.serve_work_assemblies()
-                if cur[2] > last[2]:
-                    asm.labels(mode="incremental").inc(cur[2] - last[2])
-                if cur[3] > last[3]:
-                    asm.labels(mode="full").inc(cur[3] - last[3])
-                self._wb_last = cur
         return len(self.queue) + self.num_active
 
     def _rewind_blocks(self, i, new_end):

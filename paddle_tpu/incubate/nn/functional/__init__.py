@@ -313,10 +313,18 @@ def block_multihead_attention(qkv, k_cache, v_cache, block_tables,
     block_multi_head_attention_kernel.cu). qkv: [B, 3, H, D] for the new
     token; caches [KVH, num_blocks, block_size, D] (KVH == H or a divisor
     for GQA — the kv slice of qkv uses heads [0:KVH]). Appends the token,
-    then attends via the Pallas paged kernel. Returns
-    (out [B, H, D], k_cache, v_cache)."""
-    from ....ops.pallas.paged_attention import (paged_attention,
-                                               update_paged_kv_cache)
+    then attends via the ragged Pallas kernel. Returns
+    (out [B, H, D], k_cache, v_cache).
+
+    The reference API's separate halves are stacked into the serving
+    path's one [2, KVH, NB, BS, D] buffer for the call and sliced back
+    out of it: a copy of the cache each way, which no serving step pays
+    (the engine keeps the stacked buffer). The work list is built from
+    concrete `context_lens`, so this is an eager call; a jitted caller
+    builds `build_ragged_work` on the host and calls
+    `ragged_paged_attention` itself."""
+    from ....ops.pallas.paged_attention import (append_paged_kv,
+                                               ragged_paged_attention)
 
     def impl(qkv_a, kc, vc, tables, lens):
         kvh = kc.shape[0]
@@ -324,9 +332,11 @@ def block_multihead_attention(qkv, k_cache, v_cache, block_tables,
         if q.shape[1] != kvh:
             k_new = k_new[:, :kvh]
             v_new = v_new[:, :kvh]
-        kc, vc = update_paged_kv_cache(kc, vc, k_new, v_new, tables, lens)
-        out = paged_attention(q, kc, vc, tables, lens + 1, scale=scale)
-        return out, kc, vc
+        cache = append_paged_kv(jnp.stack([kc, vc]), k_new, v_new, tables,
+                                lens)
+        out = ragged_paged_attention(q, cache, tables, lens + 1,
+                                     scale=scale)
+        return out, cache[0], cache[1]
 
     return apply_op("block_multihead_attention", impl,
                     (qkv, k_cache, v_cache, block_tables, context_lens),
@@ -343,11 +353,12 @@ def block_kv_cache_rewind(k_cache, v_cache, block_tables, new_lens,
     (k_cache, v_cache). The serving engine batches all slots' rewinds
     into one call of this per step (FusedMultiTransformerEngine's
     `_paged_rewind` applies it to every layer in one jitted program)."""
-    from ....ops.pallas.paged_attention import truncate_paged_kv_cache
+    from ....ops.pallas.paged_attention import truncate_paged_kv
     span = int(max_span)
 
     def impl(kc, vc, tables, nl, ol):
-        return truncate_paged_kv_cache(kc, vc, tables, nl, ol, span)
+        cache = truncate_paged_kv(jnp.stack([kc, vc]), tables, nl, ol, span)
+        return cache[0], cache[1]
 
     return apply_op("block_kv_cache_rewind", impl,
                     (k_cache, v_cache, block_tables, new_lens, old_lens),
@@ -434,17 +445,6 @@ class _LayerWeights(typing.NamedTuple):
     f2_b: object
 
 
-def _ragged_group_q(qkv_weights, gqa_group_size, trans_qkvw):
-    """Queries per kv head, recovered from the packed qkv weight layout
-    (needed to pick the ragged kernel's default pack factor)."""
-    w0 = qkv_weights[0]
-    shape = (w0.data if hasattr(w0, "data") else w0).shape
-    if gqa_group_size and gqa_group_size > 0:
-        ht = shape[0] if trans_qkvw else shape[1]
-        return (ht - 2 * gqa_group_size) // gqa_group_size
-    return 1
-
-
 def fused_multi_transformer(
         x, ln_scales, ln_biases, qkv_weights, qkv_biases, linear_weights,
         linear_biases, ffn_ln_scales, ffn_ln_biases, ffn1_weights,
@@ -468,9 +468,7 @@ def fused_multi_transformer(
     On TPU the per-layer chain is a jnp composition XLA fuses into the
     matmuls (the epilogue fusions the CUDA kernel hand-writes); decode
     attention over the contiguous [2, B, H, S_max, D] cache is a masked
-    einsum the TPU executes from VMEM. The paged-cache serving path is
-    `block_multihead_attention` (Pallas decode kernel,
-    ops/pallas/paged_attention.py).
+    einsum the TPU executes from VMEM.
 
     Shapes (trans_qkvw=True, the reference default):
     x [B, S, E]; qkv_weight [3, H, D, E]; linear_weight [H*D, E];
@@ -485,17 +483,15 @@ def fused_multi_transformer(
     attention runs the ragged Pallas kernel
     (ops/pallas/paged_attention.ragged_paged_attention) after appending
     the new token at slot seq_lens. `ragged_work` is the host-built
-    flattened work list (`build_ragged_work(tables, seq_lens + 1, ...)`
-    — +1 because attention covers the token just appended); required
-    under jit where seq_lens is traced. x is [B, 1, E] with time_step
-    set (classic decode), or — CHUNKED PREFILL — [B, C, E] with
+    flattened work list, `build_ragged_work(tables, seq_lens +
+    chunk_lens, block_size, pack, q_lens=chunk_lens)`: attention covers
+    the tokens just appended. x is [B, C, E] with time_step set and
     `chunk_lens` [B] giving how many of each row's C token columns are
     valid this step: sequence b's chunk_lens[b] tokens append at
-    positions seq_lens[b].. and each attends causally to its own prefix
-    (the work list must then be built with
-    `build_ragged_work(tables, seq_lens + chunk_lens, ...,
-    q_lens=chunk_lens)`). chunk_lens[b] == 0 parks the row: nothing
-    written, nothing attended, output rows zero.
+    positions seq_lens[b].. and each attends causally to its own prefix.
+    chunk_lens[b] == 0 parks the row: nothing written, nothing attended,
+    output rows zero. Without `chunk_lens` every row holds one token
+    (classic decode, x [B, 1, E]).
 
     A WIDE paged step (B x C > ROW_TILE rows) computes its live rows
     only: the slab's chunk_lens.sum() live tokens are packed to the front
@@ -532,43 +528,19 @@ def fused_multi_transformer(
             raise ValueError(
                 "fused_multi_transformer: block_tables without cache_kvs "
                 "— the paged path needs the per-layer paged caches")
-        xs = (x.data if hasattr(x, "data") else x).shape
-        if len(xs) != 3 or (xs[1] != 1 and chunk_lens is None):
-            raise ValueError(
-                "fused_multi_transformer: paged decode takes one token "
-                f"per sequence (x [B, 1, E]); got {list(xs)} — a multi-"
-                "token chunk slab needs per-sequence chunk_lens")
         if attn_mask is not None:
             raise NotImplementedError(
                 "fused_multi_transformer: attn_mask unsupported on the "
                 "paged decode path")
         if ragged_work is None:
-            # eager convenience: build the work list from concrete lens
-            import numpy as _np
-            from ....ops.pallas.paged_attention import (build_ragged_work,
-                                                        default_pack)
-            from ....core.tensor import Tensor as _T
-            lens_c = _np.asarray(
-                seq_lens.data if isinstance(seq_lens, _T) else seq_lens)
-            tbl_c = _np.asarray(
-                block_tables.data if isinstance(block_tables, _T)
-                else block_tables)
-            c0 = cache_kvs[0]
-            bs_ = (c0.data if hasattr(c0, "data") else c0).shape[3]
-            if chunk_lens is None:
-                qls_c = _np.ones_like(lens_c)
-                qkw = {}
-            else:
-                qls_c = _np.asarray(
-                    chunk_lens.data if isinstance(chunk_lens, _T)
-                    else chunk_lens)
-                qkw = {"q_lens": qls_c}
-            ragged_work = build_ragged_work(
-                tbl_c, lens_c + qls_c, bs_,
-                ragged_pack or default_pack(
-                    lens_c.shape[0],
-                    _ragged_group_q(qkv_weights, gqa_group_size,
-                                    trans_qkvw)), **qkw)
+            raise ValueError(
+                "fused_multi_transformer: the paged path takes the host-"
+                "built work list — pass ragged_work=build_ragged_work("
+                "tables, seq_lens + chunk_lens, block_size, pack, "
+                "q_lens=chunk_lens)")
+        if chunk_lens is None:
+            chunk_lens = jnp.ones(
+                (x.data if hasattr(x, "data") else x).shape[0], jnp.int32)
         if len(ragged_work) == 4 and isinstance(ragged_work[0],
                                                 (tuple, list)):
             # the full build_ragged_work result: the carried pack is
@@ -797,11 +769,10 @@ def fused_multi_transformer(
         padded = False
         if tables_a is not None:
             from ....ops.pallas.paged_attention import (
-                ROW_TILE, append_paged_kv, append_paged_kv_chunk,
-                append_paged_kv_rows, live_rows, over_row_tiles,
-                put_row_tile, ragged_paged_attention, row_tile)
-            padded = (rows is None and qlens is not None
-                      and b * s > ROW_TILE)
+                ROW_TILE, append_paged_kv_chunk, append_paged_kv_rows,
+                live_rows, over_row_tiles, put_row_tile,
+                ragged_paged_attention, row_tile)
+            padded = rows is None and b * s > ROW_TILE
         if padded:
             # a wide slab handed over as [B, C, E]: packed here, and
             # handed back in the slab's geometry
@@ -837,7 +808,7 @@ def fused_multi_transformer(
             g_eff = G or nh
             r = nh // g_eff
             if tstep is not None and caches and tables_a is not None:
-                # paged decode (continuous batching): append this step's
+                # paged step (continuous batching): append this step's
                 # token (or prompt CHUNK) into the blocks owned by each
                 # sequence starting at slot seq_lens, then run the ragged
                 # Pallas kernel over the flattened work list — grid cost
@@ -856,27 +827,16 @@ def fused_multi_transformer(
                 cache = caches[li]             # [2, KVH, NB, BS, D]
                 ln = jnp.asarray(slens).reshape(-1)
                 work = (tuple(rwork), None, rwork[0].shape[0], ragged_pack)
-                if qlens is None:
-                    with jax.named_scope("kv_write"):
-                        cache = append_paged_kv(
-                            cache, k[:, 0], v[:, 0], tables_a, ln)
-                    with jax.named_scope("attention"):
-                        ctx = ragged_paged_attention(
-                            q[:, 0], cache, tables_a, ln + 1,
-                            scale=scale, work=work,
-                            buffer_depth=kv_buffer_depth)
-                        ctx = ctx[:, None].astype(xa.dtype)  # [B,1,H,D]
-                else:
-                    ql = jnp.asarray(qlens).reshape(-1)
-                    with jax.named_scope("kv_write"):
-                        cache = append_paged_kv_chunk(
-                            cache, k, v, tables_a, ln, ql)
-                    with jax.named_scope("attention"):
-                        ctx = ragged_paged_attention(
-                            q, cache, tables_a, ln + ql, scale=scale,
-                            work=work, q_lens=ql,
-                            buffer_depth=kv_buffer_depth
-                            ).astype(xa.dtype)            # [B, C, H, D]
+                ql = jnp.asarray(qlens).reshape(-1)
+                with jax.named_scope("kv_write"):
+                    cache = append_paged_kv_chunk(
+                        cache, k, v, tables_a, ln, ql)
+                with jax.named_scope("attention"):
+                    ctx = ragged_paged_attention(
+                        q, cache, tables_a, ln + ql, scale=scale,
+                        work=work, q_lens=ql,
+                        buffer_depth=kv_buffer_depth
+                        ).astype(xa.dtype)                # [B, C, H, D]
                 new_caches.append(cache)
             elif tstep is not None and caches:
                 # decode: append the new token, attend over the valid cache

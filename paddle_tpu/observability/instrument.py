@@ -18,11 +18,11 @@ __all__ = [
     "watch_ops", "serve_ttft", "serve_tpot", "serve_queue_wait",
     "serve_step_seconds", "dispatch_seconds", "serve_tokens_total",
     "serve_requests_total",
-    "serve_inflight", "serve_queue_depth", "serve_tokens_per_s",
+    "serve_inflight", "serve_queue_depth",
     "kv_blocks_free", "kv_blocks_used", "kv_blocks_high_water",
     "kv_alloc_failures", "serve_bucket_recompiles",
     "spec_draft_tokens", "spec_accepted_tokens", "spec_accept_len",
-    "serve_effective_tokens_per_step", "serve_prefill_chunk",
+    "serve_prefill_chunk",
     "prefix_cache_hits", "prefix_cache_misses", "prefix_cache_evictions",
     "prefix_cache_cow", "kv_blocks_shared", "kv_blocks_prefix_resident",
     "serve_preemptions", "serve_cancelled", "serve_shed",
@@ -40,8 +40,7 @@ __all__ = [
     "autotune_trials", "autotune_cache_hits", "autotune_cache_misses",
     "autotune_winner",
     "serve_host_phase_seconds", "serve_step_kind_seconds",
-    "serve_slab_tokens", "serve_work_segments",
-    "serve_work_assemblies", "serve_input_copy_bytes",
+    "serve_slab_tokens",
 ]
 
 
@@ -102,33 +101,6 @@ def serve_slab_tokens():
         labels=("kind",))      # bounded: live | capacity
 
 
-def serve_work_segments():
-    return get_registry().counter(
-        "serve_work_segments_total",
-        help="per-slot ragged work-list segments per step, by outcome: "
-             "reused (buffer entry already correct) vs rebuilt (slot "
-             "dirtied by admit/grow/COW/rewind/preempt/retire)",
-        labels=("event",))     # bounded: reused | rebuilt
-
-
-def serve_work_assemblies():
-    return get_registry().counter(
-        "serve_work_assemblies_total",
-        help="work-list assemblies by mode: incremental (layout + "
-             "bucket unchanged, only dirty segments rewritten) vs full "
-             "(re-laid out into the bucket buffer)",
-        labels=("mode",))      # bounded: incremental | full
-
-
-def serve_input_copy_bytes():
-    return get_registry().counter(
-        "serve_step_input_copy_bytes_total",
-        help="bytes freshly allocated/copied for compiled-step inputs "
-             "(slab, sel, work list, q/attn lens) — 0 in steady state "
-             "on the host fast path, nonzero only on the legacy "
-             "per-step-rebuild path")
-
-
 def dispatch_seconds():
     return get_registry().histogram(
         "dispatch_seconds",
@@ -155,12 +127,6 @@ def serve_inflight():
 def serve_queue_depth():
     return get_registry().gauge(
         "serve_queue_depth", help="submitted, not yet admitted")
-
-
-def serve_tokens_per_s():
-    return get_registry().gauge(
-        "serve_tokens_per_s",
-        help="tokens emitted by the last step / its host wall time")
 
 
 def kv_blocks_free():
@@ -434,13 +400,6 @@ def spec_accept_len(max_len=8):
         "serve_spec_accept_len",
         help="accepted-prefix length per verified draft span",
         buckets=tuple(float(i) for i in range(int(max_len) + 1)))
-
-
-def serve_effective_tokens_per_step():
-    return get_registry().gauge(
-        "serve_effective_tokens_per_step",
-        help="tokens emitted by the last compiled step (speculation "
-             "pushes this above the decode-slot count)")
 
 
 def serve_prefill_chunk():

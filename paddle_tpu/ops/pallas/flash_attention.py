@@ -6,9 +6,9 @@ python/paddle/nn/functional/flash_attention.py:358. Here the kernel is
 written for the TPU memory hierarchy: queries stream through VMEM in
 (BLOCK_Q x head_dim) tiles, keys/values in (BLOCK_K x head_dim) tiles, with
 the online-softmax running max/denominator kept in f32 VMEM scratch. The
-backward is the standard two-pass flash backward (dq pass gridded over query
-blocks; dkv pass gridded over key blocks) using the saved logsumexp; the
-softmax-grad correction term delta = rowsum(do*o) is recomputed in-kernel.
+backward is one fused pass gridded over (key block, query block) pairs
+(`_bwd_fused_kernel`) using the saved logsumexp; the softmax-grad
+correction term delta = rowsum(do*o) is recomputed in-kernel.
 
 The saved logsumexp is materialized as [BH, 8, S] f32 — the sequence dim
 rides the 128-lane axis, so the (8,128) tiling pads nothing. (The earlier
@@ -314,120 +314,6 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
-                   dq_acc, *, scale, causal, block_q, block_k, seq_len):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    q_start = qi * block_q
-    k_start = ki * block_k
-
-    def _update():
-        # bf16 dot operands / f32 accumulation (see _fwd_kernel note)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        o = o_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]              # [BQ, 1]
-        delta = jnp.sum(do * o, axis=1, keepdims=True)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        if seq_len % block_k:
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 1)
-            s = jnp.where(cols < seq_len, s, NEG_INF)
-        p = jnp.exp(s - lse)                         # [BQ, BK]
-        dp = jax.lax.dot_general(
-            do_ref[0], v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [BQ, BK]
-        ds = p * (dp - delta) * scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        pl.when(k_start <= q_start + block_q - 1)(_update)
-    else:
-        _update()
-
-    @pl.when(ki == nk - 1)
-    def _final():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale, causal, block_q, block_k, seq_len):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    q_start = qi * block_q
-    k_start = ki * block_k
-
-    def _update():
-        # bf16 dot operands / f32 accumulation (see _fwd_kernel note)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        o = o_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]              # [BQ, 1]
-        delta = jnp.sum(do * o, axis=1, keepdims=True)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        if seq_len % block_k:
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 1)
-            s = jnp.where(cols < seq_len, s, NEG_INF)
-        p = jnp.exp(s - lse)                         # [BQ, BK]
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(q.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [BK, D]
-        dp = jax.lax.dot_general(
-            do_ref[0], v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                # [BQ, BK]
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [BK, D]
-
-    if causal:
-        pl.when(k_start <= q_start + block_q - 1)(_update)
-    else:
-        _update()
-
-    @pl.when(qi == nq - 1)
-    def _final():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-
 def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                bwd_block_q=None, bwd_block_k=None, rope_cos=None,
                rope_sin=None):
@@ -481,76 +367,6 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         compiler_params=_cparams(),
     )(*operands)
     dq = dqp.sum(axis=0).astype(q.dtype)
-    return dq, dk, dv
-
-
-def _flash_bwd_twopass(q, k, v, o, lse, do, scale, causal, block_q,
-                       block_k, bwd_block_q=None, bwd_block_k=None,
-                       rope_cos=None, rope_sin=None):
-    """The pre-round-5 two-pass backward, kept for A/B measurement.
-    No rope support: refuse rather than silently compute unrotated
-    gradients (the A/B must be run with fuse_rope_in_attention off)."""
-    if rope_cos is not None:
-        raise NotImplementedError(
-            "_flash_bwd_twopass has no in-kernel rope; A/B with "
-            "fuse_rope_in_attention=False")
-    block_q = bwd_block_q or min(block_q, 512)
-    block_k = bwd_block_k or min(block_k, 1024)
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
-    nq = pl.cdiv(sq, block_q)
-    nk = pl.cdiv(sk, block_k)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=sk),
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, LSE_LANES, block_q), lambda b, i, j: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        name="flash_attn_bwd_dq",
-        interpret=_interpret_mode(),
-        compiler_params=_cparams(),
-    )(q, k, v, o, do, lse)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=sk),
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, LSE_LANES, block_q), lambda b, j, i: (b, 0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        name="flash_attn_bwd_dkv",
-        interpret=_interpret_mode(),
-        compiler_params=_cparams(),
-    )(q, k, v, o, do, lse)
     return dq, dk, dv
 
 

@@ -1,55 +1,44 @@
-"""Paged KV-cache decode attention as Pallas TPU kernels.
+"""Paged KV-cache serving attention as a Pallas TPU kernel.
 
 Reference: paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu
 (paged/block KV cache) and masked_multihead_attention_kernel.cu (decode
 attention) behind python/paddle/incubate/nn/functional
 block_multihead_attention (SURVEY.md §2.9).
 
-TPU-native shape: the KV cache lives in HBM as fixed-size blocks
-[KVH, num_blocks, block_size, Dc] per half; each sequence owns a list of
-block ids (block_tables [B, max_blocks]). The serving engine keeps a
-layer's K and V halves in ONE buffer [2, KVH, num_blocks, block_size, Dc]
-that its step program donates: the writers at the end of this file
-scatter new rows into it and the ragged kernel DMAs blocks out of it, so
-no program slices a half out or stacks two back. Dc is the head dim
-rounded up to the
-128-lane tile (`paged_head_dim`; the pad lanes hold zeros): Mosaic DMAs
-whole (sublane, 128) tiles, so a 64-wide row cannot be sliced out of HBM
+TPU-native shape: the KV cache lives in HBM as fixed-size blocks; each
+sequence owns a list of block ids (block_tables [B, max_blocks]). A
+layer's K and V halves are ONE buffer [2, KVH, num_blocks, block_size,
+Dc] that the engine's step program donates: the writers at the end of
+this file scatter new rows into it and the ragged kernel DMAs blocks out
+of it, so no program slices a half out or stacks two back. Dc is the
+head dim rounded up to the 128-lane tile (`paged_head_dim`; the pad
+lanes hold zeros): Mosaic DMAs whole (sublane, 128) tiles, so a 64-wide
+row cannot be sliced out of HBM
 ("Slice shape along dimension 3 must be aligned to tiling (128)"), and
 XLA lays a sub-128 minor dim out transposed, which would put a relayout
 copy of the whole cache around every kernel call.
 
-Two kernels:
+The kernel, `ragged_paged_attention` ("Ragged Paged Attention",
+PAPERS.md): the grid is flattened over a scalar-prefetched work list with
+one entry per ACTUAL cache block (length = sum of per-sequence block
+counts — no padding-block steps), the GQA query groups of `pack`
+co-scheduled sequences ride one [pack*G, D] VMEM tile so the MXU
+multiplies real sublanes, and consecutive KV-block loads are
+double-buffered by hand (two VMEM slots + DMA semaphores; step t waits
+slot t%2 after kicking off t+1's copy) so the next block streams from HBM
+while the current one is in the MXU.
 
-* `paged_attention` — the legacy A/B reference. Grid (batch, kv_head,
-  max_blocks): every sequence pays `max_blocks` grid steps even when it
-  owns two blocks, the padding steps DMA cache blocks just to mask them
-  out, and the MXU sees one [G, D] query group per step. Measured ~15x
-  slower than the dense slice-softmax path at B=8/ctx=448 (BASELINE.md
-  round 5).
-
-* `ragged_paged_attention` — the serving kernel ("Ragged Paged
-  Attention", PAPERS.md). The grid is flattened over a scalar-prefetched
-  work list with one entry per ACTUAL cache block (length = sum of
-  per-sequence block counts — no padding-block steps), the GQA query
-  groups of `pack` co-scheduled sequences ride one [pack*G, D] VMEM tile
-  so the MXU multiplies real sublanes, and consecutive KV-block loads are
-  double-buffered by hand (two VMEM slots + DMA semaphores; step t waits
-  slot t%2 after kicking off t+1's copy) so the next block streams from
-  HBM while the current one is in the MXU.
-
-  Each work entry carries its sequence's QUERY SPAN (q_start, q_len):
-  decode sequences span one token, prefill sequences a chunk of up to C
-  prompt tokens — so one kernel invocation serves a MIXED prefill+decode
-  batch, the Sarathi-style chunked-prefill step. Speculative decode rides
-  the same span: a decode sequence verifying K prompt-lookup drafts asks
-  for a 1+K span (its last real token plus the drafts), pays ONE kernel
-  invocation for all K+1 positions, and the host rolls rejected suffixes
-  back with `truncate_paged_kv`. The packed tile grows
-  to [pack*C*G, D] (C query positions per sequence) and each query row
-  is causally masked to its own absolute position, so a 512-token prompt
-  costs ceil(512/C) steps at C-row MXU intensity instead of 512 steps
-  at one row.
+Each work entry carries its sequence's QUERY SPAN (q_start, q_len):
+decode sequences span one token, prefill sequences a chunk of up to C
+prompt tokens — so one kernel invocation serves a MIXED prefill+decode
+batch, the Sarathi-style chunked-prefill step. Speculative decode rides
+the same span: a decode sequence verifying K prompt-lookup drafts asks
+for a 1+K span (its last real token plus the drafts), pays ONE kernel
+invocation for all K+1 positions, and the host rolls rejected suffixes
+back with `truncate_paged_kv`. The packed tile grows to [pack*C*G, D]
+(C query positions per sequence) and each query row is causally masked
+to its own absolute position, so a 512-token prompt costs ceil(512/C)
+steps at C-row MXU intensity instead of 512 steps at one row.
 
 The work list is built host-side (`build_ragged_work`) because the block
 allocator that owns the tables is host code anyway; under `jax.jit` the
@@ -80,101 +69,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import LANES, NEG_INF, _interpret_mode
-
-
-def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc, *, block_size, scale):
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    nb = pl.num_programs(2)
-
-    @pl.when(i == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc[...] = jnp.zeros_like(acc)
-
-    ctx_len = lens_ref[b]
-
-    @pl.when(i * block_size < ctx_len)
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32)          # [G, D]
-        k = k_ref[0, 0].astype(jnp.float32)          # [BS, D]
-        v = v_ref[0, 0].astype(jnp.float32)          # [BS, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [G, BS]
-        pos = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < ctx_len, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = jnp.broadcast_to(
-            corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-            l_scr.shape)
-        acc[...] = acc[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-
-    @pl.when(i == nb - 1)
-    def _final():
-        l = l_scr[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
-
-
-def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
-                    scale=None):
-    """Decode-step attention over a paged KV cache.
-
-    q:            [B, H, D] — one query token per sequence
-    k/v_cache:    [KVH, num_blocks, block_size, D]
-    block_tables: [B, max_blocks_per_seq] int32 cache-block ids
-    context_lens: [B] int32 valid cache length per sequence
-    returns       [B, H, D]
-    """
-    b, h, d = q.shape
-    kvh, nblocks, block_size, _ = k_cache.shape
-    g = h // kvh
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
-    max_nb = block_tables.shape[1]
-    qg = q.reshape(b, kvh, g, d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kvh, max_nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda bb, hh, ii, tables, lens: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda bb, hh, ii, tables, lens:
-                         (hh, tables[bb, ii], 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda bb, hh, ii, tables, lens:
-                         (hh, tables[bb, ii], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, g, d), lambda bb, hh, ii, tables, lens: (bb, hh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_size=block_size,
-                          scale=float(scale)),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
-        name="paged_attn_decode",
-        interpret=_interpret_mode(),
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      qg, k_cache, v_cache)
-    return out.reshape(b, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -351,7 +245,7 @@ class RaggedWorkBuilder:
 
     Counters (`segments_reused` / `segments_rebuilt` / `assemblies_*`)
     count ACTIVE slots only, so a steady-state decode step scores 100%
-    reuse — the number `serve_bench --host` pins.
+    reuse — the number `tests/test_host_fastpath.py` pins.
 
     The returned arrays are views of the persistent bucket buffer. jit
     does not copy a numpy argument at dispatch (an aligned buffer is
@@ -936,20 +830,17 @@ def put_row_tile(buf, new, r0):
 # the cache writers
 # ---------------------------------------------------------------------------
 #
-# Two operand forms, one contract. The ENGINE's form is one layer's
-# stacked cache [2, KVH, NB, BS, Dc] (`append_paged_kv`,
-# `append_paged_kv_chunk`, `append_paged_kv_rows` for a wide step's
-# packed tiles, `truncate_paged_kv`, `copy_paged_kv`): rows
-# are scattered into that buffer itself and the buffer is the result, so
-# a jitted program that donates it never reads or writes a whole cache
-# to append a row — `tests/test_attention_ragged_paged.py`
-# `TestStepNeverCopiesTheCache` pins that for the engine's paged step
-# (no slice, stack or pad of a cache or a half in its jaxpr, its cache
-# results aliased to its donated arguments). The REFERENCE API's form is
-# the separate halves [KVH, NB, BS, Dc] (`update_paged_kv_cache` and its
-# three siblings, behind `block_multihead_attention` /
-# `block_kv_cache_rewind`). Both go through `_write_span`, where the
-# index arithmetic and the boundary contract are written once:
+# One operand form, one contract: a layer's stacked cache
+# [2, KVH, NB, BS, Dc] (`append_paged_kv`, `append_paged_kv_chunk`,
+# `append_paged_kv_rows` for a wide step's packed tiles,
+# `truncate_paged_kv`, `copy_paged_kv`). Rows are scattered into that
+# buffer itself and the buffer is the result, so a jitted program that
+# donates it never reads or writes a whole cache to append a row —
+# `tests/test_attention_ragged_paged.py` `TestStepNeverCopiesTheCache`
+# pins that for the engine's paged step (no slice, stack or pad of a
+# cache or a half in its jaxpr, its cache results aliased to its donated
+# arguments). All go through `_write_span`, where the index arithmetic
+# and the boundary contract are written once:
 #   * a position at or past its row's `stop`, or at/after the table's
 #     capacity (max_blocks * block_size), is DROPPED — its block id is
 #     set to NB, past the pool, and the scatter's mode="drop" discards
@@ -958,14 +849,14 @@ def put_row_tile(buf, new, r0):
 #   * the block-table column read is clamped into the table.
 
 def _write_span(cache, rows, block_tables, start, stop, span):
-    """Write rows[..., b, j, h, :] into cache at position start[b] + j
-    of sequence b (kv head h), for j < span (a static int) — dropped
-    where the position is at/after stop[b] or the table's capacity.
-    cache [KVH, NB, BS, Dc] takes rows [B, span, KVH, Dc]; a stacked
-    [2, KVH, NB, BS, Dc] cache takes rows [2, B, span, KVH, Dc], the
-    half's index joining the same one scatter. `rows` may be a scalar.
-    Positions are distinct per (b, j), so writes never collide."""
-    kvh, nb, bs, _ = cache.shape[-4:]
+    """Write rows[i, b, j, h, :] into half i of the stacked cache
+    [2, KVH, NB, BS, Dc] at position start[b] + j of sequence b (kv head
+    h), for j < span (a static int) — dropped where the position is
+    at/after stop[b] or the table's capacity. rows is
+    [2, B, span, KVH, Dc] or a scalar; the half's index joins the one
+    scatter. Positions are distinct per (b, j), so writes never
+    collide."""
+    _, kvh, nb, bs, _ = cache.shape
     max_nb = block_tables.shape[1]
     pos = jnp.reshape(start, (-1, 1)) + jnp.arange(span)[None, :]  # [B, S]
     valid = (pos < jnp.reshape(stop, (-1, 1))) & (pos < max_nb * bs)
@@ -974,8 +865,7 @@ def _write_span(cache, rows, block_tables, start, stop, span):
     # scatter mode="drop": invalid rows aim past the cache and vanish
     blk_ids = jnp.where(valid, blk_ids, nb)
     idx = (jnp.arange(kvh), blk_ids[:, :, None], (pos % bs)[:, :, None])
-    if cache.ndim == 5:
-        idx = (jnp.arange(2)[:, None, None, None],) + idx
+    idx = (jnp.arange(2)[:, None, None, None],) + idx
     return cache.at[idx].set(rows, mode="drop")
 
 
@@ -1028,8 +918,7 @@ def truncate_paged_kv(cache, block_tables, new_lens, old_lens, max_span):
     old_lens[b]-1 of every sequence — the KV a rejected speculative
     draft span left behind. `max_span` (static python int) bounds
     old_lens - new_lens, so the scatter keeps a jit-compatible static
-    shape; rows where new_lens == old_lens are a no-op. (A separate
-    half [KVH, NB, BS, Dc] is served as well.)
+    shape; rows where new_lens == old_lens are a no-op.
 
     Zeroing (rather than just rolling the host length back) keeps the
     strong invariant the serving tests lean on: a speculated-then-rewound
@@ -1049,49 +938,12 @@ def copy_paged_kv(cache, src_block, dst_block):
     that must append into a block other requests still read gets a
     private copy first; the shared original stays byte-identical for its
     remaining readers, so prefix sharing never rests on
-    overwrite-ordering reasoning. (Blocks are the third axis from the
-    end, so a separate half [KVH, NB, BS, Dc] is served as well.)
+    overwrite-ordering reasoning.
 
     Boundary contract: both block ids are data from the host allocator,
     so the gather side is CLAMPED into the pool and the scatter side
     uses mode="drop" — an out-of-pool id copies garbage nowhere instead
     of aliasing another sequence's KV."""
-    ax = cache.ndim - 3
-    src = jnp.minimum(src_block, cache.shape[ax] - 1)   # clamp the gather
-    row = jax.lax.dynamic_index_in_dim(cache, src, axis=ax, keepdims=False)
-    return cache.at[(slice(None),) * ax + (dst_block,)].set(
-        row, mode="drop")
-
-
-def update_paged_kv_cache_chunk(k_cache, v_cache, k_new, v_new,
-                                block_tables, context_lens, valid_counts):
-    """`append_paged_kv_chunk` over separate halves
-    [KVH, NB, BS, Dc]: the reference API's operand form. Returns the
-    two updated halves."""
-    d = k_cache.shape[-1]
-    stop = context_lens + valid_counts
-    return tuple(
-        _write_span(cache, _lane_pad(new, d), block_tables, context_lens,
-                    stop, new.shape[1])
-        for cache, new in ((k_cache, k_new), (v_cache, v_new)))
-
-
-def update_paged_kv_cache(k_cache, v_cache, k_new, v_new, block_tables,
-                          context_lens):
-    """`append_paged_kv` over separate halves."""
-    return update_paged_kv_cache_chunk(
-        k_cache, v_cache, k_new[:, None], v_new[:, None], block_tables,
-        context_lens, jnp.ones_like(context_lens))
-
-
-def truncate_paged_kv_cache(k_cache, v_cache, block_tables, new_lens,
-                            old_lens, max_span):
-    """`truncate_paged_kv` over separate halves."""
-    return tuple(truncate_paged_kv(c, block_tables, new_lens, old_lens,
-                                   max_span) for c in (k_cache, v_cache))
-
-
-def copy_paged_kv_block(k_cache, v_cache, src_block, dst_block):
-    """`copy_paged_kv` over separate halves."""
-    return tuple(copy_paged_kv(c, src_block, dst_block)
-                 for c in (k_cache, v_cache))
+    src = jnp.minimum(src_block, cache.shape[2] - 1)    # clamp the gather
+    row = jax.lax.dynamic_index_in_dim(cache, src, axis=2, keepdims=False)
+    return cache.at[:, :, dst_block].set(row, mode="drop")

@@ -91,18 +91,6 @@ class TestRaggedKernel:
         np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
                                    atol=2e-3)
 
-    def test_legacy_kernel_close_to_dense(self):
-        # the A/B reference kernel on a ragged batch: it produces the
-        # same numbers, just over a B x max_blocks grid
-        lens = [1, 8 * 3, 5, 8 * 6, 13]
-        q, kc, vc, tables, lens = _setup(8, 4, lens, seed=2)
-        out = pa.paged_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
-            jnp.asarray(tables), jnp.asarray(lens))
-        ref = _dense_softmax_ref(q, kc, vc, tables, lens)
-        np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
-                                   atol=2e-3)
-
     @pytest.mark.parametrize("pack", [1, 2, 3, 5])
     def test_pack_variants_bit_exact(self, pack):
         q, kc, vc, tables, lens = _setup(8, 4, RAGGED_LENS, seed=3)
@@ -228,10 +216,9 @@ class TestCacheUpdateBoundary:
         # context_lens == table capacity (max_nb * bs == 8): the old code
         # read block_tables[:, 2] (one past the end); now the write drops
         kc, vc, kn, vn, tables, lens = self._setup([8, 3, 8])
-        kc2, vc2 = pa.update_paged_kv_cache(
-            jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(kn),
-            jnp.asarray(vn), jnp.asarray(tables), jnp.asarray(lens))
-        kc2, vc2 = np.asarray(kc2), np.asarray(vc2)
+        kc2, vc2 = np.asarray(pa.append_paged_kv(
+            jnp.stack([kc, vc]), jnp.asarray(kn), jnp.asarray(vn),
+            jnp.asarray(tables), jnp.asarray(lens)))
         # row 1 (len 3) landed at its block 0 (table id 2), offset 3
         np.testing.assert_array_equal(kc2[:, tables[1, 0], 3], kn[1])
         np.testing.assert_array_equal(vc2[:, tables[1, 0], 3], vn[1])
@@ -244,17 +231,16 @@ class TestCacheUpdateBoundary:
 
     def test_last_slot_still_writable(self):
         kc, vc, kn, vn, tables, lens = self._setup([7, 7, 7])
-        kc2, vc2 = pa.update_paged_kv_cache(
-            jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(kn),
-            jnp.asarray(vn), jnp.asarray(tables), jnp.asarray(lens))
-        kc2 = np.asarray(kc2)
+        kc2, _ = np.asarray(pa.append_paged_kv(
+            jnp.stack([kc, vc]), jnp.asarray(kn), jnp.asarray(vn),
+            jnp.asarray(tables), jnp.asarray(lens)))
         for b in range(3):
             np.testing.assert_array_equal(kc2[:, tables[b, 1], 3], kn[b])
 
 
 def _writer_case(writer, dtype, seed=11):
-    """Random tables and rows for one writer: (stacked call, per-half
-    call, numpy oracle), each a thunk over the same data. The rows cover
+    """Random tables and rows for one writer: (the writer's call as a
+    thunk, numpy oracle) over the same data. The rows cover
     the boundary contract: a slot whose length equals the table's
     capacity (its write drops), a chunk with valid_counts 0 (parked), a
     chunk that crosses a block boundary and one that runs into the
@@ -286,8 +272,6 @@ def _writer_case(writer, dtype, seed=11):
             put(bb, int(lens[bb]), kn[bb], vn[bb])
         stacked = lambda: pa.append_paged_kv(
             kv, j(kn), j(vn), ji(tables), ji(lens))
-        halves = lambda: pa.update_paged_kv_cache(
-            j(kc), j(vc), j(kn), j(vn), ji(tables), ji(lens))
     elif writer == "chunk":
         lens = np.asarray([2, 5, cap - 2, cap], np.int32)
         valid = np.asarray([c, 0, 4, 3], np.int32)
@@ -297,8 +281,6 @@ def _writer_case(writer, dtype, seed=11):
                 put(bb, int(lens[bb]) + jj, kn[bb, jj], vn[bb, jj])
         stacked = lambda: pa.append_paged_kv_chunk(
             kv, j(kn), j(vn), ji(tables), ji(lens), ji(valid))
-        halves = lambda: pa.update_paged_kv_cache_chunk(
-            j(kc), j(vc), j(kn), j(vn), ji(tables), ji(lens), ji(valid))
     elif writer == "rewind":
         old = np.asarray([cap, 7, 5, cap + 2], np.int32)
         new_l = np.asarray([cap - 3, 7, 1, cap - 1], np.int32)
@@ -307,41 +289,33 @@ def _writer_case(writer, dtype, seed=11):
                 put(bb, p, 0.0, 0.0)
         stacked = lambda: pa.truncate_paged_kv(
             kv, ji(tables), ji(new_l), ji(old), c)
-        halves = lambda: pa.truncate_paged_kv_cache(
-            j(kc), j(vc), ji(tables), ji(new_l), ji(old), c)
     else:
         src, dst = int(tables[1, 0]), nb - 1
         want_k[:, dst], want_v[:, dst] = kc[:, src], vc[:, src]
         stacked = lambda: pa.copy_paged_kv(kv, ji(src), ji(dst))
-        halves = lambda: pa.copy_paged_kv_block(
-            j(kc), j(vc), ji(src), ji(dst))
-    return stacked, halves, (want_k, want_v)
+    return stacked, (want_k, want_v)
 
 
 class TestStackedWriters:
-    """The engine's writers take one layer's [2, KVH, NB, BS, Dc] cache
-    and return it: bit for bit what the per-half writers of the
-    reference API give on the two halves, and what numpy gives."""
+    """The writers take one layer's [2, KVH, NB, BS, Dc] cache and
+    return it: bit for bit what numpy gives on the two halves."""
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("writer",
                              ["decode", "chunk", "rewind", "copy"])
-    def test_equals_per_half_writers_and_numpy(self, writer, dtype):
-        stacked, halves, want = _writer_case(writer, jnp.dtype(dtype))
+    def test_equals_numpy(self, writer, dtype):
+        stacked, want = _writer_case(writer, jnp.dtype(dtype))
         got = stacked()
-        k2, v2 = halves()
-        assert got.shape == (2,) + k2.shape and got.dtype == k2.dtype
-        for h, (per_half, oracle) in enumerate(zip((k2, v2), want)):
-            np.testing.assert_array_equal(
-                np.asarray(got[h], np.float32),
-                np.asarray(per_half, np.float32))
+        assert got.shape == (2,) + want[0].shape
+        assert got.dtype == jnp.dtype(dtype)
+        for h, oracle in enumerate(want):
             np.testing.assert_array_equal(
                 np.asarray(got[h], np.float32), oracle)
 
     def test_copy_out_of_pool_ids_touch_nothing(self):
         # the host allocator's ids are data: a destination past the pool
         # drops, a source past the pool is clamped (and then dropped)
-        stacked, _, _ = _writer_case("copy", jnp.float32)
+        stacked, _ = _writer_case("copy", jnp.float32)
         kv = np.asarray(stacked())
         nb = kv.shape[2]
         out = pa.copy_paged_kv(jnp.asarray(kv), jnp.int32(1),
@@ -359,6 +333,60 @@ class TestStackedWriters:
             pa.ragged_paged_attention(
                 jnp.asarray(q), jnp.asarray(kc), jnp.asarray(tables),
                 jnp.asarray(lens))
+
+
+class TestReferenceApiOverTheStackedBuffer:
+    """`block_multihead_attention` and `block_kv_cache_rewind` keep the
+    reference API's separate halves in and out and run the serving
+    path's kernel and writers on the stacked buffer in between: held to
+    the dense oracle and to numpy."""
+
+    @pytest.mark.parametrize("h,kvh", [
+        pytest.param(4, 4, id="mha"), pytest.param(8, 2, id="gqa4")])
+    def test_block_multihead_attention(self, h, kvh):
+        import paddle_tpu as paddle
+        from paddle_tpu.incubate.nn import functional as FI
+        lens = [0, 8 * 3, 1, 8 * 6 - 1, 13]
+        q, kc, vc, tables, lens = _setup(h, kvh, lens, seed=5)
+        b, _, d = q.shape
+        rng = np.random.default_rng(6)
+        qkv = rng.standard_normal((b, 3, h, d)).astype(np.float32)
+        qkv[:, 0] = q
+        out, kc2, vc2 = FI.block_multihead_attention(
+            paddle.to_tensor(qkv), paddle.to_tensor(kc),
+            paddle.to_tensor(vc), paddle.to_tensor(tables),
+            paddle.to_tensor(lens))
+        want_k, want_v = kc.copy(), vc.copy()
+        for bb in range(b):
+            blk, off = tables[bb, lens[bb] // 8], lens[bb] % 8
+            want_k[:, blk, off] = qkv[bb, 1, :kvh]
+            want_v[:, blk, off] = qkv[bb, 2, :kvh]
+        np.testing.assert_array_equal(kc2.numpy(), want_k)
+        np.testing.assert_array_equal(vc2.numpy(), want_v)
+        ref = _dense_softmax_ref(q, want_k, want_v, tables, lens + 1)
+        np.testing.assert_allclose(out.numpy(), ref, rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("h,kvh", [
+        pytest.param(4, 4, id="mha"), pytest.param(8, 2, id="gqa4")])
+    def test_block_kv_cache_rewind(self, h, kvh):
+        import paddle_tpu as paddle
+        from paddle_tpu.incubate.nn import functional as FI
+        _, kc, vc, tables, _ = _setup(h, kvh, [0] * 3, seed=7)
+        new_lens = np.array([5, 9, 8 * 6 - 2], np.int32)
+        old_lens = np.array([5, 12, 8 * 6 + 1], np.int32)
+        kc2, vc2 = FI.block_kv_cache_rewind(
+            paddle.to_tensor(kc), paddle.to_tensor(vc),
+            paddle.to_tensor(tables), paddle.to_tensor(new_lens),
+            paddle.to_tensor(old_lens), 4)
+        want_k, want_v = kc.copy(), vc.copy()
+        for bb in range(3):
+            # a position past the table's capacity has no row to zero
+            for pos in range(new_lens[bb], min(old_lens[bb], 8 * 6)):
+                want_k[:, tables[bb, pos // 8], pos % 8] = 0.0
+                want_v[:, tables[bb, pos // 8], pos % 8] = 0.0
+        assert (want_k != kc).any()
+        np.testing.assert_array_equal(kc2.numpy(), want_k)
+        np.testing.assert_array_equal(vc2.numpy(), want_v)
 
 
 class TestBlockAllocator:

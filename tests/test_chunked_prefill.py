@@ -172,11 +172,9 @@ class TestChunkedKernel:
         # row 2: runs into the table capacity (12) mid-chunk -> dropped
         lens = np.asarray([2, 5, 10], np.int32)
         valid = np.asarray([4, 0, 4], np.int32)
-        kc2, vc2 = pa.update_paged_kv_cache_chunk(
-            jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(kn),
-            jnp.asarray(vn), jnp.asarray(tables), jnp.asarray(lens),
-            jnp.asarray(valid))
-        kc2, vc2 = np.asarray(kc2), np.asarray(vc2)
+        kc2, vc2 = np.asarray(pa.append_paged_kv_chunk(
+            jnp.stack([kc, vc]), jnp.asarray(kn), jnp.asarray(vn),
+            jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(valid)))
         kc_exp, vc_exp = kc.copy(), vc.copy()
         for bb in range(3):
             for j in range(int(valid[bb])):

@@ -8,9 +8,9 @@ Four claims, all host-deterministic under CPU interpret mode:
   * dirty accounting is EXACT: a steady decode reuses every cached
     segment, one dirtied slot rebuilds exactly that slot's segments,
     and a missed dirty mark is CAUGHT by the debug cross-check,
-  * the fast-path engine generates token-for-token what the eager
-    engine does in every scheduler mode, with zero copied step-input
-    bytes and an identical compile-bucket set,
+  * with `host_debug_check=True` the engine serves, in every scheduler
+    mode, token for token what `engine.generate()` gives, and the
+    per-step cross-check against `build_ragged_work` never fires,
   * nothing leaks: KV blocks return to baseline and the builder's
     buffer pool stays bounded by the bucket set it has seen.
 """
@@ -180,44 +180,127 @@ def _mode_workload(mode, V):
 
 class TestEngineTokenExactness:
     @pytest.mark.parametrize("mode", sorted(_MODE_KW))
-    def test_fast_matches_eager(self, mode):
+    def test_served_tokens_match_generate_under_debug_check(self, mode):
+        """Every step's incremental work list is held, array for array,
+        to a from-scratch `build_ragged_work` (`_check_host_state`
+        raises on the first difference), and the tokens served are
+        `engine.generate()`'s."""
         eng, V = _tiny_engine()
         prompts, new = _mode_workload(mode, V)
-        outs = {}
-        for cfg, kw in (
-                ("eager", {"host_fastpath": False}),
-                ("fast", {"host_debug_check": True})):
-            toks, cb = _serve(eng, prompts, new,
-                              **_MODE_KW[mode], **kw)
-            outs[cfg] = [list(t) for t in toks]
-            hs = cb.host_stats()
-            if cfg == "eager":
-                assert not hs["fastpath"]
-                assert hs["input_copy_bytes"] > 0
-            else:
-                assert hs["fastpath"]
-                assert hs["input_copy_bytes"] == 0
-            # KV leak check: every allocatable block back, either free
-            # or parked in the (reclaimable) prefix pool
-            assert (cb.allocator.num_free
-                    + getattr(cb.allocator, "num_pooled", 0)
-                    == cb.allocator.num_blocks - cb.allocator.reserved)
-        assert outs["fast"] == outs["eager"]
+        toks, cb = _serve(eng, prompts, new, **_MODE_KW[mode],
+                          host_debug_check=True)
+        assert cb._step_count > 0
+        for p, n, got in zip(prompts, new, toks):
+            ref = eng.generate(p[None, :], max_new_tokens=n)[0, :n]
+            assert list(got) == ref.tolist()
+        hs = cb.host_stats()
+        assert hs["segments_reused"] + hs["segments_rebuilt"] > 0
+        assert hs["assemblies_full"] + hs["assemblies_incremental"] \
+            == cb._step_count
+        # KV leak check: every allocatable block back, either free
+        # or parked in the (reclaimable) prefix pool
+        assert (cb.allocator.num_free
+                + getattr(cb.allocator, "num_pooled", 0)
+                == cb.allocator.num_blocks - cb.allocator.reserved)
 
-    def test_bucket_sets_identical_and_phases_reported(self):
+    def test_debug_check_fires_when_a_table_write_is_not_marked(self):
+        """The engine-level half of the hazard above. Four requests of
+        one shape through two slots: the second pair takes the slots'
+        rows over with other blocks at the same segment lengths, which
+        only the dirty mark can tell the builder. With `_dirty_slot`
+        silenced `host_debug_check` fails that step."""
+        from paddle_tpu.incubate.nn import (ContinuousBatchingEngine,
+                                            GenerationRequest)
+        eng, V = _tiny_engine()
+        rng = np.random.default_rng(5)
+        cb = ContinuousBatchingEngine(eng, num_blocks=9, block_size=8,
+                                      max_batch=2, host_debug_check=True)
+        cb._dirty_slot = lambda i: None
+        for _ in range(4):
+            cb.submit(GenerationRequest(
+                rng.integers(1, V, 7).astype(np.int32), 2))
+        with pytest.raises(AssertionError, match="_dirty_slot"):
+            cb.run()
+
+    def test_debug_check_holds_through_preempt_and_cancel(self):
+        """The two schedules `_serve` cannot drive: a late priority-0
+        arrival evicts its way into a tight pool, and a request is
+        cancelled mid-decode. Every table write on those paths is
+        marked, or the cross-check would fail the step."""
+        from paddle_tpu.incubate.nn import (ContinuousBatchingEngine,
+                                            GenerationRequest)
+        eng, V = _tiny_engine()
+        rng = np.random.default_rng(11)
+        cb = ContinuousBatchingEngine(eng, num_blocks=7, block_size=8,
+                                      max_batch=2, host_debug_check=True)
+        low = [GenerationRequest(rng.integers(1, V, 14).astype(np.int32),
+                                 8, priority=2) for _ in range(2)]
+        high = GenerationRequest(rng.integers(1, V, 12).astype(np.int32),
+                                 6, priority=0)
+        for r in low:
+            cb.submit(r)
+        for _ in range(4):
+            cb.step()
+        cb.submit(high)
+        out = cb.run()
+        assert sum(r.preemptions for r in low) >= 1
+        for r in low + [high]:
+            ref = eng.generate(np.asarray(r.prompt)[None, :],
+                               max_new_tokens=r.max_new_tokens)
+            assert list(out[r.request_id]) == \
+                ref[0, :r.max_new_tokens].tolist()
+
+        cb = ContinuousBatchingEngine(eng, num_blocks=9, block_size=8,
+                                      max_batch=2, host_debug_check=True)
+        reqs = [GenerationRequest(rng.integers(1, V, p).astype(np.int32),
+                                  8) for p in (6, 9, 4)]
+        for r in reqs:
+            cb.submit(r)
+        for _ in range(4):
+            cb.step()
+        cb.cancel(reqs[1].request_id)
+        cb.run()
+        assert [r.status for r in reqs] == ["finished", "cancelled",
+                                            "finished"]
+        assert cb.allocator.num_free == (cb.allocator.num_blocks
+                                         - cb.allocator.reserved)
+
+    def test_engine_steady_decode_reuses_every_segment(self):
+        """Three decode-only slots sized so that no block boundary is
+        crossed: after the first decode step every build reuses every
+        segment and assembles incrementally."""
+        from paddle_tpu.incubate.nn import (ContinuousBatchingEngine,
+                                            GenerationRequest)
+        eng, V = _tiny_engine()
+        rng = np.random.default_rng(17)
+        cb = ContinuousBatchingEngine(eng, num_blocks=24, block_size=8,
+                                      max_batch=4, host_debug_check=True)
+        for _ in range(3):
+            cb.submit(GenerationRequest(
+                rng.integers(1, V, 9).astype(np.int32), 6))
+        snaps = []
+        while cb.queue or cb.num_active:
+            cb.step()
+            snaps.append(cb.host_stats())
+        steady = [
+            (b["segments_rebuilt"] - a["segments_rebuilt"],
+             b["assemblies_full"] - a["assemblies_full"],
+             b["segments_reused"] - a["segments_reused"],
+             b["assemblies_incremental"] - a["assemblies_incremental"])
+            for a, b in zip(snaps[1:], snaps[2:])]
+        # (the run's last step only retires: it builds nothing)
+        assert steady[:4] == [(0, 0, 3, 1)] * 4
+        assert set(steady[4:]) <= {(0, 0, 0, 0)}
+
+    def test_phases_reported(self):
         eng, V = _tiny_engine()
         prompts, new = _mode_workload("plain", V)
-        seen = {}
-        for cfg, kw in (("eager", {"host_fastpath": False}),
-                        ("fast", {})):
-            _, cb = _serve(eng, prompts, new, **kw)
-            seen[cfg] = set(cb._seen_buckets)
-            phases = cb.host_stats()["phases"]
-            assert set(phases) == {"schedule", "build", "dispatch",
-                                   "fetch", "commit"}
-            rid = next(iter(cb.finished))
-            assert cb.explain(rid)["host_phases"] == phases
-        assert seen["fast"] == seen["eager"]
+        _, cb = _serve(eng, prompts, new)
+        phases = cb.host_stats()["phases"]
+        assert set(phases) == {"schedule", "build", "dispatch",
+                               "fetch", "commit"}
+        rid = next(iter(cb.finished))
+        assert cb.explain(rid)["host_phases"] == phases
 
 
 class TestNoLeaks:

@@ -235,6 +235,48 @@ def test_wide_step_matches_the_padded_computation(case, entry):
         assert (got[:, :, written] != before[:, :, written]).any(-1).all()
 
 
+def test_paged_op_without_chunk_lens_is_one_token_a_row():
+    """The documented decode call, x [B, 1, E] and no `chunk_lens`, is
+    the chunk call with `chunk_lens = ones(B)`: output and every layer's
+    cache bit for bit."""
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.incubate.nn.functional import fused_multi_transformer
+    lens = np.array([9, 30, 0, 17, 2, 31, 8, 25], np.int32)
+    ones = np.ones(B, np.int32)
+    caches, toks, _, _, tables, _, _, _ = _step_inputs(ones, lens, seed=3)
+    work, _, _, pack = pa.build_ragged_work(
+        tables, lens + 1, BS, pa.default_pack(B, H // G),
+        bucket_to=pa.next_pow2)      # no q_lens: one query a row
+    w = _weights()
+
+    def call(ragged_work=tuple(work), **chunk):
+        cts = [Tensor(jnp.asarray(c)) for c in caches]
+        out = fused_multi_transformer(
+            Tensor(jnp.asarray(w["embedding"])[toks[:, :1]]),
+            w["ln_scales"], None, w["qkv_weights"], w["qkv_biases"],
+            w["linear_weights"], None, w["ffn_ln_scales"], None,
+            w["ffn1_weights"], None, w["ffn2_weights"], None,
+            cache_kvs=cts, time_step=Tensor(jnp.zeros((), jnp.int32)),
+            seq_lens=Tensor(jnp.asarray(lens)),
+            rotary_embs=jnp.asarray(w["rotary_embs"]),
+            block_tables=tables, ragged_work=ragged_work,
+            ragged_pack=pack, norm_type="rmsnorm", activation="swiglu",
+            use_neox_rotary_style=True, gqa_group_size=G, **chunk)
+        return np.asarray(out.data), [np.asarray(c.data) for c in cts]
+
+    out, got = call()
+    want_out, want = call(chunk_lens=Tensor(jnp.asarray(ones)))
+    assert out.shape == (B, 1, E) and np.abs(out).max() > 0
+    np.testing.assert_array_equal(out, want_out)
+    for g, wnt, before in zip(got, want, caches):
+        np.testing.assert_array_equal(g, wnt)
+        assert (g != before).any()
+
+    # the work list is the host's to build: there is no eager fallback
+    with pytest.raises(ValueError, match="ragged_work"):
+        call(ragged_work=None)
+
+
 def test_live_rows_packs_slot_major_and_maps_back():
     q_lens = np.array([3, 0, 2, 0, 1, 0, 0, 0], np.int32)
     rows = pa.live_rows(q_lens, C)

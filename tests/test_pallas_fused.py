@@ -155,9 +155,8 @@ class TestPagedAttention:
 
     def test_matches_dense(self):
         q, kc, vc, tables, lens = self._setup()
-        out = pa.paged_attention(jnp.asarray(q), jnp.asarray(kc),
-                                 jnp.asarray(vc), jnp.asarray(tables),
-                                 jnp.asarray(lens))
+        out = pa.ragged_paged_attention(
+            jnp.asarray(q), jnp.stack([kc, vc]), tables, lens)
         ref = self._dense_ref(q, kc, vc, tables, lens)
         np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
                                    atol=2e-3)
@@ -169,18 +168,17 @@ class TestPagedAttention:
         rng = np.random.default_rng(5)
         k_new = rng.standard_normal((B, KVH, D)).astype(np.float32)
         v_new = rng.standard_normal((B, KVH, D)).astype(np.float32)
-        kc2, vc2 = pa.update_paged_kv_cache(
-            jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(k_new),
-            jnp.asarray(v_new), jnp.asarray(tables), jnp.asarray(lens))
-        kc2, vc2 = np.asarray(kc2), np.asarray(vc2)
+        kv2 = pa.append_paged_kv(
+            jnp.stack([kc, vc]), jnp.asarray(k_new), jnp.asarray(v_new),
+            jnp.asarray(tables), jnp.asarray(lens))
+        kc2, vc2 = np.asarray(kv2)
         for b in range(B):
             blk = tables[b, lens[b] // kc.shape[2]]
             off = lens[b] % kc.shape[2]
             np.testing.assert_allclose(kc2[:, blk, off], k_new[b])
             np.testing.assert_allclose(vc2[:, blk, off], v_new[b])
-        out = pa.paged_attention(jnp.asarray(q), jnp.asarray(kc2),
-                                 jnp.asarray(vc2), jnp.asarray(tables),
-                                 jnp.asarray(lens + 1))
+        out = pa.ragged_paged_attention(jnp.asarray(q), kv2, tables,
+                                        lens + 1)
         ref = self._dense_ref(q, kc2, vc2, tables, lens + 1)
         np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
                                    atol=2e-3)

@@ -14,7 +14,7 @@ Parity ladder, one rung up from test_speculative_decode.py:
     `engine.generate()`, with speculative decode layered on top, and
     through conversation resume off the reuse pool,
   * a write into a block other requests still read copies first
-    (`copy_paged_kv_block` + `_cow_block`): the shared original must be
+    (`copy_paged_kv` + `_cow_block`): the shared original must be
     BIT-IDENTICAL after the writer diverges,
   * and churn leaks nothing: after every request retires the allocator
     holds zero refcounts and the compile buckets stay flat on replay.
@@ -402,9 +402,8 @@ class TestPagedCopy:
         rng = np.random.default_rng(0)
         kc = rng.standard_normal((2, 5, 4, 8)).astype(np.float32)
         vc = rng.standard_normal((2, 5, 4, 8)).astype(np.float32)
-        k2, v2 = pa.copy_paged_kv_block(
-            jnp.asarray(kc), jnp.asarray(vc), jnp.int32(1), jnp.int32(3))
-        k2, v2 = np.asarray(k2), np.asarray(v2)
+        k2, v2 = np.asarray(pa.copy_paged_kv(
+            jnp.stack([kc, vc]), jnp.int32(1), jnp.int32(3)))
         np.testing.assert_array_equal(k2[:, 3], kc[:, 1])
         np.testing.assert_array_equal(v2[:, 3], vc[:, 1])
         mask = np.ones(5, bool)
@@ -415,8 +414,8 @@ class TestPagedCopy:
     def test_out_of_pool_dst_drops(self):
         kc = np.ones((2, 4, 4, 8), np.float32)
         vc = np.ones((2, 4, 4, 8), np.float32)
-        k2, v2 = pa.copy_paged_kv_block(
-            jnp.asarray(kc), jnp.asarray(vc), jnp.int32(1), jnp.int32(7))
+        k2, v2 = pa.copy_paged_kv(
+            jnp.stack([kc, vc]), jnp.int32(1), jnp.int32(7))
         np.testing.assert_array_equal(np.asarray(k2), kc)
         np.testing.assert_array_equal(np.asarray(v2), vc)
 

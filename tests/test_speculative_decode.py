@@ -3,7 +3,7 @@ mode on CPU).
 
 Parity ladder, one rung up from test_chunked_prefill.py:
   * the prompt-lookup proposer is pure host math with pinned semantics,
-  * the paged-KV rewind (`truncate_paged_kv_cache`) must leave a
+  * the paged-KV rewind (`truncate_paged_kv`) must leave a
     speculated-then-rewound cache BIT-IDENTICAL to a never-speculated
     one — mid-block, across block boundaries, and through a
     rewind-then-append round trip,
@@ -74,18 +74,24 @@ def _mk_cache(seed, kvh=2, nb=13, bs=4, d=8):
 
 
 class TestKVRewind:
-    """`truncate_paged_kv_cache` unit contract: zero exactly the
-    rejected span, drop everything out of range."""
+    """`truncate_paged_kv` unit contract: zero exactly the rejected
+    span, drop everything out of range. The cache is the stacked
+    [2, KVH, NB, BS, D] buffer; the cases read its two halves."""
 
     def _append(self, kc, vc, tables, lens, rows):
         """Append rows [B, C, KVH, D] at positions lens.. (all valid)."""
         c = rows.shape[1]
         counts = np.full(rows.shape[0], c, np.int32)
-        kc2, vc2 = pa.update_paged_kv_cache_chunk(
-            jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(rows),
+        return tuple(np.asarray(pa.append_paged_kv_chunk(
+            jnp.stack([kc, vc]), jnp.asarray(rows),
             jnp.asarray(rows + 0.5), jnp.asarray(tables),
-            jnp.asarray(lens, np.int32), jnp.asarray(counts))
-        return np.asarray(kc2), np.asarray(vc2)
+            jnp.asarray(lens, np.int32), jnp.asarray(counts))))
+
+    def _truncate(self, kc, vc, tables, new_lens, old_lens, span):
+        return tuple(np.asarray(pa.truncate_paged_kv(
+            jnp.stack([kc, vc]), jnp.asarray(tables),
+            jnp.asarray(new_lens, np.int32),
+            jnp.asarray(old_lens, np.int32), span)))
 
     def test_rejection_mid_block(self):
         kc, vc, rng = _mk_cache(0)
@@ -94,11 +100,7 @@ class TestKVRewind:
         rows = rng.standard_normal((2, 3, 2, 8)).astype(np.float32)
         kc1, vc1 = self._append(kc, vc, tables, lens, rows)
         # rewind row 0 from 4 back to 2 (both inside block 0, bs=4)
-        kc2, vc2 = pa.truncate_paged_kv_cache(
-            jnp.asarray(kc1), jnp.asarray(vc1), jnp.asarray(tables),
-            jnp.asarray([2, 8], np.int32), jnp.asarray([4, 8], np.int32),
-            4)
-        kc2, vc2 = np.asarray(kc2), np.asarray(vc2)
+        kc2, vc2 = self._truncate(kc1, vc1, tables, [2, 8], [4, 8], 4)
         exp_k, exp_v = kc1.copy(), vc1.copy()
         for p in (2, 3):
             exp_k[:, tables[0, p // 4], p % 4] = 0.0
@@ -113,10 +115,7 @@ class TestKVRewind:
         rows = rng.standard_normal((1, 5, 2, 8)).astype(np.float32)
         kc1, vc1 = self._append(kc, vc, tables, lens, rows)  # fills 2..6
         # rewind 7 -> 3: positions 3..6 span blocks 0 and 1
-        kc2, vc2 = pa.truncate_paged_kv_cache(
-            jnp.asarray(kc1), jnp.asarray(vc1), jnp.asarray(tables),
-            jnp.asarray([3], np.int32), jnp.asarray([7], np.int32), 4)
-        kc2 = np.asarray(kc2)
+        kc2, _ = self._truncate(kc1, vc1, tables, [3], [7], 4)
         exp = kc1.copy()
         for p in range(3, 7):
             exp[:, tables[0, p // 4], p % 4] = 0.0
@@ -132,11 +131,7 @@ class TestKVRewind:
         kc1, vc1 = self._append(kc, vc, tables, lens, rows)
         # row 0: new == old (no-op); row 1: old_lens claims past the
         # 12-token table capacity — the over-capacity positions DROP
-        kc2, _ = pa.truncate_paged_kv_cache(
-            jnp.asarray(kc1), jnp.asarray(vc1), jnp.asarray(tables),
-            jnp.asarray([6, 11], np.int32),
-            jnp.asarray([6, 14], np.int32), 4)
-        kc2 = np.asarray(kc2)
+        kc2, _ = self._truncate(kc1, vc1, tables, [6, 11], [6, 14], 4)
         exp = kc1.copy()
         exp[:, tables[1, 2], 3] = 0.0          # position 11 zeroed
         np.testing.assert_array_equal(kc2, exp)
@@ -157,9 +152,7 @@ class TestKVRewind:
                               true_rows[:, :2])
         kA, vA = self._append(kA, vA, tables, np.asarray([2], np.int32),
                               spec)
-        kA, vA = (np.asarray(x) for x in pa.truncate_paged_kv_cache(
-            jnp.asarray(kA), jnp.asarray(vA), jnp.asarray(tables),
-            jnp.asarray([4], np.int32), jnp.asarray([6], np.int32), 4))
+        kA, vA = self._truncate(kA, vA, tables, [4], [6], 4)
         kA, vA = self._append(kA, vA, tables, np.asarray([4], np.int32),
                               true_rows[:, 4:6])
 
@@ -273,6 +266,7 @@ class TestSpeculativeEngine:
 
         d0, a0 = val("spec_draft_tokens_total"), \
             val("spec_accepted_tokens_total")
+        t0 = val("serve_tokens_total")
         eng, V = _tiny_engine()
         prompts, news = _workload(V)
         _, cb, reqs = _serve(eng, prompts, news, prefill_chunk=8,
@@ -284,7 +278,8 @@ class TestSpeculativeEngine:
         assert val("spec_accepted_tokens_total") - a0 == accepted
         h = reg.get("serve_spec_accept_len")
         assert h is not None and h.count > 0
-        assert reg.get("serve_effective_tokens_per_step").value >= 1
+        assert val("serve_tokens_total") - t0 == sum(
+            len(r.generated) for r in reqs)
 
     def test_spec_requires_greedy(self):
         from paddle_tpu.incubate.nn import ContinuousBatchingEngine

@@ -27,12 +27,6 @@ if [ "${LINT_SKIP_SERVE:-0}" != "1" ]; then
   python tools/serve_bench.py --check tools/serve_ragged.json
   python tools/serve_bench.py --check tools/serve_spec.json
   python tools/serve_bench.py --check tools/serve_prefix.json
-  # host fast-path gate: the incremental work-list / in-place-input
-  # engine must stay token-exact vs the eager rebuild
-  # path in every scheduler mode at tp=1/2 (debug cross-check on), with
-  # ZERO step-input copy bytes, 100% steady-decode segment reuse, an
-  # identical compile-bucket set, and exact per-mode work counters
-  python tools/serve_bench.py --check tools/serve_host.json
   # tensor-parallel gate: on the virtual 8-device mesh the kv-head-
   # sharded engine must stay token-exact vs single-chip at TP=2/4/8
   # across plain/chunked/spec/prefix, per-device KV high-water bytes

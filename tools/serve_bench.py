@@ -10,9 +10,8 @@ Legs:
   - bf16 / weight-only int8 / weight-only int4 decode at the flagship
     GQA shape (24L/1024E, 16 q-heads / 8 kv-heads, B=8) via
     FusedMultiTransformerEngine
-  - paged vs dense decode-step attention at the same shape (op level:
-    the engine serves a dense cache; vLLM-style paged serving uses
-    ops/pallas/paged_attention.py with a block table)
+  - the ragged paged kernel's grid accounting over a ragged batch
+    (ops/pallas/paged_attention.py)
 
 Usage: python tools/serve_bench.py [--json out.json]
 Reference bar: the fused_multi_transformer int8 inference tier,
@@ -23,7 +22,6 @@ import collections
 import glob
 import gzip
 import json
-import math
 import os
 import shutil
 import sys
@@ -111,71 +109,14 @@ def decode_leg(weight_quant, B=8, NEW=64):
     }
 
 
-def paged_vs_dense_leg(B=8, H=16, KVH=8, D=64, ctx=448, iters=32):
-    """Decode-step attention only: dense [KVH, S, D] slice-softmax vs the
-    paged kernel with 64-token blocks (same effective context)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from paddle_tpu.ops.pallas.paged_attention import paged_attention
-
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
-    scale = 1.0 / math.sqrt(D)
-
-    # dense: per-sequence cache [B, KVH, S, D]
-    kd = jnp.asarray(rng.standard_normal((B, KVH, ctx, D)), jnp.bfloat16)
-    vd = jnp.asarray(rng.standard_normal((B, KVH, ctx, D)), jnp.bfloat16)
-
-    def dense(q, k, v):
-        g = H // KVH
-        qg = q.reshape(B, KVH, g, D).astype(jnp.float32)
-        s = jnp.einsum("bkgd,bksd->bkgs", qg, k.astype(jnp.float32))
-        p = jax.nn.softmax(s * scale, axis=-1)
-        return jnp.einsum("bkgs,bksd->bkgd", p,
-                          v.astype(jnp.float32)).reshape(B, H, D)
-
-    block = 64
-    nblk = B * ctx // block
-    kp = jnp.asarray(rng.standard_normal((KVH, nblk, block, D)),
-                     jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((KVH, nblk, block, D)),
-                     jnp.bfloat16)
-    tables = jnp.asarray(
-        rng.permutation(nblk).reshape(B, ctx // block), jnp.int32)
-    lens = jnp.full((B,), ctx, jnp.int32)
-
-    def many(fn, *args):
-        # the q input must DEPEND on the carry or XLA hoists the whole
-        # loop-invariant body out of the scan (measured: iters=1/32/256
-        # all took one kernel time) and us/step under-reports by ~iters
-        def run(a):
-            def body(c, _):
-                qq = a[0] + (c * 0).astype(a[0].dtype)
-                o = fn(qq, *a[1:])
-                return c + o.astype(jnp.float32).sum(), None
-            s, _ = jax.lax.scan(body, jnp.float32(0), jnp.arange(iters))
-            return s
-        return jax.jit(run)(args)
-
-    md = _capture(lambda: float(many(dense, q, kd, vd)))
-    mp = _capture(lambda: float(many(
-        lambda q, k, v: paged_attention(q, k, v, tables, lens),
-        q, kp, vp)))
-    dense_ms = sum(v for k, v in md.items() if k.startswith("jit_run"))
-    paged_ms = sum(v for k, v in mp.items() if k.startswith("jit_run"))
-    return {"dense_attn_us_per_step": dense_ms / iters * 1e3,
-            "paged_attn_us_per_step": paged_ms / iters * 1e3,
-            "context": ctx, "block_size": block}
-
-
 def ragged_leg(iters=4):
-    """Legacy paged grid vs ragged work-list grid over a RAGGED batch at
-    the round-5 decode-attention shape. Grid-step counts are exact host
-    math (they gate in --check); timings are whole-call wall-clock on
-    EVERY platform (dispatch included), recorded for context only — under
-    CPU interpret they measure the interpreter, not the chip."""
+    """The ragged work-list grid over a RAGGED batch at the round-5
+    decode-attention shape, beside what a B x KVH x max_blocks grid
+    (`legacy_grid_steps`, arithmetic) would walk. Grid-step counts are
+    exact host math (they gate in --check); the call's timing is
+    whole-call wall-clock on EVERY platform (dispatch included), recorded
+    for context only — under CPU interpret it measures the interpreter,
+    not the chip."""
     import time
 
     import jax
@@ -215,25 +156,18 @@ def ragged_leg(iters=4):
         "interpret": not on_tpu,
     }
 
-    def timed(fn):
-        o = fn()
-        jax.block_until_ready(o)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            o = fn()
-        jax.block_until_ready(o)
-        return (time.perf_counter() - t0) / iters * 1e6, o
-
-    t_legacy, o_l = timed(lambda: pa.paged_attention(
-        q, kc, vc, tables, lens_j))
     kv = jnp.stack([kc, vc])
-    t_ragged, o_r = timed(lambda: pa.ragged_paged_attention(
-        q, kv, tables, lens_j, work=(work, t_real, t_total, pack)))
-    np.testing.assert_allclose(
-        np.asarray(o_l, np.float32), np.asarray(o_r, np.float32),
-        rtol=2e-2, atol=2e-2)
-    out["legacy_call_us"] = t_legacy
-    out["ragged_call_us"] = t_ragged
+
+    def call():
+        return pa.ragged_paged_attention(
+            q, kv, tables, lens_j, work=(work, t_real, t_total, pack))
+
+    jax.block_until_ready(call())
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        o = call()
+    jax.block_until_ready(o)
+    out["ragged_call_us"] = (time.perf_counter() - t0) / iters * 1e6
     return out
 
 
@@ -998,366 +932,6 @@ def check_tp(base):
     return 0
 
 
-def host_leg(tps=(1, 2)):
-    """Host-step fast path (ISSUE 20): the SAME deterministic workload
-    drives two host configs of the scheduler — eager (fast path off:
-    per-step table copies + from-scratch work-list rebuild) and fast
-    (incremental RaggedWorkBuilder + in-place step inputs, with the
-    debug cross-check rebuilding from scratch every step and asserting
-    equality) — across every scheduler mode (plain / chunked /
-    budgeted / spec / prefix / preempt / cancel) at tp=1 and tp=2.
-    Gated claims, all host-deterministic:
-
-      * token-exact: fast produces byte-identical outputs and
-        terminal statuses vs eager in every mode at every tp,
-      * identical compile-bucket sets per tp (the fast path is a host
-        optimization: it must not change what gets compiled), and 0
-        new buckets after warm replay on the budgeted config,
-      * step-input copy bytes == 0 on the fast path (eager's figure is
-        committed alongside as the avoided-work witness),
-      * work-list counters exact per mode (segment rebuilds track the
-        dirty-slot schedule, not the step count), and a steady-decode
-        window where segment reuse is 100% with every assembly
-        incremental.
-
-    Host-phase p50s (schedule/build/dispatch/fetch/commit) are
-    REPORTED for BASELINE.md but not gated — wall time off-TPU times
-    the interpreter, not the TPU step."""
-    import jax
-    import numpy as np
-
-    from paddle_tpu.framework.platform import init_platform
-    from paddle_tpu.incubate.nn import (ContinuousBatchingEngine,
-                                        GenerationRequest)
-
-    on_tpu = init_platform() == "tpu"
-    if len(jax.devices()) < max(tps):
-        raise RuntimeError(
-            f"host leg needs {max(tps)} devices (run with "
-            f"--xla_force_host_platform_device_count=8; the --host "
-            "flag sets it when it runs before jax initializes)")
-    rng = np.random.default_rng(0)
-    weights, V, L, E = _tp_weights(rng)
-    block_size = 8
-    workload = [(5, 4), (11, 3), (3, 6), (8, 2)]
-    pattern = [7, 23, 41, 11]
-    prefix_toks = rng.integers(1, V, 24).astype(np.int32)
-
-    def drive(cb, arrivals, cancels=(), phases_out=None,
-              stats_out=None):
-        """Step-driven run loop: submissions and cancels land at their
-        scheduled step index, per-step host stats are optionally
-        collected, and every submitted request's tokens + terminal
-        status come back (a cancelled request holds its exact prefix)."""
-        reqs = [r for _, r in arrivals]
-        pend = sorted(arrivals, key=lambda sr: sr[0])
-        cxl = sorted(cancels, key=lambda sr: sr[0])
-        step = 0
-        while pend or cxl or cb.queue or cb.num_active:
-            while pend and pend[0][0] <= step:
-                cb.submit(pend.pop(0)[1])
-            while cxl and cxl[0][0] <= step:
-                cb.cancel(cxl.pop(0)[1])
-            if cb.queue or cb.num_active:
-                cb.step()
-                if phases_out is not None:
-                    phases_out.append(dict(cb.host_stats()["phases"]))
-                if stats_out is not None:
-                    stats_out.append(cb.host_stats())
-            step += 1
-            if step > 500:
-                raise RuntimeError("host leg did not converge")
-        cb._retire()
-        res = dict(cb.finished)
-        return ({r.request_id: list(res.get(r.request_id, ()))
-                 for r in reqs},
-                {r.request_id: r.status for r in reqs})
-
-    def run_modes(engine, host_kw, phases_out=None):
-        """All seven scheduler modes against one model engine under one
-        host config. Returns per-mode outputs/statuses/steps, per-mode
-        work counters, the union bucket set, copy bytes, and the
-        warm-replay bucket count (budgeted mode)."""
-        out = {}
-        buckets = set()
-        copy_bytes = 0
-        uid = [0]
-
-        def tag(p):
-            uid[0] += 1
-            return f"h_{p}{uid[0]}"
-
-        def finish(name, cb, toks, stat, extra=None):
-            hs = cb.host_stats()
-            out[name] = {
-                "outputs": toks, "status": stat,
-                "steps": cb._step_count,
-                "work": {"reused": hs["segments_reused"],
-                         "rebuilt": hs["segments_rebuilt"],
-                         "incremental": hs["assemblies_incremental"],
-                         "full": hs["assemblies_full"]},
-            }
-            if extra:
-                out[name].update(extra)
-            buckets.update(cb._seen_buckets)
-            return hs["input_copy_bytes"]
-
-        def mk(**kw):
-            cfg = dict(num_blocks=24, block_size=block_size,
-                       max_batch=4)
-            cfg.update(kw)
-            cfg.update(host_kw)
-            return ContinuousBatchingEngine(engine, **cfg)
-
-        # plain FIFO over the ragged mix (host-phase samples ride here)
-        cb = mk()
-        prng = np.random.default_rng(7)
-        toks, stat = drive(cb, [(0, GenerationRequest(
-            prng.integers(1, V, p).astype(np.int32), n,
-            request_id=tag("pl"))) for p, n in workload],
-            phases_out=phases_out)
-        copy_bytes += finish("plain", cb, toks, stat)
-        # chunked prefill, no budget
-        cb = mk(prefill_chunk=4)
-        prng = np.random.default_rng(7)
-        toks, stat = drive(cb, [(0, GenerationRequest(
-            prng.integers(1, V, p).astype(np.int32), n,
-            request_id=tag("ch"))) for p, n in workload])
-        copy_bytes += finish("chunked", cb, toks, stat)
-        # chunked prefill under a token budget + the warm-replay
-        # bucket gate
-        cb = mk(prefill_chunk=4, token_budget=6)
-        prng = np.random.default_rng(7)
-        toks, stat = drive(cb, [(0, GenerationRequest(
-            prng.integers(1, V, p).astype(np.int32), n,
-            request_id=tag("bu"))) for p, n in workload])
-        cb.declare_warm()
-        warm = set(cb._seen_buckets)
-        prng = np.random.default_rng(5)
-        drive(cb, [(0, GenerationRequest(
-            prng.integers(1, V, p).astype(np.int32), n,
-            request_id=tag("bw"))) for p, n in workload])
-        copy_bytes += finish(
-            "budgeted", cb, toks, stat,
-            {"new_buckets_after_warmup":
-                 len(set(cb._seen_buckets) - warm)})
-        # speculative decode on the repetitive workload
-        cb = mk(max_batch=2, prefill_chunk=8, spec_k=4)
-        reqs = [GenerationRequest(np.asarray(pattern * 6, np.int32),
-                                  10, request_id=tag("sp")),
-                GenerationRequest(np.asarray(pattern * 3, np.int32),
-                                  10, request_id=tag("sp"))]
-        toks, stat = drive(cb, [(0, r) for r in reqs])
-        copy_bytes += finish(
-            "spec", cb, toks, stat,
-            {"accepted": sum(r.spec_accepted for r in reqs)})
-        # prefix cache over a shared preamble (COW + rewind paths)
-        cb = mk(prefill_chunk=8, prefix_cache=True)
-        prng = np.random.default_rng(3)
-        toks, stat = drive(cb, [(0, GenerationRequest(
-            np.concatenate([prefix_toks,
-                            prng.integers(1, V, 3).astype(np.int32)]),
-            4, request_id=tag("pf"))) for _ in range(4)])
-        copy_bytes += finish(
-            "prefix", cb, toks, stat,
-            {"cache_hits": cb.cache_stats["hit_blocks"]})
-        # preemption: a tight pool, then a late priority-0 arrival
-        # evicts its way in (finish/preempt/re-admit all dirty slots)
-        cb = mk(num_blocks=10)
-        prng = np.random.default_rng(11)
-        reqs = [GenerationRequest(
-            prng.integers(1, V, 20).astype(np.int32), 10,
-            request_id=tag("pe"), priority=2) for _ in range(2)]
-        hi = GenerationRequest(
-            prng.integers(1, V, 12).astype(np.int32), 6,
-            request_id=tag("pe"), priority=0)
-        toks, stat = drive(cb, [(0, reqs[0]), (0, reqs[1]), (4, hi)])
-        copy_bytes += finish(
-            "preempt", cb, toks, stat,
-            {"preemptions": sum(r.preemptions for r in reqs)
-                 + hi.preemptions})
-        # mid-stream cancel during decode (terminal prefix + free)
-        cb = mk()
-        prng = np.random.default_rng(13)
-        reqs = [GenerationRequest(
-            prng.integers(1, V, p).astype(np.int32), 8,
-            request_id=tag("ca")) for p in (6, 9, 4)]
-        toks, stat = drive(cb, [(0, r) for r in reqs],
-                           cancels=[(5, reqs[1].request_id)])
-        copy_bytes += finish("cancel", cb, toks, stat)
-        return out, buckets, copy_bytes
-
-    def steady_decode(engine, host_kw):
-        """3 decode-only slots sized so no block boundary is crossed:
-        after the first decode step every build must reuse every
-        segment and assemble incrementally."""
-        cb = ContinuousBatchingEngine(
-            engine, num_blocks=24, block_size=block_size, max_batch=4,
-            **host_kw)
-        prng = np.random.default_rng(17)
-        snaps = []
-        drive(cb, [(0, GenerationRequest(
-            prng.integers(1, V, 9).astype(np.int32), 6,
-            request_id=f"h_sd{i}")) for i in range(3)],
-            stats_out=snaps)
-        run = best = 0
-        for prev, curn in zip(snaps, snaps[1:]):
-            d_reb = curn["segments_rebuilt"] - prev["segments_rebuilt"]
-            d_reu = curn["segments_reused"] - prev["segments_reused"]
-            d_inc = (curn["assemblies_incremental"]
-                     - prev["assemblies_incremental"])
-            d_full = curn["assemblies_full"] - prev["assemblies_full"]
-            if d_reb == 0 and d_full == 0 and d_reu > 0 and d_inc == 1:
-                run += 1
-                best = max(best, run)
-            else:
-                run = 0
-        last, first = snaps[-1], snaps[0]
-        # the first assembly rebuilds every admitted slot by definition
-        # — the 100% claim is about the DECODE steps after it
-        reused = last["segments_reused"] - first["segments_reused"]
-        rebuilt = last["segments_rebuilt"] - first["segments_rebuilt"]
-        return {
-            "steps": len(snaps),
-            "steady_run_len": best,
-            "segments_reused": last["segments_reused"],
-            "segments_rebuilt": last["segments_rebuilt"],
-            "assemblies_incremental": last["assemblies_incremental"],
-            "assemblies_full": last["assemblies_full"],
-            "reuse_fraction": round(reused / (reused + rebuilt), 4),
-        }
-
-    configs = {
-        "eager": {"host_fastpath": False},
-        # the debug cross-check rebuilds from scratch and asserts
-        # equality EVERY step — the leg is its continuous proof
-        "fast": {"host_fastpath": True, "host_debug_check": True},
-    }
-    modes = ("plain", "chunked", "budgeted", "spec", "prefix",
-             "preempt", "cancel")
-    per_tp = {}
-    phase_samples = []
-    for tp in tps:
-        engine = _tiny_tp_engine(weights, tp)
-        runs = {}
-        for cname, ckw in configs.items():
-            want_phases = (tp == tps[0] and cname == "fast")
-            r, buckets, copy_bytes = run_modes(
-                engine, ckw,
-                phases_out=phase_samples if want_phases else None)
-            runs[cname] = {"modes": r, "buckets": buckets,
-                           "copy_bytes": copy_bytes}
-        per_tp[tp] = runs
-        eq = {c: all(
-            runs[c]["modes"][m]["outputs"]
-            == runs["eager"]["modes"][m]["outputs"]
-            and runs[c]["modes"][m]["status"]
-            == runs["eager"]["modes"][m]["status"]
-            for m in modes) for c in ("fast",)}
-        print(f"host[tp={tp}]: token-exact {eq}, copy bytes "
-              f"eager={runs['eager']['copy_bytes']} "
-              f"fast={runs['fast']['copy_bytes']}, buckets "
-              f"{[len(runs[c]['buckets']) for c in configs]}")
-    steady = steady_decode(_tiny_tp_engine(weights, tps[0]),
-                           configs["fast"])
-    e0 = per_tp[tps[0]]
-    p50 = {}
-    if phase_samples:
-        import statistics
-        for ph in ("schedule", "build", "dispatch", "fetch", "commit"):
-            p50[ph] = round(statistics.median(
-                s[ph] for s in phase_samples) * 1e6, 1)
-    out = {
-        "interpret": not on_tpu,
-        "shape": {"V": V, "E": E, "L": L, "block_size": block_size},
-        "tps": list(tps),
-        "modes": list(modes),
-        "token_exact": {
-            str(tp): {c: all(
-                per_tp[tp][c]["modes"][m]["outputs"]
-                == per_tp[tp]["eager"]["modes"][m]["outputs"]
-                and per_tp[tp][c]["modes"][m]["status"]
-                == per_tp[tp]["eager"]["modes"][m]["status"]
-                for m in modes) for c in ("fast",)}
-            for tp in tps},
-        "buckets_equal": {
-            str(tp): all(
-                per_tp[tp][c]["buckets"]
-                == per_tp[tp]["eager"]["buckets"]
-                for c in ("fast",)) for tp in tps},
-        "new_buckets_after_warmup": {
-            c: e0[c]["modes"]["budgeted"]["new_buckets_after_warmup"]
-            for c in configs},
-        "steps": {m: e0["eager"]["modes"][m]["steps"] for m in modes},
-        "input_copy_bytes": {c: e0[c]["copy_bytes"] for c in configs},
-        "work_counters": {m: e0["fast"]["modes"][m]["work"]
-                          for m in modes},
-        "preemptions": e0["eager"]["modes"]["preempt"]["preemptions"],
-        "cancelled": sorted(
-            s for s in e0["eager"]["modes"]["cancel"]["status"]
-            .values() if s == "cancelled"),
-        "spec_accepted": e0["eager"]["modes"]["spec"]["accepted"],
-        "prefix_cache_hits": e0["eager"]["modes"]["prefix"]
-        ["cache_hits"],
-        "steady_decode": steady,
-        "host_phase_p50_us": p50,     # reported, not gated
-    }
-    print(f"host leg: token-exact {out['token_exact']}, fast-path "
-          f"copy bytes {out['input_copy_bytes']['fast']} (eager "
-          f"{out['input_copy_bytes']['eager']}), steady-decode reuse "
-          f"{out['steady_decode']['reuse_fraction']}, phase p50s (us) "
-          f"{p50}")
-    return out
-
-
-HOST_KEYS = ("shape", "tps", "modes", "token_exact", "buckets_equal",
-             "new_buckets_after_warmup", "steps", "input_copy_bytes",
-             "work_counters", "preemptions", "cancelled",
-             "spec_accepted", "prefix_cache_hits", "steady_decode")
-
-
-def check_host(base):
-    """CI gate for the host-step fast path: token/status-exact and
-    bucket-set-identical vs the eager scheduler in every mode at every
-    tp, zero step-input copy bytes and zero new warm buckets on the
-    fast path, per-mode work counters exactly the committed dirty-slot
-    schedule, and a steady-decode window at 100% segment reuse."""
-    cur = host_leg()
-    bad = [k for k in HOST_KEYS if cur[k] != base[k]]
-    for k in bad:
-        print(f"MISMATCH {k}: current {cur[k]!r} != baseline {base[k]!r}")
-    for tp, eq in cur["token_exact"].items():
-        if not all(eq.values()):
-            print(f"REGRESSION: fast-path serving at tp={tp} is not "
-                  f"token/status-exact vs eager: {eq}")
-            bad.append("token_exact")
-    if not all(cur["buckets_equal"].values()):
-        print("REGRESSION: the host fast path changed the compile-"
-              f"bucket set: {cur['buckets_equal']}")
-        bad.append("buckets_equal")
-    if any(cur["new_buckets_after_warmup"].values()):
-        print("REGRESSION: fresh compile buckets after warmup: "
-              f"{cur['new_buckets_after_warmup']}")
-        bad.append("new_buckets_after_warmup")
-    if cur["input_copy_bytes"]["fast"] != 0:
-        print(f"REGRESSION: fast config copied "
-              f"{cur['input_copy_bytes']['fast']} step-input bytes "
-              "(must be 0: persistent buffers only)")
-        bad.append("input_copy_bytes")
-    sd = cur["steady_decode"]
-    if sd["reuse_fraction"] != 1.0 or sd["steady_run_len"] < 4:
-        print("REGRESSION: steady-decode window lost segment reuse: "
-              f"{sd}")
-        bad.append("steady_decode")
-    if bad:
-        return 1
-    print(f"host leg OK: token-exact at tp={cur['tps']}, identical "
-          "buckets, 0 copied step-input bytes, steady-decode reuse "
-          f"{sd['reuse_fraction']} over {sd['steady_run_len']} steps, "
-          f"phase p50s (us) {cur['host_phase_p50_us']}")
-    return 0
-
-
 PREFIX_KEYS = ("n_requests", "prefix_len", "suffix_len", "chunk",
                "block_size", "new_tokens", "token_exact_all_modes",
                "new_buckets_after_warmup", "cache", "unshared",
@@ -1767,7 +1341,7 @@ def main():
                     help="comma-separated decode batch sizes")
     ap.add_argument("--skip-paged", action="store_true")
     ap.add_argument("--ragged", action="store_true",
-                    help="run only the ragged-vs-legacy paged leg "
+                    help="run only the ragged paged leg "
                          "(works on CPU via interpret mode)")
     ap.add_argument("--check", metavar="BASELINE_JSON", default=None,
                     help="gate against a committed baseline — runs the "
@@ -1804,12 +1378,6 @@ def main():
                          "shared portion must drop to 1/N and KV-pool "
                          "high-water accordingly, token-exact in every "
                          "mode (works on CPU via interpret mode)")
-    ap.add_argument("--host", action="store_true",
-                    help="host-step fast-path leg: eager vs "
-                         "incremental/in-place host configs "
-                         "across every scheduler mode at tp=1/2 — "
-                         "token-exact, identical buckets, 0 copied "
-                         "step-input bytes, 100%% steady-decode reuse")
     ap.add_argument("--tp", action="store_true",
                     help="tensor-parallel serving on the virtual "
                          "8-device mesh: token-exactness vs single-chip "
@@ -1843,9 +1411,8 @@ def main():
     if args.check:
         with open(args.check) as f:
             base = json.load(f)
-    if args.tp or args.host or (base is not None
-                                and ("tp" in base or "host" in base)):
-        # the tp/host legs need 8 devices: on the CPU (JAX_PLATFORMS=cpu)
+    if args.tp or (base is not None and "tp" in base):
+        # the tp leg needs 8 devices: on the CPU (JAX_PLATFORMS=cpu)
         # that is the virtual mesh, and XLA reads this flag at BACKEND
         # INIT — set it before anything touches jax.devices()
         flag = "--xla_force_host_platform_device_count=8"
@@ -1882,12 +1449,9 @@ def main():
         if "tp" in base:
             ran = True
             rc |= check_tp(base["tp"])
-        if "host" in base:
-            ran = True
-            rc |= check_host(base["host"])
         if not ran:
             print(f"{args.check}: no 'ragged'/'spec'/'trace'/'prefix'/"
-                  "'tp'/'host' section to gate")
+                  "'tp' section to gate")
             return 1
         return rc
     if args.autotune or args.quant:
@@ -1913,8 +1477,7 @@ def main():
             print(f"wrote {args.json}")
         return 0
     if args.ragged or args.metrics or args.prefill or args.spec \
-            or args.no_spec or args.trace or args.prefix or args.tp \
-            or args.host:
+            or args.no_spec or args.trace or args.prefix or args.tp:
         out = {}
         if args.ragged:
             out["ragged"] = ragged_leg()
@@ -1951,9 +1514,6 @@ def main():
         if args.tp:
             # last for the same registry-isolation reason
             out["tp"] = tp_leg()
-        if args.host:
-            # engine-local stats only — safe after any leg
-            out["host"] = host_leg()
         if args.json:
             with open(args.json, "w") as f:
                 json.dump(out, f, indent=1)
@@ -1983,16 +1543,11 @@ def main():
             print(f"  B={B} {q} speedup vs bf16: "
                   f"{out[f'b{B}_{q}_speedup_vs_bf16']:.2f}x")
     if not args.skip_paged:
-        pv = paged_vs_dense_leg()
-        out["paged_vs_dense"] = pv
-        print(f"decode-step attention @ctx={pv['context']}: dense "
-              f"{pv['dense_attn_us_per_step']:.0f} us vs paged "
-              f"{pv['paged_attn_us_per_step']:.0f} us per step")
         rg = ragged_leg()
         out["ragged"] = rg
         print(f"ragged paged leg: {rg['ragged_grid_steps']} grid steps "
-              f"({rg['ragged_call_us']:.0f} us/call) vs legacy "
-              f"{rg['legacy_grid_steps']} ({rg['legacy_call_us']:.0f} us)")
+              f"({rg['ragged_call_us']:.0f} us/call) where a B x KVH x "
+              f"max_blocks grid walks {rg['legacy_grid_steps']}")
     if args.json:
         with open(args.json, "w") as f:
             json.dump(out, f, indent=1)
