@@ -70,6 +70,11 @@ FULL_KERNELS = dict(dtype="bfloat16", KVH=8, G=2, D=64, BS=16, max_nb=16,
                     decode_lens=[40, 16, 0, 129, 200, 1, 77, 256],
                     chunk=64,
                     chunk_qlens=[64, 1, 0, 20, 64, 1, 7, 33],
+                    # a chunk step as the scheduler sends it: one slot
+                    # prefills a full 128-wide chunk beside decoding
+                    # slots, a parked one and a 1 + 3 speculative span
+                    mixed_chunk=128,
+                    mixed_qlens=[1, 128, 0, 1, 1, 1, 4, 1],
                     flash=dict(B=1, S=2048, H=16, D=64))
 
 
@@ -197,6 +202,30 @@ def kernels_leg(k):
         out = pa.ragged_paged_attention(qc, kv, tables, ctx,
                                         q_lens=qlens, buffer_depth=depth)
         _close(f"ragged chunk C={C} depth={depth}", out, ref, tol)
+    # the mixed slab over a BUCKETED work list (its second half padding
+    # entries, which visit no query rows): each entry's grid step walks
+    # the live sub-tiles of its own slot only
+    C = k["mixed_chunk"]
+    qlens = np.asarray(k["mixed_qlens"], np.int32)
+    ctx = np.minimum(lens + qlens, max_nb * BS).astype(np.int32)
+    qc = jnp.asarray(rng.standard_normal((B, C, H, D)), dt)
+    work = pa.build_ragged_work(
+        tables, ctx, BS, pa.default_pack(B, G),
+        bucket_to=lambda n: 2 * pa.next_pow2(n), q_lens=qlens)
+    check(work[2] >= 2 * work[1], "mixed slab: the list is not padded")
+    live, visited = pa.attn_rows(work[0], work[3], C, G, BS)
+    print(f"  ragged mixed C={C}: {work[1]} entries of {work[2]}, "
+          f"{live} live query rows of {visited} visited "
+          f"({work[2] * work[3] * C * G} in whole tiles)", flush=True)
+    ref = pa.ragged_paged_attention_reference(
+        qc, kc, vc, tables, ctx, pack=work[3], q_lens=qlens)
+    for depth in (1, 2):
+        out = pa.ragged_paged_attention(qc, kv, tables, ctx, q_lens=qlens,
+                                        work=work, buffer_depth=depth)
+        _close(f"ragged mixed C={C} depth={depth}", out, ref, tol)
+        dead = np.arange(C)[None, :] >= qlens[:, None]
+        check(not np.asarray(out, np.float32)[dead].any(),
+              "ragged mixed: a dead row is not zero")
 
     # the paged cache writers on the stacked cache, as the engine's
     # programs call them, against numpy (pure data movement: exact)

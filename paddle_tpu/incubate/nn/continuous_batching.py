@@ -56,7 +56,7 @@ import numpy as np
 
 from ...observability import instrument as _metrics
 from ...observability import tracing as _tracing
-from ...ops.pallas.paged_attention import (RaggedWorkBuilder,
+from ...ops.pallas.paged_attention import (RaggedWorkBuilder, attn_rows,
                                            build_ragged_work, default_pack,
                                            next_pow2, step_rows)
 
@@ -729,7 +729,8 @@ class ContinuousBatchingEngine:
         self.on_terminal = None
         kvh = self.caches[0].shape[1]
         num_q = engine.num_heads
-        self._pack = default_pack(self.max_batch, num_q // kvh)
+        self._group_q = num_q // kvh
+        self._pack = default_pack(self.max_batch, self._group_q)
         # committed autotune winners (ops/pallas/autotune.py): passing a
         # cache (path or dict) opts the scheduler into the swept
         # (pack, prefill_chunk) for this EXACT shape class — resolved
@@ -1954,6 +1955,13 @@ class ContinuousBatchingEngine:
                 slab_tokens.labels(kind="live").inc(live)
                 slab_tokens.labels(kind="capacity").inc(
                     step_rows(self.max_batch, c, live))
+                # and of the ragged kernel's query rows: the live ones
+                # of each work entry over the sub-tiles it visited
+                rows_live, rows_visited = attn_rows(
+                    work, pack, c, self._group_q, self.block_size)
+                attn = _metrics.serve_attn_rows()
+                attn.labels(kind="live").inc(rows_live)
+                attn.labels(kind="visited").inc(rows_visited)
             _metrics.serve_step_kind_seconds().labels(kind=kind).observe(
                 pc_done - pc_step)
             if emitted:

@@ -725,10 +725,12 @@ def fused_multi_transformer(
             (`rows`), and each row-wise half runs ROW_TILE rows at a
             time, rows.n_tiles times (a trip count read on the device).
             The layer's cache rides the first loop as a carry: a tile's
-            K/V rows are appended where the buffer lies. Attention keeps
-            the slab's [B, C] geometry: one gather lays the packed q
-            rows into it (a dead cell reads some other row; the kernel
-            masks it by q_lens) and one reads ctx back. qp [R, H, D] is
+            K/V rows are appended where the buffer lies. The kernel keeps
+            the slab's [B, C] geometry on its query side: one gather
+            lays the packed q rows into it (a dead cell reads some other
+            row, which no grid step visits). Its output is never laid
+            out as a slab: the second loop reads each tile's ctx rows
+            out of the kernel's own tiles (`tile_rows`). qp [R, H, D] is
             the packed q rows' buffer, any layer's.
 
             Jitted, with everything traced among its arguments: the
@@ -751,17 +753,20 @@ def fused_multi_transformer(
 
             qp, cache = over_row_tiles(rows.n_tiles, before, (qp, cache))
             with jax.named_scope("attention"):
-                ctx = ragged_paged_attention(
-                    qp[rows.back], cache, tables, ln + ql,
-                    scale=1.0 / math.sqrt(qp.shape[-1]),
-                    work=(work, None, work[0].shape[0], ragged_pack),
-                    q_lens=ql, buffer_depth=kv_buffer_depth)
-                ctx = ctx.astype(hp.dtype)[rows.slot, rows.col]  # [R,H,D]
+                tiles = ragged_attention_tiles(
+                    qp[rows.back], cache,
+                    (work, None, work[0].shape[0], ragged_pack),
+                    buffer_depth=kv_buffer_depth)
 
             def after(r0, hp):
+                with jax.named_scope("attention"):
+                    ctx = tile_rows(
+                        tiles, row_tile(rows.slot, r0),
+                        row_tile(rows.col, r0), row_tile(rows.live, r0),
+                        ragged_pack, rows.back.shape[1], *qp.shape[1:])
                 return put_row_tile(hp, finish(
-                    row_tile(hp, r0)[None], row_tile(ctx, r0)[None], lw,
-                    li, None if dkey is None
+                    row_tile(hp, r0)[None], ctx.astype(hp.dtype)[None],
+                    lw, li, None if dkey is None
                     else jax.random.fold_in(dkey, r0))[0], r0)
 
             return over_row_tiles(rows.n_tiles, after, hp), qp, cache
@@ -771,7 +776,8 @@ def fused_multi_transformer(
             from ....ops.pallas.paged_attention import (
                 ROW_TILE, append_paged_kv_chunk, append_paged_kv_rows,
                 live_rows, over_row_tiles, put_row_tile,
-                ragged_paged_attention, row_tile)
+                ragged_attention_tiles, ragged_paged_attention, row_tile,
+                tile_rows)
             padded = rows is None and b * s > ROW_TILE
         if padded:
             # a wide slab handed over as [B, C, E]: packed here, and

@@ -40,7 +40,7 @@ __all__ = [
     "autotune_trials", "autotune_cache_hits", "autotune_cache_misses",
     "autotune_winner",
     "serve_host_phase_seconds", "serve_step_kind_seconds",
-    "serve_slab_tokens",
+    "serve_slab_tokens", "serve_attn_rows",
 ]
 
 
@@ -99,6 +99,18 @@ def serve_slab_tokens():
              "max_batch x slab width, or a wide slab's live row tiles) — "
              "live over capacity is how full the computed rows ran",
         labels=("kind",))      # bounded: live | capacity
+
+
+def serve_attn_rows():
+    return get_registry().counter(
+        "serve_attn_rows_total",
+        help="query rows of the ragged kernel on the chunk steps, summed "
+             "over the work list's entries (per kv head and layer): live "
+             "(rows whose query sees some of the entry's cache block) vs "
+             "visited (rows of the sub-tiles the kernel multiplied for "
+             "them) — live over visited is how full the kernel's "
+             "sub-tiles ran",
+        labels=("kind",))      # bounded: live | visited
 
 
 def dispatch_seconds():

@@ -40,6 +40,23 @@ back with `truncate_paged_kv`. The packed tile grows to [pack*C*G, D]
 to its own absolute position, so a 512-token prompt costs ceil(512/C)
 steps at C-row MXU intensity instead of 512 steps at one row.
 
+A grid step does NOT multiply that whole tile. Its entry is one cache
+block of one slot, of whose span q_len positions are live this step, so
+it visits only the SUB_ROWS-row sub-tiles of the tile that hold those
+q_len*G rows (a `lax.fori_loop` over `pl.ds` slices of the query block,
+the running max / sum / accumulator scratch and the output block, its
+trip count read from the scalar-prefetched q_len, its first trip from
+the causal bound: a sub-tile whose last query sits before the block's
+first position is skipped too). A decode slot in a 128-wide chunk step
+costs one sub-tile where it cost the 1024-row tile, a prefilling slot
+what its span holds, and a padding entry of the bucketed list waits for
+its DMA and returns. The accumulators start over at a slot's first block
+and its live rows are written out at its last, so rows no slot had live
+are never written (the wrapper masks them). A tile of at most SUB_ROWS
+rows (every decode bucket) is its own single sub-tile, with no loop.
+`attn_rows` counts the same trips on the host for the scheduler's
+`serve_attn_rows_total`.
+
 The work list is built host-side (`build_ragged_work`) because the block
 allocator that owns the tables is host code anyway; under `jax.jit` the
 caller passes the arrays in (`work=`) and the list length stays static
@@ -406,10 +423,83 @@ class RaggedWorkBuilder:
         return arrs, t_real, t_total, self.pack
 
 
+# Rows of one query sub-tile. A grid step multiplies only the sub-tiles
+# that hold live query rows of its own entry's slot, so the height trades
+# the rows a decode entry pays for beyond its G live ones against the
+# number of trips a prefilling slot's entry makes (each trip loads the
+# block into the MXU again). 64 is a whole number of sublane tiles of
+# every dtype the kernel takes (8 rows of f32, 16 of bf16, 32 of int8),
+# so a sub-tile's first row is always tile-aligned.
+SUB_ROWS = 64
+
+
+def query_subtile(pack, chunk, group_q):
+    """(sub, rows): the height of the kernel's query sub-tiles for a
+    packed tile of pack*chunk*group_q rows, and the tile's rows padded to
+    whole sub-tiles. A tile no taller than SUB_ROWS is its own single
+    sub-tile (every decode bucket: 8 rows)."""
+    rows = pack * chunk * group_q
+    if rows <= SUB_ROWS:
+        return rows, rows
+    return SUB_ROWS, -(-rows // SUB_ROWS) * SUB_ROWS
+
+
+class _Trips(typing.NamedTuple):
+    """What one work entry visits of its group's packed query tile."""
+    lo: typing.Any      # first sub-tile holding a row that sees the block
+    hi: typing.Any      # one past the last sub-tile holding a live row
+    start: typing.Any   # the sub-tile the slot's first row lies in
+    first: typing.Any   # the slot's first row
+    rows: typing.Any    # live rows of the slot's span (q_len x G)
+    seen: typing.Any    # of them, rows whose query sees the block
+
+
+def _subtile_trips(xp, wr, wpos, wqs, wql, *, chunk, group_q, sub, rows,
+                   block_size):
+    """`_Trips` of one work entry (or, on the host, of a whole list's
+    arrays). The slot's span starts at row wr*chunk*group_q of the
+    packed tile and wql query positions of it are live; the first
+    `unseen` of those sit before the block's first cache position, and
+    the sub-tiles that hold nothing else are skipped. A padding entry
+    (wql 0) visits none: hi == lo. `xp` is jnp inside the kernel
+    (scalars read from SMEM: shifts, no division) and numpy on the host
+    (`attn_rows`): one arithmetic."""
+    ql = xp.minimum(wql, chunk)
+    unseen = xp.clip(wpos * block_size - wqs, 0, ql)
+    first = wr * (chunk * group_q)
+    if sub == rows:                     # the tile is its one sub-tile
+        lo = start = first * 0
+        hi = xp.where(ql > unseen, 1, 0)
+    else:
+        shift = sub.bit_length() - 1
+        assert sub == 1 << shift, sub
+        lo = (first + unseen * group_q) >> shift
+        start = first >> shift
+        hi = xp.where(ql > unseen,
+                      (first + ql * group_q + sub - 1) >> shift, lo)
+    return _Trips(lo, hi, start, first, ql * group_q,
+                  (ql - unseen) * group_q)
+
+
+def attn_rows(work, pack, chunk, group_q, block_size):
+    """(live, visited) query rows of one ragged call over `work` (the
+    nine arrays), per kv head: the rows whose query sees some of its
+    entry's cache block, and the rows of the sub-tiles the kernel
+    multiplies for them. Host arithmetic for the scheduler's counter,
+    from the function the kernel takes its trip counts from."""
+    wr, wpos, wqs, wql = (
+        np.asarray(work[i], np.int64) for i in (2, 4, 7, 8))
+    sub, rows = query_subtile(pack, chunk, group_q)
+    trips = _subtile_trips(
+        np, wr, wpos, wqs, wql, chunk=chunk, group_q=group_q, sub=sub,
+        rows=rows, block_size=block_size)
+    return int(trips.seen.sum()), int((trips.hi - trips.lo).sum()) * sub
+
+
 def _ragged_kernel(ws, wg, wr, wblk, wpos, wfirst, wlast, wqs, wql,
                    q_ref, kv_hbm, o_ref,
                    kbuf, vbuf, ksem, vsem, m_scr, l_scr, acc,
-                   *, block_size, scale, group_q, chunk, depth=2):
+                   *, block_size, scale, group_q, chunk, sub, depth=2):
     hh = pl.program_id(0)
     t = pl.program_id(1)
     nt = pl.num_programs(1)
@@ -448,61 +538,120 @@ def _ragged_kernel(ws, wg, wr, wblk, wpos, wfirst, wlast, wqs, wql,
         kdma((t + depth - 1) % depth, t + depth - 1).start()
         vdma((t + depth - 1) % depth, t + depth - 1).start()
 
-    @pl.when(wfirst[t] == 1)
+    # The packed tile holds `pack` slots' query spans (chunk positions x
+    # G group rows each, row = (slot*chunk + j)*G + gr) and this entry
+    # is one cache block of ONE of them, of whose span wql[t] positions
+    # are live this step: a decode slot's one, a prefilling slot's up to
+    # `chunk`, a padding entry's none. Only the sub-tiles that hold
+    # those rows are touched, here and in `_init` and `_final`; a
+    # sub-tile may also hold rows of the slot's neighbours, which the
+    # row mask leaves alone. (wfirst / wlast mark a GROUP's first and
+    # last entry for the whole-tile reference; the kernel starts and
+    # finishes per slot, which wpos tells it.)
+    trips = _subtile_trips(
+        jnp, wr[t], wpos[t], wqs[t], wql[t], chunk=chunk, group_q=group_q,
+        sub=sub, rows=m_scr.shape[0], block_size=block_size)
+
+    def over_subtiles(lo, body):
+        """body(rows, r0) for the sub-tiles lo .. trips.hi - 1: `rows`
+        indexes the sub-tile's rows in a ref, r0 is its first row."""
+        if sub == m_scr.shape[0]:   # one sub-tile: the caller's pl.when
+            return body(slice(None), 0)
+
+        def trip(i, carry):
+            # the trip range is host-built data (wr, wql): a row past
+            # the tile would not fault, it would alias other scratch
+            r0 = pl.multiple_of(
+                jnp.minimum(i, m_scr.shape[0] // sub - 1) * sub, sub)
+            body(pl.ds(r0, sub), r0)
+            return carry
+
+        jax.lax.fori_loop(lo, trips.hi, trip, 0)
+
+    def live_rows_of(r0, shape):
+        """[*shape] bool: row r0 + i is a live query row of this slot;
+        and the row's position in the slot's span."""
+        rel = r0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0) \
+            - trips.first
+        g_shift = group_q.bit_length() - 1      # a shift where G allows
+        j = rel >> g_shift if group_q == 1 << g_shift else rel // group_q
+        return (rel >= 0) & (rel < trips.rows), j
+
+    # the slot's first block: its rows' running max, sum and accumulator
+    # start over. The sub-tiles are set whole: a neighbour whose rows
+    # share one is either done (its `_final` has run) or yet to start
+    @pl.when(wpos[t] == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc[...] = jnp.zeros_like(acc)
+        def body(rows, _):
+            m_scr[rows, :] = jnp.full((sub, LANES), NEG_INF, jnp.float32)
+            l_scr[rows, :] = jnp.zeros((sub, LANES), jnp.float32)
+            acc[rows, :] = jnp.zeros((sub, acc.shape[1]), jnp.float32)
+        over_subtiles(trips.start, body)
 
     kdma(t % depth, t).wait()
     vdma(t % depth, t).wait()
 
-    span = chunk * group_q                            # rows per sequence
-    q = q_ref[0, 0].astype(jnp.float32)              # [pack*chunk*G, D]
-    k = kbuf[t % depth].astype(jnp.float32)          # [BS, D]
-    v = vbuf[t % depth].astype(jnp.float32)          # [BS, D]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale   # [pack*chunk*G, BS]
-    # the packed tile holds `pack` sequences' query spans (chunk query
-    # positions x G group rows each); only the rows of THIS work item's
-    # sequence may see this KV block — everyone else is masked to a
-    # numerical no-op (p == 0, m/l/acc carried through). Within the
-    # sequence, query position j sits at absolute position q_start + j:
-    # rows past the valid span (j >= q_len) and KV positions a query may
-    # not see yet (pos > q_start + j, the intra-chunk causal boundary —
-    # which also caps at q_start + q_len - 1 == ctx - 1) mask off.
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    rel = row - wr[t] * span
-    j = rel // group_q                                # chunk position
-    pos = wpos[t] * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
-    mask = ((rel >= 0) & (rel < span) & (j < wql[t])
-            & (pos <= wqs[t] + j))
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(
-        m_prev, jnp.max(jnp.where(mask, s, NEG_INF), axis=1, keepdims=True))
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(m_prev - m_new)    # masked rows: exp(0) == 1, no-op
-    l_scr[...] = jnp.broadcast_to(
-        corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
-    acc[...] = acc[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    @pl.when(trips.hi > trips.lo)
+    def _attend():
+        k = kbuf[t % depth].astype(jnp.float32)          # [BS, D]
+        v = vbuf[t % depth].astype(jnp.float32)          # [BS, D]
 
-    @pl.when(wlast[t] == 1)
+        def body(rows, r0):
+            q = q_ref[0, 0, rows, :].astype(jnp.float32)  # [sub, D]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [sub, BS]
+            # query position j of the span sits at absolute position
+            # q_start + j and sees the cache up to itself (the
+            # intra-chunk causal boundary, which also caps at
+            # q_start + q_len - 1 == ctx - 1); rows that are not this
+            # slot's live ones are a numerical no-op (p == 0, m/l/acc
+            # carried through)
+            live, j = live_rows_of(r0, s.shape)
+            pos = wpos[t] * block_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            mask = live & (pos <= wqs[t] + j)
+            m_prev = m_scr[rows, :1]
+            m_new = jnp.maximum(
+                m_prev,
+                jnp.max(jnp.where(mask, s, NEG_INF), axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)    # masked rows: exp(0) == 1
+            l_scr[rows, :] = jnp.broadcast_to(
+                corr * l_scr[rows, :1] + jnp.sum(p, axis=1, keepdims=True),
+                (sub, LANES))
+            acc[rows, :] = acc[rows, :] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[rows, :] = jnp.broadcast_to(m_new, (sub, LANES))
+
+        over_subtiles(trips.lo, body)
+
+    # the slot's last block (the next entry does not continue it): its
+    # live rows go to the output tile, the rows around them stay as they
+    # are. Rows no slot ever writes are masked off after the call
+    nxt = jnp.minimum(t + 1, nt - 1)
+
+    @pl.when((wql[t] > 0)
+             & ((t == nt - 1) | (wpos[nxt] != wpos[t] + 1)))
     def _final():
-        l = l_scr[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
+        def body(rows, r0):
+            l = l_scr[rows, :1]
+            l = jnp.where(l == 0.0, 1.0, l)
+            live, _ = live_rows_of(r0, (sub, acc.shape[1]))
+            o_ref[0, 0, rows, :] = jnp.where(
+                live, (acc[rows, :] / l).astype(o_ref.dtype),
+                o_ref[0, 0, rows, :])
+        over_subtiles(trips.start, body)
 
 
-def _pack_queries(q, kvh, g, pack):
-    """[B, C, H, D] -> [ngroups, KVH, pack*C*G, D] (+zero rows past B).
+def _pack_queries(q, kvh, g, pack, rows):
+    """[B, C, H, D] -> [ngroups, KVH, rows, D] (+zero rows past B and
+    past pack*C*G, up to the tile's whole sub-tiles).
 
     Row order within a group is sequence-major, then chunk position,
     then GQA group row — row = (slot*C + j)*G + gr — matching the
-    kernel's rel/j decomposition."""
+    kernel's row arithmetic."""
     b, c, h, d = q.shape
     ngroups = -(-b // pack)
     qg = q.reshape(b, c, kvh, g, d)
@@ -510,16 +659,20 @@ def _pack_queries(q, kvh, g, pack):
     if pad:
         qg = jnp.concatenate(
             [qg, jnp.zeros((pad,) + qg.shape[1:], qg.dtype)], 0)
-    return qg.reshape(ngroups, pack, c, kvh, g, d) \
+    qp = qg.reshape(ngroups, pack, c, kvh, g, d) \
         .transpose(0, 3, 1, 2, 4, 5) \
         .reshape(ngroups, kvh, pack * c * g, d)
+    if rows > pack * c * g:
+        qp = jnp.pad(qp, [(0, 0), (0, 0), (0, rows - pack * c * g), (0, 0)])
+    return qp
 
 
 def _unpack_outputs(out, b, c, h, g, pack):
     ngroups = out.shape[0]
     kvh = out.shape[1]
     d = out.shape[-1]
-    return out.reshape(ngroups, kvh, pack, c, g, d) \
+    return out[:, :, :pack * c * g] \
+        .reshape(ngroups, kvh, pack, c, g, d) \
         .transpose(0, 2, 3, 1, 4, 5) \
         .reshape(ngroups * pack, c, h, d)[:b]
 
@@ -586,11 +739,10 @@ def ragged_paged_attention(q, kv_cache, block_tables, context_lens,
         raise ValueError(
             "ragged_paged_attention takes one [2, KVH, NB, BS, Dc] cache "
             f"(K and V stacked), got shape {tuple(kv_cache.shape)}")
-    _, kvh, _, block_size, d = kv_cache.shape
+    _, kvh, _, block_size, _ = kv_cache.shape
     g = h // kvh
     if scale is None:
         scale = 1.0 / math.sqrt(d_q)
-    q = _lane_pad(q, d)
     if work is not None:
         work_arrs, t_total = work[0], work[2]
         work_pack = work[3] if len(work) > 3 else None
@@ -615,13 +767,57 @@ def ragged_paged_attention(q, kv_cache, block_tables, context_lens,
     if t_total == 0:
         out = jnp.zeros((b, c, h, d_q), q.dtype)
         return out[:, 0] if squeeze else out
+    # only a slot's live rows are ever written: every other row (a
+    # len 0 / q_len 0 slot's, the columns past q_len) carries
+    # uninitialised VMEM, and is masked off by its slot's count of
+    # valid columns
+    if q_lens is None:
+        n_valid = jnp.asarray(context_lens).reshape(-1) > 0
+    else:
+        n_valid = jnp.asarray(q_lens).reshape(-1)
+    out = _ragged_call(
+        tuple(jnp.asarray(a, jnp.int32) for a in work_arrs), q, kv_cache,
+        n_valid.astype(jnp.int32), scale=float(scale), pack=pack,
+        depth=buffer_depth, interpret=_interpret_mode())
+    return out[:, 0] if squeeze else out
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "pack", "depth", "interpret"))
+def _ragged_call(work, q, kv_cache, n_valid, *, scale, pack, depth,
+                 interpret):
+    """The kernel over q [B, C, H, D], its packing and its output laid
+    back into the slab, as ONE jitted function: the layers of a step
+    call it with the same shapes, so one trace of the kernel's body and
+    one lowering to Mosaic serve them all (a step that unrolls 16 layers
+    would trace and lower it 16 times; the set-up of a serving process
+    is mostly that)."""
+    b, c, h, d_q = q.shape
+    out = _ragged_tiles(work, q, kv_cache, scale=scale, pack=pack,
+                        depth=depth, interpret=interpret)
+    out = _unpack_outputs(
+        out, b, c, h, h // kv_cache.shape[1], pack)[..., :d_q]
+    valid = jnp.arange(c)[None, :] < n_valid[:, None]            # [B, C]
+    return jnp.where(valid[:, :, None, None], out, 0.0)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "pack", "depth", "interpret"))
+def _ragged_tiles(work, q, kv_cache, *, scale, pack, depth, interpret):
+    """q [B, C, H, D] packed into the kernel's query tiles and the
+    kernel's output tiles [ngroups, KVH, rows, Dc] as it leaves them, in
+    the same row order (`_pack_queries`): only the rows some slot had
+    live were written."""
+    b, c, h, _ = q.shape
+    _, kvh, _, block_size, d = kv_cache.shape
+    g = h // kvh
     ngroups = -(-b // pack)
-    pg = pack * c * g
-    qp = _pack_queries(q, kvh, g, pack)
+    sub, pg = query_subtile(pack, c, g)
+    qp = _pack_queries(_lane_pad(q, d), kvh, g, pack, pg)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=9,
-        grid=(kvh, t_total),
+        grid=(kvh, work[0].shape[0]),
         in_specs=[
             pl.BlockSpec((1, 1, pg, d),
                          lambda hh, t, ws, wg, *_: (wg[t], hh, 0, 0)),
@@ -630,19 +826,19 @@ def ragged_paged_attention(q, kv_cache, block_tables, context_lens,
         out_specs=pl.BlockSpec(
             (1, 1, pg, d), lambda hh, t, ws, wg, *_: (wg[t], hh, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((buffer_depth, block_size, d), kv_cache.dtype),
-            pltpu.VMEM((buffer_depth, block_size, d), kv_cache.dtype),
-            pltpu.SemaphoreType.DMA((buffer_depth,)),
-            pltpu.SemaphoreType.DMA((buffer_depth,)),
+            pltpu.VMEM((depth, block_size, d), kv_cache.dtype),
+            pltpu.VMEM((depth, block_size, d), kv_cache.dtype),
+            pltpu.SemaphoreType.DMA((depth,)),
+            pltpu.SemaphoreType.DMA((depth,)),
             pltpu.VMEM((pg, LANES), jnp.float32),
             pltpu.VMEM((pg, LANES), jnp.float32),
             pltpu.VMEM((pg, d), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_ragged_kernel, block_size=block_size,
-                          scale=float(scale), group_q=g, chunk=c,
-                          depth=buffer_depth),
+                          scale=scale, group_q=g, chunk=c, sub=sub,
+                          depth=depth),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ngroups, kvh, pg, d), q.dtype),
         # the HLO instruction takes this name (`%paged_step_ragged_attn.N
@@ -650,19 +846,39 @@ def ragged_paged_attention(q, kv_cache, block_tables, context_lens,
         # what the instruction was called after the enclosing jit before
         # it had a name, and what the benchmark's first reader matches
         name="paged_step_ragged_attn",
-        interpret=_interpret_mode(),
-    )(*[jnp.asarray(a, jnp.int32) for a in work_arrs],
-      qp, kv_cache)
-    out = _unpack_outputs(out, b, c, h, g, pack)[..., :d_q]
-    # rows whose group was never visited (len 0 / q_len 0) carry
-    # uninitialised VMEM — mask every invalid (seq, chunk-pos) row off
-    if q_lens is None:
-        valid = jnp.asarray(context_lens).reshape(-1, 1) > 0     # [B, 1]
-    else:
-        valid = (jnp.arange(c)[None, :]
-                 < jnp.asarray(q_lens).reshape(-1, 1))           # [B, C]
-    out = jnp.where(valid[:, :, None, None], out, 0.0)
-    return out[:, 0] if squeeze else out
+        interpret=interpret,
+    )(*work, qp, kv_cache)
+
+
+def ragged_attention_tiles(q, kv_cache, work, scale=None, buffer_depth=2):
+    """`ragged_paged_attention` over a prebuilt `work` 4-tuple, stopping
+    at the kernel's own output tiles: for a caller that wants a few of
+    the slab's rows back (`tile_rows`) and not the [B, C, H, D] slab,
+    most of which a wide step never had live."""
+    work_arrs, _, t_total, pack = work
+    b, c, h, d_q = q.shape
+    kvh, d = kv_cache.shape[1], kv_cache.shape[-1]
+    if t_total == 0:
+        return jnp.zeros((-(-b // pack), kvh,
+                          query_subtile(pack, c, h // kvh)[1], d), q.dtype)
+    return _ragged_tiles(
+        tuple(jnp.asarray(a, jnp.int32) for a in work_arrs), q, kv_cache,
+        scale=float(1.0 / math.sqrt(d_q) if scale is None else scale),
+        pack=pack, depth=int(buffer_depth), interpret=_interpret_mode())
+
+
+def tile_rows(tiles, slot, col, live, pack, chunk, heads, head_dim):
+    """The attention output rows [n, H, D] of the slab cells
+    (slot[i], col[i]), gathered out of the kernel's tiles where they lie
+    (row (slot % pack * chunk + col) * G + gr of group slot // pack,
+    under each kv head); rows that are not `live` come back zero."""
+    ngroups, kvh, _, d = tiles.shape
+    g = heads // kvh
+    cells = tiles[:, :, :pack * chunk * g].reshape(
+        ngroups, kvh, pack, chunk, g, d)
+    out = cells[slot // pack, :, slot % pack, col]       # [n, KVH, G, Dc]
+    out = jnp.where(live[:, None, None, None], out, 0.0)
+    return out.reshape(-1, heads, d)[..., :head_dim]
 
 
 def ragged_paged_attention_reference(q, k_cache, v_cache, block_tables,
@@ -671,9 +887,12 @@ def ragged_paged_attention_reference(q, k_cache, v_cache, block_tables,
     """Plain-JAX (no Pallas) execution of the ragged algorithm: same work
     list, same packed tiles, same online-softmax update, same query-span
     masking — each update jitted as one program so XLA applies the same
-    FMA contraction as inside the kernel. On the CPU interpret grid the
-    kernel must match this BIT-EXACTLY; it is also the validation oracle
-    the serving tests diff against."""
+    FMA contraction as inside the kernel. It multiplies every entry's
+    WHOLE packed tile, the algorithm the kernel visits the live
+    sub-tiles of: a row's running max, sum and accumulator see the same
+    values either way. On the CPU interpret grid the kernel must match
+    this BIT-EXACTLY; it is also the validation oracle the serving tests
+    diff against."""
     q = jnp.asarray(q)
     squeeze = q.ndim == 3
     if squeeze:
@@ -693,7 +912,7 @@ def ragged_paged_attention_reference(q, k_cache, v_cache, block_tables,
         build_ragged_work(block_tables, lens, bs, pack, q_lens=q_lens)
     span = c * g
     pg = pack * span
-    qp = _pack_queries(q, kvh, g, pack)
+    qp = _pack_queries(q, kvh, g, pack, pg)
     ngroups = qp.shape[0]
 
     @jax.jit
@@ -756,8 +975,10 @@ def ragged_paged_attention_reference(q, k_cache, v_cache, block_tables,
 # the front of a [B * C]-row buffer and walks ROW_TILE rows at a time,
 # ceil(n_live / ROW_TILE) times: a trip count read on the device, so the
 # step's arguments, shapes and compile buckets do not know about it. Only
-# attention keeps the slab's [B, C] geometry (`LiveRows.back` lays the
-# packed q rows into it, `slot`/`col` read ctx back).
+# the attention kernel's query side keeps the slab's [B, C] geometry
+# (`LiveRows.back` lays the packed q rows into it); its output is read
+# back a row tile at a time from the kernel's own tiles (`tile_rows` by
+# `slot`/`col`), so no [B, C, H, D] slab of ctx is ever written.
 
 # Rows per tile: the v5e's ridge. A bf16 weight streamed once from HBM at
 # 819 GB/s pays for ~240 rows of matmul at 197 TFLOP/s, so a 256-row tile

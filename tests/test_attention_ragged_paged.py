@@ -201,6 +201,139 @@ class TestRaggedKernel:
                                    rtol=1e-6, atol=1e-6)
 
 
+def _mixed_slab(q0, g, pack, seed, dtype=np.float32, kvh=2, d=32, bs=16):
+    """A chunk step's slab as the scheduler hands it over: slot 1
+    prefills q0 tokens, slots 0 and 4 decode one, slot 2 is parked
+    (q_len 0, its cache left as it is), slot 3 verifies a 1 + 3
+    speculative span; the work list is bucketed to twice its power of
+    two, so its second half is padding."""
+    rng = np.random.default_rng(seed)
+    q_lens = np.asarray([1, q0, 0, 4, 1], np.int32)
+    ctx = np.asarray([20, q0 + 9, 33, 40, 1], np.int32)  # span included
+    b, c = len(q_lens), pa.next_pow2(max(q0, 4))
+    max_nb = -(-int(ctx.max()) // bs)
+    nblk = b * max_nb + 3
+    q = rng.standard_normal((b, c, kvh * g, d)).astype(dtype)
+    kc = rng.standard_normal((kvh, nblk, bs, d)).astype(dtype)
+    vc = rng.standard_normal((kvh, nblk, bs, d)).astype(dtype)
+    tables = rng.permutation(nblk)[:b * max_nb].reshape(
+        b, max_nb).astype(np.int32)
+    work = pa.build_ragged_work(
+        tables, ctx, bs, pack, bucket_to=lambda n: 2 * pa.next_pow2(n),
+        q_lens=q_lens)
+    assert work[2] >= 2 * work[1] and not work[0][8][work[2] // 2:].any()
+    return q, kc, vc, tables, ctx, q_lens, work
+
+
+class TestLiveQueryRows:
+    """A grid step visits only the sub-tiles of the packed query tile
+    that hold live rows of its own entry's slot: the results are those
+    of the whole-tile algorithm, bit for bit, and a row no slot had live
+    comes back zero."""
+
+    @pytest.mark.parametrize("g", [1, 4, 8])
+    @pytest.mark.parametrize("pack", [1, 2, 3])
+    @pytest.mark.parametrize("q0", [1, 7, 100, 128])
+    def test_mixed_slab_bit_exact_vs_reference(self, q0, pack, g):
+        q, kc, vc, tables, ctx, q_lens, work = _mixed_slab(
+            q0, g, pack, seed=q0 + 10 * pack + g)
+        out = np.asarray(pa.ragged_paged_attention(
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
+            jnp.asarray(tables), jnp.asarray(ctx), q_lens=q_lens,
+            work=work))
+        ref = np.asarray(pa.ragged_paged_attention_reference(
+            q, kc, vc, tables, ctx, pack=pack, q_lens=q_lens))
+        np.testing.assert_array_equal(out, ref)
+        dead = np.arange(q.shape[1])[None, :] >= q_lens[:, None]
+        assert not out[dead].any() and np.isfinite(out).all()
+        assert np.abs(out[~dead]).min(-1).max() > 0   # and the live ones ran
+
+    @pytest.mark.parametrize("q0,pack,g,depth", [
+        (100, 2, 4, 1), (128, 2, 4, 3), (7, 3, 8, 1)])
+    def test_bf16_and_buffer_depths(self, q0, pack, g, depth):
+        import ml_dtypes
+        q, kc, vc, tables, ctx, q_lens, work = _mixed_slab(
+            q0, g, pack, seed=5, dtype=ml_dtypes.bfloat16)
+        out = pa.ragged_paged_attention(
+            jnp.asarray(q), jnp.asarray(np.stack([kc, vc])),
+            jnp.asarray(tables), jnp.asarray(ctx), q_lens=q_lens,
+            work=work, buffer_depth=depth)
+        ref = pa.ragged_paged_attention_reference(
+            q, kc, vc, tables, ctx, pack=pack, q_lens=q_lens)
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(ref, np.float32))
+
+    @pytest.mark.parametrize("q0,pack,g", [
+        (128, 2, 4), (100, 3, 1), (7, 2, 8), (1, 1, 4), (100, 1, 8)])
+    def test_trips_cover_exactly_the_rows_the_mask_keeps(self, q0, pack, g):
+        """`_subtile_trips`, which the kernel loops by and `attn_rows`
+        counts by, against the row mask written out per entry: the
+        sub-tiles that hold a row whose query sees some of the block are
+        lo .. hi - 1, no more and no fewer."""
+        *_, ctx, q_lens, work = _mixed_slab(q0, g, pack, seed=1)
+        c, bs = pa.next_pow2(max(q0, 4)), 16
+        sub, rows = pa.query_subtile(work[3], c, g)
+        assert rows % sub == 0 and rows >= work[3] * c * g
+        live = visited = 0
+        _, _, wr, _, wpos, _, _, wqs, wql = work[0]
+        for t in range(work[2]):
+            row = np.arange(rows)
+            rel = row - wr[t] * c * g
+            j = rel // g
+            sees = ((rel >= 0) & (rel < c * g) & (j < wql[t])
+                    & (wpos[t] * bs <= wqs[t] + j))
+            tiles = np.unique(row[sees] // sub)
+            trips = pa._subtile_trips(
+                np, wr[t], wpos[t], wqs[t], wql[t], chunk=c, group_q=g,
+                sub=sub, rows=rows, block_size=bs)
+            lo, hi = trips.lo, trips.hi
+            assert trips.seen == sees.sum() and hi - lo == len(tiles)
+            assert not len(tiles) or (tiles[0], tiles[-1]) == (lo, hi - 1)
+            assert trips.start <= lo and trips.rows == min(wql[t], c) * g
+            live += sees.sum()
+            visited += len(tiles) * sub
+        assert (live, visited) == pa.attn_rows(work[0], work[3], c, g, bs)
+        assert 0 < live <= visited
+
+    def test_a_tile_no_taller_than_a_sub_tile_is_one_static_trip(self):
+        # every decode bucket: pack * G = 8 rows, no loop in the kernel
+        assert pa.query_subtile(2, 1, 4) == (8, 8)
+        assert pa.query_subtile(2, 8, 4) == (64, 64)
+        assert pa.query_subtile(2, 16, 4) == (pa.SUB_ROWS, 128)
+        assert pa.query_subtile(3, 5, 8) == (pa.SUB_ROWS, 128)   # 120 rows
+        q, kc, vc, tables, lens = _setup(8, 2, RAGGED_LENS)
+        jaxpr = jax.make_jaxpr(lambda q, kv: pa.ragged_paged_attention(
+            q, kv, tables, lens))(
+                jnp.asarray(q), jnp.asarray(np.stack([kc, vc])))
+        assert "while" not in [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+
+    @pytest.mark.parametrize("q0,pack,g", [
+        (128, 2, 4), (100, 3, 1), (7, 3, 8), (1, 1, 4)])
+    def test_rows_read_out_of_the_tiles_are_the_slabs(self, q0, pack, g):
+        """What a wide step does: `tile_rows` picks single cells out of
+        the kernel's own output tiles. They are the cells of the
+        [B, C, H, D] slab `ragged_paged_attention` lays out, a dead one
+        zero, with no slab in between."""
+        q, kc, vc, tables, ctx, q_lens, work = _mixed_slab(
+            q0, g, pack, seed=3)
+        kv = jnp.asarray(np.stack([kc, vc]))
+        slab = np.asarray(pa.ragged_paged_attention(
+            jnp.asarray(q), kv, jnp.asarray(tables), jnp.asarray(ctx),
+            q_lens=q_lens, work=work))
+        tiles = pa.ragged_attention_tiles(jnp.asarray(q), kv, work)
+        b, c, h, d = q.shape
+        assert tiles.shape[:2] == (-(-b // pack), kc.shape[0])
+        slot, col = (a.reshape(-1) for a in np.indices((b, c)))
+        order = np.random.default_rng(q0).permutation(b * c)
+        slot, col = slot[order], col[order]
+        live = col < q_lens[slot]
+        rows = np.asarray(pa.tile_rows(
+            tiles, jnp.asarray(slot), jnp.asarray(col), jnp.asarray(live),
+            pack, c, h, d))
+        np.testing.assert_array_equal(rows, slab[slot, col])
+        assert live.any() and not rows[~live].any()
+
+
 class TestCacheUpdateBoundary:
     def _setup(self, lens):
         rng = np.random.default_rng(7)
@@ -564,7 +697,10 @@ class TestStepNeverCopiesTheCache:
         heavy = {"dot_general", "scatter"}
         rows_in_loop, loops, layer_fns = set(), 0, set()
         for eqn, path in _eqns(traced.jaxpr.jaxpr, ()):
-            loops += eqn.primitive.name == "while"
+            # (the ragged kernel's own loop over its entry's live query
+            # sub-tiles is not the step's)
+            loops += (eqn.primitive.name == "while"
+                      and "pallas_call" not in path)
             if eqn.params.get("name") == "packed_paged_layer":
                 layer_fns.add(id(eqn.params["jaxpr"]))
             if eqn.primitive.name not in heavy or "pallas_call" in path:
@@ -584,6 +720,20 @@ class TestStepNeverCopiesTheCache:
         # the embedding's loop and two a layer (the walk enters each
         # call), all of them the same function
         assert loops == 1 + 2 * len(cb.caches) and len(layer_fns) == 1
+        # the kernel's output is never laid out as a [B, C, H, D] slab:
+        # a layer's second loop gathers its tile's rows out of the
+        # kernel's own tiles, and nothing selects over the slab
+        slab_cells = (self.WIDE["max_batch"], self.WIDE["width"])
+        tile_gathers = 0
+        for eqn, path in _eqns(traced.jaxpr.jaxpr, ()):
+            if "pallas_call" in path:
+                continue
+            out = tuple(eqn.outvars[0].aval.shape) if eqn.outvars else ()
+            if eqn.primitive.name == "select_n":     # [B, C, H, D]
+                assert len(out) < 4 or out[:2] != slab_cells, out
+            if eqn.primitive.name == "gather" and "while" in path:
+                tile_gathers += len(out) == 4 and out[0] == pa.ROW_TILE
+        assert tile_gathers == len(cb.caches)
 
     @pytest.mark.parametrize("width", [1, 8, 16])
     def test_one_tile_step_is_straight_line(self, width):
@@ -591,7 +741,9 @@ class TestStepNeverCopiesTheCache:
         in it: no packing, no loop, one scatter a layer."""
         cb, traced = self._traced(width, max_batch=8)
         assert 8 * width <= pa.ROW_TILE
-        names = [e.primitive.name for e in _eqns(traced.jaxpr.jaxpr)]
+        names = [e.primitive.name
+                 for e, path in _eqns(traced.jaxpr.jaxpr, ())
+                 if "pallas_call" not in path]
         assert "while" not in names
         assert names.count("scatter") == len(cb.caches)
         assert not any(e.params.get("name") == "packed_paged_layer"

@@ -67,16 +67,17 @@ def _engine(tp=1):
     return _ENGINES[tp]
 
 
-def _step_inputs(q_lens, lens, seed):
-    """One step's arguments as the scheduler would build them, over a
-    cache full of noise (so that an unwritten row is told from a
-    written one) and tables that scatter each slot's blocks."""
+def _step_inputs(q_lens, lens, seed, c=C):
+    """One step's arguments as the scheduler would build them for a
+    [B, c] slab, over a cache full of noise (so that an unwritten row is
+    told from a written one) and tables that scatter each slot's
+    blocks."""
     rng = np.random.default_rng(seed)
     q_lens = np.asarray(q_lens, np.int32)
     lens = np.asarray(lens, np.int32)
     tables = (1 + rng.permutation(NB - 1)[:B * MAX_NB]
               ).reshape(B, MAX_NB).astype(np.int32)
-    toks = rng.integers(1, V, (B, C)).astype(np.int32)
+    toks = rng.integers(1, V, (B, c)).astype(np.int32)
     sel = np.maximum(q_lens - 1, 0)[:, None].astype(np.int32)
     dc = pa.paged_head_dim(D)
     caches = [rng.standard_normal((2, G, NB, BS, dc)).astype(np.float32)
@@ -103,6 +104,7 @@ def _padded_reference(w, caches, toks, q_lens, sel, tables, lens):
     """The step over the whole padded [B, C] slab: every column of every
     slot projected and fed forward, the dead ones masked where they could
     be seen (the cache, the keys a query may read)."""
+    C = toks.shape[1]           # the slab's width, the module's or a case's
     pos = lens[:, None] + np.arange(C)[None, :]                 # [B, C]
     live = np.arange(C)[None, :] < q_lens[:, None]
     cos, sin = (jnp.asarray(w["rotary_embs"][i, 0, 0])[
@@ -191,12 +193,32 @@ def _through_the_op(w, caches, toks, q_lens, sel, tables, lens, work,
     return logits, logits.argmax(-1), [c.data for c in cts]
 
 
+# A 16-wide slab is 128 rows, one row tile: the step is straight-line
+# code, and what it computes live rows only in is the ragged kernel, whose
+# packed query tile (4 slots x 16 x 2 = 128 rows) is two sub-tiles.
+NARROW = 16
+assert B * NARROW <= pa.ROW_TILE
+assert pa.query_subtile(pa.default_pack(B, H // G), NARROW, H // G)[1] \
+    > pa.SUB_ROWS
+CASES.update({
+    "narrow_chunk_among_decoders": ([1, 1, NARROW, 1, 0, 5, 1, 1],
+                                    [9, 30, 7, 17, 0, 40, 8, 25]),
+    "narrow_spans_and_padding": ([4, 0, 0, 11, 4, 0, 1, 0],
+                                 [20, 0, 0, 3, 33, 0, 64, 0]),
+})
+WIDTH = {"narrow_chunk_among_decoders": NARROW,
+         "narrow_spans_and_padding": NARROW}
+
+
 @pytest.mark.parametrize("case,entry", [(c, "engine") for c in CASES] + [
     ("two_chunks", "tp2"), ("two_chunks", "op"),
-    ("position_at_capacity", "op")])
+    ("position_at_capacity", "op"), ("parked_slot_between_live", "tp2"),
+    ("narrow_chunk_among_decoders", "tp2"),
+    ("narrow_spans_and_padding", "tp2")])
 def test_wide_step_matches_the_padded_computation(case, entry):
     q_lens, lens = CASES[case]
-    args = _step_inputs(q_lens, lens, seed=sorted(CASES).index(case))
+    args = _step_inputs(q_lens, lens, seed=sorted(CASES).index(case),
+                        c=WIDTH.get(case, C))
     caches, toks, q_lens, sel, tables, lens, work, pack = args
     w = _weights()
     want_logits, want_caches, written = _padded_reference(

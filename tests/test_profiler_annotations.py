@@ -214,15 +214,33 @@ def _value(snap, family, child):
     return c["value"] if c else 0.0
 
 
-@pytest.mark.parametrize("prompt_len,chunk,chunk_steps,live,capacity", [
-    (8, 8, 1, 8, 4 * 8),            # the whole prompt in one 8-wide slab
-    (12, 8, 2, 12, 4 * 8 + 4 * 4),  # 8 then 4: slabs 8 and 4 wide
-    (3, 8, 1, 3, 4 * 4),            # 3 tokens in a 4-wide slab
-])
+# The ragged kernel's query rows on those chunk steps (G = 2 rows a query
+# position, 8-token blocks, 4 slots packed into one tile): per work entry
+# the live rows are the span's positions that see the entry's block, the
+# visited ones the 64-row sub-tiles (or the whole tile, where it is no
+# taller) that hold them.
+@pytest.mark.parametrize(
+    "prompt_len,chunk,chunk_steps,live,capacity,attn_live,attn_visited", [
+        # the whole prompt in one 8-wide slab: one block, a 64-row tile
+        (8, 8, 1, 8, 4 * 8, 8 * 2, 64),
+        # 8 then 4: slabs 8 and 4 wide; the second span sees two blocks
+        # through a 32-row tile
+        (12, 8, 2, 12, 4 * 8 + 4 * 4, 8 * 2 + 2 * 4 * 2, 64 + 2 * 32),
+        # 3 tokens in a 4-wide slab
+        (3, 8, 1, 3, 4 * 4, 3 * 2, 32),
+        # 32 then 8: the 32-wide slab's tile is 256 rows, of which each
+        # of the span's four blocks visits the slot's one sub-tile, and
+        # block k is seen by the 32 - 8k positions at or after it; then
+        # five blocks through a 64-row tile
+        (40, 32, 2, 40, 4 * 32 + 4 * 8,
+         (32 + 24 + 16 + 8) * 2 + 5 * 8 * 2, 4 * 64 + 5 * 64),
+    ])
 def test_step_kinds_and_slab_fill_match_a_hand_count(
-        eng, prompt_len, chunk, chunk_steps, live, capacity):
+        eng, prompt_len, chunk, chunk_steps, live, capacity, attn_live,
+        attn_visited):
     """`chunk_steps` chunk steps, the last of which emits the first
-    token, then two decode steps for tokens two and three."""
+    token, then two decode steps for tokens two and three (which leave
+    the chunk steps' counters as they are)."""
     cb = _cb(eng, prefill_chunk=chunk)
     cb.submit(GenerationRequest(
         np.arange(1, prompt_len + 1, dtype=np.int32), 3))
@@ -249,6 +267,10 @@ def test_step_kinds_and_slab_fill_match_a_hand_count(
     for kind, want in (("live", live), ("capacity", capacity)):
         got = (_value(snap1, "serve_slab_tokens_total", kind)
                - _value(snap0, "serve_slab_tokens_total", kind))
+        assert got == want, kind
+    for kind, want in (("live", attn_live), ("visited", attn_visited)):
+        got = (_value(snap1, "serve_attn_rows_total", kind)
+               - _value(snap0, "serve_attn_rows_total", kind))
         assert got == want, kind
 
 

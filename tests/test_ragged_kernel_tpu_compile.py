@@ -1,0 +1,78 @@
+"""The ragged kernel, compiled for a v5e that is described and not
+attached (the TPU's compiler is installed here): Mosaic refuses what the
+CPU interpreter lets through — a slice off the sublane tiling, a block
+shape it cannot lay out, more VMEM than a kernel may use — and it
+refuses in seconds, without the chip. The shapes are the chat cell's
+(Mistral-7B: 8 kv heads x 4 x 128, 128-token blocks, 16 slots, pack 2)
+over its chunk widths, and two whose packed tile has to be padded to
+whole sub-tiles. Nothing runs: a compile that passes says nothing about
+results or times.
+
+The topology is described inside a fixture, never at import (one
+process at a time may load the TPU's library; the other workers only
+collect this file)."""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import paged_attention as pa
+
+
+@pytest.fixture(autouse=True)
+def _mosaic_not_the_interpreter():
+    # `init_platform()` leaves the kernels in interpret mode for the
+    # rest of a CPU process; here the kernel itself is what is compiled
+    old = fa._INTERPRET
+    fa._INTERPRET = False
+    yield
+    fa._INTERPRET = old
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1))
+    except Exception as e:     # no TPU compiler here, or another holds it
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(one_chip, *, c, t, kvh=8, g=4, d=128, bs=128, nb=321, b=16,
+             pack=2, dtype=jnp.bfloat16, depth=2):
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def call(q, kv, tables, lens, q_lens, *work):
+        return pa.ragged_paged_attention(
+            q, kv, tables, lens, work=(work, None, t, pack),
+            q_lens=q_lens, buffer_depth=depth)
+
+    args = [sds((b, c, kvh * g, d), dtype),
+            sds((2, kvh, nb, bs, pa.paged_head_dim(d)), dtype),
+            sds((b, 32), jnp.int32), sds((b,), jnp.int32),
+            sds((b,), jnp.int32)] + [sds((t,), jnp.int32)] * 9
+    compiled = jax.jit(call).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("c", [1, 8, 16, 128])
+def test_chat_cell_widths_compile_for_a_v5e(one_chip, c):
+    # c = 1 and 8: the tile is one static sub-tile (8 and 64 rows);
+    # c = 16: two sub-tiles; c = 128: sixteen, the widest bucket
+    _compile(one_chip, c=c, t=32)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(c=5, t=16, g=8, pack=3),                       # 120 rows -> 128
+    dict(c=7, t=16, g=1, pack=11, b=11, dtype=jnp.float32),  # 77 -> 128
+    dict(c=128, t=64, depth=1),
+    dict(c=64, t=64, kvh=2, g=2, d=64, bs=16, nb=257, b=8, pack=4),
+], ids=["padded_bf16", "padded_f32", "depth1", "smoke_tp4_shard"])
+def test_other_tilings_compile_for_a_v5e(one_chip, kw):
+    _compile(one_chip, **kw)
