@@ -130,6 +130,91 @@ def propose_draft_tokens(tokens, max_k, ngram=2):
     return []
 
 
+class _StepInputs:
+    """One set of the numpy arrays a dispatch hands to jit. jit does NOT
+    snapshot a numpy argument: the CPU client aliases a 64-byte-aligned
+    buffer and an accelerator copies it when it gets to it, so a set is
+    written only while no step in flight was given it. The engine keeps
+    two and alternates: with a look-ahead of one step, the set that
+    step n+2 is built in is the one step n, whose tokens have been read
+    by then, was given. Slab, sample-gather and work-list buffers are
+    keyed by the bucketed widths that key the compiles, so steady state
+    allocates nothing."""
+
+    def __init__(self, batch, max_blocks):
+        self.batch = batch
+        self.slabs = {}         # c -> [B, c] int32
+        self.sels = {}          # w_sel -> [B, w_sel] int32
+        self.works = {}         # t_total -> nine [t_total] int32
+        self.q = np.zeros(batch, np.int32)
+        self.fed = np.zeros(batch, bool)
+        self.tables = np.zeros((batch, max_blocks), np.int32)
+        self.lens = np.zeros(batch, np.int32)
+
+    def zeroed(self, pool, width):
+        buf = pool.get(width)
+        if buf is None:
+            buf = pool[width] = np.zeros((self.batch, width), np.int32)
+        else:
+            buf.fill(0)
+        return buf
+
+    def work(self, arrs, t_total):
+        """Private copies of the work builder's arrays, which the next
+        build rewrites in place whatever is in flight."""
+        if not t_total:
+            return arrs         # the builder's constant empty list
+        mine = self.works.get(t_total)
+        if mine is None:
+            mine = self.works[t_total] = tuple(
+                np.zeros(t_total, np.int32) for _ in arrs)
+        for dst, src in zip(mine, arrs):
+            np.copyto(dst, src)
+        return mine
+
+
+class _Flight:
+    """One dispatched step until its tokens are committed: the device
+    array of its samples, the numpy arrays it was given, and per slot
+    what the commit needs of the schedule it was built from."""
+
+    __slots__ = ("step", "toks", "snapshot", "entries", "c", "t_total",
+                 "pack", "work", "bucket", "kind", "live", "comm_task",
+                 "t_begin", "pc_begin", "pc_sched", "pc_step", "pc_disp")
+
+
+class _HostPhases:
+    """The five host phases of one `step()` call on `PhaseMarks`' stamps:
+    `enter` closes the open phase where the next one opens and sums the
+    seconds per phase (which phases a call goes through, and in what
+    order, follows what it dispatches and what it reads)."""
+
+    NAMES = ("schedule", "build", "dispatch", "fetch", "commit")
+
+    def __init__(self, marks):
+        self._marks = marks
+        self.seconds = dict.fromkeys(self.NAMES, 0.0)
+        self._open = None
+        self._since = 0.0
+
+    def enter(self, phase, suffix=""):
+        t = self._marks.mark("serve." + phase + suffix)
+        self._close(t)
+        self._open = phase
+        return t
+
+    def _close(self, t):
+        if self._open is not None:
+            self.seconds[self._open] += t - self._since
+        self._since = t
+
+    def close(self):
+        """Sum the open phase up to now; it stays open (the annotation
+        runs on to `step()`'s return)."""
+        self._close(time.perf_counter())
+        return self.seconds
+
+
 class BlockAllocator:
     """Refcounted free-list + content-addressed prefix index over the
     paged KV cache's physical blocks.
@@ -436,6 +521,10 @@ class GenerationRequest:
         self.blocks = []        # physical cache blocks, in table order
         self.progress = 0       # prompt tokens consumed so far
         self.generated = []
+        # the steps dispatched and not committed that sample a token
+        # of this request (1 at any schedule): `generated` holds
+        # values, the scheduler counts `len(generated) + _pending`
+        self._pending = 0
         # prefill source/target: for a fresh request the prompt itself;
         # a preempted-and-resumed request re-prefills prompt + every
         # token it already emitted (the KV it lost), then decodes on
@@ -762,12 +851,24 @@ class ContinuousBatchingEngine:
             os.environ.get("PADDLE_TPU_HOST_DEBUG_CHECK"))
         self._work_builder = RaggedWorkBuilder(
             self.max_batch, self.max_blocks, self.block_size, self._pack)
-        # persistent step-input buffers, keyed by the same bucketed
-        # widths that key the compiles — steady state allocates nothing
-        self._slab_bufs = {}        # c -> [B, c] int32
-        self._sel_bufs = {}         # w_sel -> [B, w_sel] int32
-        self._q_arr_buf = np.zeros(self.max_batch, np.int32)
+        # look-ahead of one step: step n+1 is built from counts and
+        # dispatched before step n's tokens are read. Two sets of step
+        # inputs alternate, so that none is written while a step in
+        # flight was given it; `_flight` is the step dispatched and not
+        # committed, `_sampled` the device array of the last dispatched
+        # step's samples (the next slab's column 0 is fed from it), and
+        # `_finishing` the requests whose last token is in that step:
+        # their slots and blocks went back by count, their records are
+        # made when the token lands.
+        self._inputs = (_StepInputs(self.max_batch, self.max_blocks),
+                        _StepInputs(self.max_batch, self.max_blocks))
         self._attn_buf = np.zeros(self.max_batch, np.int32)
+        self._flight = None
+        self._sampled = engine.new_sampled(self.max_batch)
+        self._finishing = []
+        self._landing = 0           # the step being committed
+        self._tokens_at = 0.0       # perf_counter of the last fetch
+        self._tokens_at_mono = 0.0  # and on the monotonic clock
         self._last_host_phases = {}
 
     def host_stats(self):
@@ -868,7 +969,21 @@ class ContinuousBatchingEngine:
 
     @property
     def num_active(self):
-        return sum(r is not None for r in self.slots)
+        """Requests in flight: those in a slot and those whose last
+        token is dispatched and not committed (`_finishing`), so that
+        `run()` and the stepper's park test drain a step in flight by
+        themselves."""
+        return sum(r is not None for r in self.slots) \
+            + len(self._finishing)
+
+    def _depth(self):
+        """How many steps may be dispatched ahead of the last one read:
+        1 when the next step can be built from counts alone, 0 when its
+        input needs values. A speculative engine's drafts are made from
+        token values and a rejected span rewinds `lens`, so it reads
+        every step before it builds the next. Read off the engine's
+        state each step; nothing selects it."""
+        return 0 if self.spec_k else 1
 
     @property
     def tp(self):
@@ -964,6 +1079,17 @@ class ContinuousBatchingEngine:
         table row, and record the structured RequestResult. Every
         terminal path funnels through here so the allocator bookkeeping
         can't diverge between finish/cancel/deadline/failure."""
+        req = self._vacate_slot(i)
+        # a token of this request that is still in flight is discarded:
+        # the commit of that step finds the slot without it
+        req._pending = 0
+        self._record_terminal(req, status, reason)
+
+    def _vacate_slot(self, i):
+        """Hand slot i's blocks back and park its table row. A block
+        freed while a step in flight still reads or writes it may be
+        granted to the very next step: the device runs steps in order,
+        so the new holder's writes follow the old one's."""
         req = self.slots[i]
         self.allocator.free(req.blocks)
         req.blocks = []
@@ -971,6 +1097,9 @@ class ContinuousBatchingEngine:
         self.tables[i] = 0
         self.lens[i] = 0
         self._dirty_slot(i)
+        return req
+
+    def _record_terminal(self, req, status, reason=None):
         req.status = status
         req.status_reason = reason
         res = RequestResult(
@@ -1032,6 +1161,15 @@ class ContinuousBatchingEngine:
                          generated=len(req.generated),
                          deadline_steps=req.deadline_steps)
                 self._finish_slot(i, "deadline_exceeded", "in_flight")
+                retired += 1
+            elif req._pending and len(req.generated) + req._pending \
+                    >= req.max_new_tokens:
+                # its last token is dispatched and not read: it has no
+                # next step, so slot and blocks go back now, by count
+                # (every full block it wrote is registered: the tokens
+                # in the cache are all committed), and the record is
+                # made when the token lands
+                self._finishing.append(self._vacate_slot(i))
                 retired += 1
         if retired:
             self._update_pool_gauges()
@@ -1165,17 +1303,12 @@ class ContinuousBatchingEngine:
         for it. The request keeps every token it generated; resumption
         re-prefills prompt + generated and decodes on, token-exact
         under greedy verification by construction."""
-        req = self.slots[i]
-        freed = len(req.blocks)
-        self.allocator.free(req.blocks)
-        req.blocks = []
-        self.slots[i] = None
-        self.tables[i] = 0
-        self.lens[i] = 0
-        self._dirty_slot(i)
+        freed = len(self.slots[i].blocks)
+        req = self._vacate_slot(i)
         req.status = "preempted"
         req.preemptions += 1
         req.progress = 0
+        req._pending = 0        # a token in flight is discarded
         req._cow_reserve = 0
         self.queue.append(req)
         if q_lens is not None:
@@ -1420,21 +1553,28 @@ class ContinuousBatchingEngine:
         return new
 
     def _register_full_blocks(self, i):
-        """Publish slot i's newly FULL blocks into the prefix index.
-        Runs after the step's accept/rewind settled lens, so every
-        registered block is immutable: its tokens are committed prompt
-        or committed generations (a rejected speculative span can never
-        have been registered). Generated tokens register too — that is
-        the conversation-resume path: a follow-up request whose prompt
+        """Publish slot i's newly FULL blocks into the prefix index:
+        those under `lens` whose tokens are all on the host, the prompt
+        and the committed generations (a rejected speculative span
+        rewinds to past the last committed token, so nothing registered
+        is ever rewound). Runs when a step is dispatched, which is when
+        `lens` advances: a prompt block is mappable by the very next
+        schedule, as it was when steps were read before the next was
+        built, and whoever maps it is dispatched after the step that
+        writes it. Runs again at the step's commit for the blocks its
+        token completed. Generated tokens register too — that is the
+        conversation-resume path: a follow-up request whose prompt
         embeds this reply maps these blocks straight from the index."""
         req = self.slots[i]
         bs = self.block_size
-        full = int(self.lens[i]) // bs
+        # `lens` may run ahead of the values by the token in flight, and
+        # never covers the newest one (not fed yet)
+        full = min(int(self.lens[i]),
+                   len(req.prompt) + len(req.generated)) // bs
         if full <= req._registered:
             return
         # token at position p is seq[p]: the prompt, then every
-        # generated token except the newest (which has not been fed —
-        # and so not appended — yet); lens never covers it
+        # generated token
         seq = req.prompt + req.generated
         key = req._prefix_key
         for k in range(req._registered, full):
@@ -1609,18 +1749,29 @@ class ContinuousBatchingEngine:
                                    drafts=drafts)
 
     def step(self):
-        """One scheduler tick + one compiled mixed prefill/decode step.
-        Returns the number of requests still in flight (active +
-        queued).
+        """One scheduler tick. Builds and dispatches the next compiled
+        mixed prefill/decode step, then reads and commits the step
+        before it: with a look-ahead of one (`_depth`), step n+1 is
+        built from counts, its decode tokens fed on the device from
+        step n's samples, and dispatched before step n's tokens are
+        read, so that the host's turn runs beside the device's step and
+        not between two of them. A token is committed (`generated`,
+        `on_token`, the latency samples) in the call after the one that
+        dispatched its step; an engine whose next step needs values (a
+        speculative one) commits in the same call, as a call always did.
+        Returns the number of requests still in flight (queued, in a
+        slot, or waiting for a last token that is dispatched and not
+        read), so a caller that steps until it reads 0 has every token.
 
         Its host phases tile the calling thread for the profiler
         (`tracing.PhaseMarks`): `serve.schedule`, `serve.build`,
-        `serve.dispatch <bucket>`, `serve.fetch <bucket>` (the wait for
-        the device) and `serve.commit` with `serve.telemetry` nested in
-        it. Their boundaries are the stamps the `serve_step` span,
+        `serve.dispatch <bucket>` (the step being dispatched),
+        `serve.fetch <bucket>` (the wait for the step whose tokens are
+        read) and `serve.commit` with `serve.telemetry` nested in it.
+        Their boundaries are the stamps the `serve_step` span,
         `_last_host_phases` and `serve_host_phase_seconds` are fed from;
         `serve.commit` alone runs on past the last stamp to the return,
-        so that nothing of a step lies outside them."""
+        so that nothing of a call lies outside them."""
         marks = _tracing.PhaseMarks()
         try:
             return self._step(marks)
@@ -1628,21 +1779,68 @@ class ContinuousBatchingEngine:
             marks.end()
 
     def _step(self, marks):
-        import jax
-
         t_begin = time.monotonic()
-        pc_begin = marks.mark("serve.schedule")
-        tr = _tracing.get_tracer()
+        ph = _HostPhases(marks)
+        pc_begin = ph.enter("schedule")
+        depth = self._depth()
+        # the step dispatched and not read: None at depth 0, where every
+        # call reads its own
+        before, self._flight = self._flight, None
+        plan = self._plan()
+        if plan is None:
+            if before is None:
+                if self.monitor is not None:
+                    self.monitor.tick()  # keep sampling through idle ticks
+                if self.memory_watch is not None:
+                    self.memory_watch.tick()
+                return len(self.queue) + self.num_active
+            self._land(ph, before)
+        else:
+            _metrics.serve_steps_dispatched().labels(
+                mode="drained" if before is None else "ahead").inc()
+            flight = self._dispatch(ph, *plan, feed=bool(depth),
+                                    t_begin=t_begin, pc_begin=pc_begin)
+            if before is not None:
+                self._land(ph, before)
+            if depth:
+                self._flight = flight
+                if before is None:
+                    ph.enter("commit")  # nothing to commit: the close-out
+            else:
+                self._land(ph, flight)
+        # what a call pays for the engine's own instrumentation beside
+        # each step's (ROADMAP D7): the monitor's and the memory watch's
+        # ticks, the call's phases
+        with _tracing.annotation("serve.telemetry"):
+            # host-side cadence hooks: registry sample + burn-rate pass
+            # when the monitor's cadence elapsed, a monotonic compare
+            # otherwise — AFTER the step's own metrics landed, so a
+            # breach evaluation always sees this step's samples
+            if self.monitor is not None:
+                self.monitor.tick()
+            if self.memory_watch is not None:
+                # same cadence contract: HBM/census + hbm_pressure
+                self.memory_watch.tick()
+            self._last_host_phases = phases = dict(ph.close())
+            hp = _metrics.serve_host_phase_seconds()
+            hp.labels(phase="schedule").observe(phases["schedule"])
+            hp.labels(phase="build").observe(phases["build"])
+            hp.labels(phase="dispatch").observe(phases["dispatch"])
+            hp.labels(phase="fetch").observe(phases["fetch"])
+            hp.labels(phase="commit").observe(phases["commit"])
+        return len(self.queue) + self.num_active
+
+    def _plan(self):
+        """The schedule phase: retire, admit, match prefixes, grant this
+        step's tokens and grow the slots' block lists, all from counts
+        (`lens`, `progress`, `len(generated) + _pending`). Returns
+        (active slots, q_lens, drafts), or None when no slot is active."""
         self._retire()
         self._admit()
         active = [i for i, r in enumerate(self.slots) if r is not None]
         self._update_pool_gauges()
         if not active:
-            if self.monitor is not None:
-                self.monitor.tick()     # keep sampling through idle ticks
-            if self.memory_watch is not None:
-                self.memory_watch.tick()
-            return len(self.queue)
+            return None
         if self._prefix_on:
             # admission + wavefront prefix matching: map every full
             # prompt block the index already holds before the scheduler
@@ -1660,43 +1858,28 @@ class ContinuousBatchingEngine:
         # of the step only sees the survivors (their q_lens are zeroed,
         # their table rows parked)
         active = [i for i in active if self.slots[i] is not None]
-        if not active:
-            if self.monitor is not None:
-                self.monitor.tick()
-            if self.memory_watch is not None:
-                self.memory_watch.tick()
-            return len(self.queue) + self.num_active
-        pc_sched = marks.mark("serve.build")
+        return (active, q_lens, drafts) if active else None
+
+    def _dispatch(self, ph, active, q_lens, drafts, feed, t_begin,
+                  pc_begin):
+        """Build one step's inputs in the set no step in flight was
+        given, dispatch it, and advance `lens` and `progress` by what it
+        was granted. Returns its `_Flight`."""
+        import jax
+
+        tr = _tracing.get_tracer()
+        fl = _Flight()
+        fl.step = self._step_count
+        fl.t_begin, fl.pc_begin = t_begin, pc_begin
+        fl.pc_sched = ph.enter("build")
+        ins = self._inputs[self._step_count & 1]
         # token slab [B, C]: C is the widest span this step, bucketed to
         # a power of two (1 for an all-decode step) so slab shapes — and
         # the programs they key — stay off the per-prompt-length
         # treadmill. Idle slots and budget-starved prefill slots have
         # q_len 0: zero slab tokens, zero work entries, output ignored.
-        # Per-width persistent buffers zero-filled in place — a
-        # steady-state step allocates nothing (a fresh width keys a
-        # fresh compile anyway, so buffer creation rides warmup).
         c = int(next_pow2(int(q_lens.max())))
-        slab = self._slab_bufs.get(c)
-        if slab is None:
-            slab = np.zeros((self.max_batch, c), np.int32)
-            self._slab_bufs[c] = slab
-        else:
-            slab.fill(0)
-        prefilling = False      # some slot consumes prompt this step
-        for i in active:
-            req = self.slots[i]
-            n = int(q_lens[i])
-            if req.progress < req._resume_len:
-                prefilling = prefilling or n > 0
-                slab[i, :n] = \
-                    req._prefill_src[req.progress:req.progress + n]
-            elif n:
-                # decode: last real token, then the speculative drafts
-                # (if granted) — the step verifies the whole span
-                slab[i, 0] = req.generated[-1]
-                d = drafts.get(i)
-                if d:
-                    slab[i, 1:1 + len(d)] = d
+        slab = ins.zeroed(ins.slabs, c)
         # sample-position gather [B, W]: the device projects/samples only
         # these slab columns, so lm_head cost is bounded by 1 + spec_k
         # per slot, not the chunk width. Prefill slots read one column
@@ -1704,27 +1887,64 @@ class ContinuousBatchingEngine:
         # padding repeats column 0 (computed, ignored). W is a pure
         # function of c and the engine-static spec_k, so the (t_total,
         # c) bucket pair still keys every compile.
-        w_sel = min(c, 1 + self.spec_k)
-        sel = self._sel_bufs.get(w_sel)
-        if sel is None:
-            sel = np.zeros((self.max_batch, w_sel), np.int32)
-            self._sel_bufs[w_sel] = sel
-        else:
-            sel.fill(0)
+        sel = ins.zeroed(ins.sels, min(c, 1 + self.spec_k))
+        fed = ins.fed
+        fed.fill(False)
+        prefilling = False      # some slot consumes prompt this step
+        # per slot with tokens: (slot, request, width, drafts or None
+        # for a prompt chunk, whether the step samples a token of the
+        # request's, the chunk's (requested, granted, progress after))
+        fl.entries = entries = []
         for i in active:
             req = self.slots[i]
             n = int(q_lens[i])
-            if n == 0:
-                continue
             if req.progress < req._resume_len:
+                rem = req._resume_len - req.progress
+                if n == 0:      # starved prefill slot: stalled this step
+                    if i in self._pending_stalls:
+                        # deferred on purpose: another slot is computing
+                        # this slot's next block THIS step — next step's
+                        # wavefront match maps it for free
+                        tr.event("stall_cache_pending",
+                                 request=req.request_id,
+                                 prompt_remaining=rem)
+                    else:
+                        # budget starvation: the prompt wanted a chunk
+                        # and got zero work-list entries this step
+                        tr.event("stall_budget", request=req.request_id,
+                                 prompt_remaining=rem,
+                                 token_budget=self.token_budget)
+                    continue
+                prefilling = True
+                slab[i, :n] = \
+                    req._prefill_src[req.progress:req.progress + n]
                 sel[i, 0] = n - 1
-            else:
+                # a chunk that ends the prompt: sel column 0 carries its
+                # last valid position, whose sample is the request's
+                # FIRST output token
+                entries.append((i, req, n, None, n == rem,
+                                self._sched_info.get(i, (n, n))
+                                + (req.progress + n,)))
+            elif n:
+                # decode: last real token, then the speculative drafts
+                # (if granted) — the step verifies the whole span. A
+                # token still in flight is column 0 of the last
+                # dispatched step's samples: `_feed_tokens` puts it in
+                # on the device
+                if req._pending:
+                    fed[i] = True
+                else:
+                    slab[i, 0] = req.generated[-1]
+                d = drafts.get(i, ())
+                if d:
+                    slab[i, 1:1 + len(d)] = d
                 sel[i, :n] = np.arange(n)
-        # in-place step inputs: the persistent int32 views mutate
-        # under np.copyto/np.add, and the work list assembles
-        # incrementally — only slots the dirty schedule touched
-        # rebuild their segments (RaggedWorkBuilder)
-        q_arr = self._q_arr_buf
+                entries.append((i, req, n, d, True, None))
+        # the work list assembles incrementally — only slots the dirty
+        # schedule touched rebuild their segments (RaggedWorkBuilder) —
+        # in the builder's own buffers, which the next build rewrites:
+        # the step gets this set's copies, as it does of tables and lens
+        q_arr = ins.q
         q_arr[:] = q_lens
         attn_lens = self._attn_buf
         np.add(self.lens, q_arr, out=attn_lens)
@@ -1732,6 +1952,15 @@ class ContinuousBatchingEngine:
             self.tables, attn_lens, q_arr)
         if self._host_debug:
             self._check_host_state(attn_lens, q_arr, work, t_total, pack)
+        work = ins.work(work, t_total)
+        np.copyto(ins.tables, self.tables)
+        np.copyto(ins.lens, self.lens)
+        fl.snapshot = None
+        if self._host_debug:
+            # what the step was given, to hold the set to at its fetch
+            fl.snapshot = [(a, a.copy()) for a in
+                           (slab, sel, fed, q_arr, ins.tables, ins.lens)
+                           + work]
         # the (padded work-list length, slab width) pair is the ONLY
         # shape the scheduler varies step to step — a pair not seen
         # before keys a fresh compile of the step program
@@ -1751,7 +1980,7 @@ class ContinuousBatchingEngine:
                     "post_warmup_recompile", bucket=f"{t_total}x{c}",
                     step=self._step_count)
         self._key, sub = jax.random.split(self._key)
-        comm_task = None
+        fl.comm_task = None
         if self._comm_tasks is not None:
             # the TP step's per-layer reduces, attributed through the
             # PR-9 collective path: payload bytes are pure aval math
@@ -1761,105 +1990,125 @@ class ContinuousBatchingEngine:
             # dispatch-to-sync span that CONTAINS the reduces, so the
             # (psum, tp) bandwidth gauge is a floor and
             # collective_bytes_total attributes the comms cost exactly
-            comm_task = self._comm_tasks.start_task(
+            fl.comm_task = self._comm_tasks.start_task(
                 "psum", group="tp",
                 nbytes=self.engine.tp_step_comm_bytes(
                     self.max_batch, c, int(q_lens.sum())))
+        fl.c, fl.t_total, fl.pack, fl.work = c, t_total, pack, work
+        fl.live = int(q_lens.sum())
+        # decode against chunk steps, which the tail of the gap between
+        # tokens follows
+        fl.kind = "decode" if c <= 1 + self.spec_k and not prefilling \
+            else "chunk"
         # the bucket rides the two device-facing annotations' names: at
         # most one name per compiled program (64 on the chat cell)
-        bucket = f"w{t_total}c{c}"
-        pc_step = marks.mark("serve.dispatch " + bucket)
-        # tables/lens (and the slab/sel/work buffers) go in as the
-        # persistent scheduler arrays themselves. jit does NOT snapshot
-        # a numpy argument at dispatch: a 64-byte-aligned buffer is
-        # aliased by the CPU client, and an accelerator copies it
-        # asynchronously — so nothing may mutate these arrays until the
-        # token fetch below has waited the step out
-        toks2, self.caches = self.engine._paged_step(
+        fl.bucket = f" w{t_total}c{c}"
+        fl.pc_step = ph.enter("dispatch", fl.bucket)
+        # the programs below read this set's arrays whenever the device
+        # gets to them: nothing writes the set again before the step's
+        # tokens have been fetched, which is why there are two
+        if feed:
+            slab = self.engine._feed_tokens(slab, self._sampled, fed)
+        fl.toks, self.caches = self.engine._paged_step(
             self.engine._w, self.caches, slab, q_arr, sel,
-            self.tables, self.lens, tuple(work),
+            ins.tables, ins.lens, work,
             pack, np.float32(self._temp), np.float32(self._topp), sub)
-        pc_disp = marks.mark("serve.fetch " + bucket)
+        if feed:
+            self._sampled = fl.toks
+        fl.pc_disp = time.perf_counter()
+        self._step_count += 1
+        # by count: the cache holds the step's tokens as far as any later
+        # schedule is concerned, and a token it samples is on its way
+        for i, req, n, d, yields, _ in entries:
+            self.lens[i] += n
+            if d is None:
+                req.progress += n
+            req._pending += yields
+        if self._prefix_on:
+            for e in entries:
+                self._register_full_blocks(e[0])
+        return fl
+
+    def _land(self, ph, fl):
+        """Wait for a dispatched step's tokens and commit them: token
+        values, `on_token`, the latency samples, a rejected draft span's
+        rewind, the blocks its tokens completed, the records of the
+        requests it was the last step of, and the step's own spans and
+        metrics. A slot that lost its request since the dispatch
+        (cancelled, preempted, past its deadline, failed) is skipped:
+        that token is discarded."""
+        tr = _tracing.get_tracer()
+        pc_fetch = ph.enter("fetch", fl.bucket)
+        toks2 = np.asarray(fl.toks)     # [B, W]: a sample per sel column
+        t_done = time.monotonic()
+        pc_done = ph.enter("commit")
+        if fl.snapshot is not None and not all(
+                np.array_equal(a, was) for a, was in fl.snapshot):
+            raise AssertionError(
+                f"an input of step {fl.step} was written while the step "
+                "was in flight: its set was not the free one")
+        finishing, self._finishing = self._finishing, []
+        self._landing = fl.step     # the step label `on_token` carries
+        # the slots whose request is the one the step was built for
+        kept = {i for i, req, *_ in fl.entries
+                if self.slots[i] is req or req in finishing}
+        if fl.comm_task is not None:
+            # end AFTER the host read above synced the program: the
+            # collective span covers real execution, not async enqueue
+            self._comm_tasks.end_task(fl.comm_task)
+            comm_dur = fl.comm_task.elapsed
+            for i, req, *_ in fl.entries:
+                if i in kept:
+                    rid = req.request_id
+                    self._comm_seconds[rid] = self._comm_seconds.get(
+                        rid, 0.0) + comm_dur
         emitted = 0
         rewinds = []    # (slot, new_end, old_end): rejected draft spans
         slot_spans = []  # (slot, request_id, span name, args) this step
-        toks2 = np.asarray(toks2)      # [B, W]: a sample per sel column
-        t_done = time.monotonic()
-        pc_done = marks.mark("serve.commit")
-        if comm_task is not None:
-            # end AFTER the host read above synced the program: the
-            # collective span covers real execution, not async enqueue
-            self._comm_tasks.end_task(comm_task)
-            comm_dur = comm_task.elapsed
-            for i in active:
-                if q_lens[i]:
-                    rid = self.slots[i].request_id
-                    self._comm_seconds[rid] = self._comm_seconds.get(
-                        rid, 0.0) + comm_dur
-        for i in active:
-            req = self.slots[i]
-            n = int(q_lens[i])
-            if n == 0:
-                if req.progress < req._resume_len:
-                    if i in self._pending_stalls:
-                        # deferred on purpose: another slot is computing
-                        # this slot's next block THIS step — next step's
-                        # wavefront match maps it for free
-                        tr.event("stall_cache_pending",
-                                 request=req.request_id,
-                                 prompt_remaining=req._resume_len
-                                 - req.progress)
-                    else:
-                        # budget starvation: the prompt wanted a chunk
-                        # and got zero work-list entries this step
-                        tr.event("stall_budget", request=req.request_id,
-                                 prompt_remaining=req._resume_len
-                                 - req.progress,
-                                 token_budget=self.token_budget)
-                continue        # starved prefill slot: stalled this step
-            if req.progress < req._resume_len:
-                requested, granted = self._sched_info.get(i, (n, n))
+        for i, req, n, d, yields, info in fl.entries:
+            if d is None:
+                # the chunk is in the cache whatever became of the
+                # request since: its span tells that
+                requested, granted, progress = info
                 slot_spans.append((i, req.request_id, "prefill_chunk",
                                    {"width": n, "granted": granted,
                                     "requested": requested,
-                                    "progress": req.progress + n}))
-                self.lens[i] += n
-                req.progress += n
-                if req.progress == req._resume_len:
-                    # the chunk ended the prompt: sel column 0 carried
-                    # its last valid position — that sample is the
-                    # request's FIRST output token
+                                    "progress": progress}))
+            if i not in kept:
+                continue
+            req._pending -= yields
+            if d is None:
+                if yields:
                     self._append_token(req, toks2[i, 0], t_done)
                     emitted += 1
-            else:
-                # decode: greedy-verify the drafted span (sel columns
-                # 0..n-1 are slab positions 0..n-1). Column j's sample
-                # is the model's choice after slab column j, so draft
-                # d[a] (at slab column a+1) is accepted iff it EQUALS
-                # sample a; the sample after the last accepted draft is
-                # emitted too (it was computed against a fully-valid
-                # prefix) — a+1 tokens out of one compiled step.
-                d = drafts.get(i, [])
-                k = len(d)               # n == 1 + k
-                span = toks2[i, :n]
-                a = 0
-                while a < k and d[a] == int(span[a]):
-                    a += 1
-                self._append_span(req, span[:a + 1], t_done)
-                emitted += a + 1
-                slot_spans.append((i, req.request_id, "decode",
-                                   {"emitted": a + 1, "drafted": k,
-                                    "accepted": a}))
-                old_end = int(self.lens[i]) + n
-                new_end = int(self.lens[i]) + a + 1
-                self.lens[i] = new_end
-                if k:
-                    req.spec_drafted += k
-                    req.spec_accepted += a
-                    _metrics.spec_draft_tokens().inc(k)
-                    _metrics.spec_accepted_tokens().inc(a)
-                    _metrics.spec_accept_len().observe(a)
-                if new_end < old_end:
+                continue
+            # decode: greedy-verify the drafted span (sel columns
+            # 0..n-1 are slab positions 0..n-1). Column j's sample
+            # is the model's choice after slab column j, so draft
+            # d[a] (at slab column a+1) is accepted iff it EQUALS
+            # sample a; the sample after the last accepted draft is
+            # emitted too (it was computed against a fully-valid
+            # prefix) — a+1 tokens out of one compiled step.
+            k = len(d)               # n == 1 + k
+            span = toks2[i, :n]
+            a = 0
+            while a < k and d[a] == int(span[a]):
+                a += 1
+            self._append_span(req, span[:a + 1], t_done)
+            emitted += a + 1
+            slot_spans.append((i, req.request_id, "decode",
+                               {"emitted": a + 1, "drafted": k,
+                                "accepted": a}))
+            if k:
+                req.spec_drafted += k
+                req.spec_accepted += a
+                _metrics.spec_draft_tokens().inc(k)
+                _metrics.spec_accepted_tokens().inc(a)
+                _metrics.spec_accept_len().observe(a)
+                if a < k:
+                    # the dispatch advanced lens over the whole span
+                    old_end = int(self.lens[i])
+                    self.lens[i] = new_end = old_end - (k - a)
                     rewinds.append((i, new_end, old_end))
         blocks_freed = {}
         if rewinds:
@@ -1904,15 +2153,24 @@ class ContinuousBatchingEngine:
             for i, _, oe in rewinds:
                 old_l[i] = oe
             self.caches = self.engine._paged_rewind(
-                self.caches, ztab, new_l, old_l, c)
+                self.caches, ztab, new_l, old_l, fl.c)
             for i, ne, _ in rewinds:
                 blocks_freed[i] = self._rewind_blocks(i, ne)
             self._update_pool_gauges()
         if self._prefix_on:
-            # AFTER accept/rewind settled lens: every newly-full block
-            # is immutable now, publish it for other requests to map
-            for i in active:
-                self._register_full_blocks(i)
+            # AFTER accept/rewind settled lens: the blocks this step's
+            # tokens completed are all values now, publish them for
+            # other requests to map
+            for i, req, *_ in fl.entries:
+                if self.slots[i] is req:
+                    self._register_full_blocks(i)
+        for req in finishing:
+            # the step was their last: slot and blocks went back when
+            # the schedule counted that, the record waited for the token
+            self._record_terminal(req, "finished")
+            _metrics.serve_requests_total().inc()
+        if finishing:
+            _metrics.serve_inflight().set(self.num_active)
         # per-request lanes: every slot's work this step as one span
         # over the compiled-step window (the chunk widths, spec
         # accounting, and rewind block frees ride as args) — recorded
@@ -1920,75 +2178,52 @@ class ContinuousBatchingEngine:
         for i, rid, name, args in slot_spans:
             if blocks_freed.get(i):
                 args["blocks_freed"] = blocks_freed[i]
-            tr.record_span(name, pc_step * 1e6,
-                           (pc_done - pc_step) * 1e6, request=rid,
-                           step=self._step_count, **args)
-        # span BEFORE the increment: its step label must match the
-        # step= the flight-recorder triggers above stamped, so a dump's
-        # context cross-references the right serve_step on the timeline
-        dur = t_done - t_begin
-        tr.record_span("serve_step", pc_begin * 1e6,
-                       (pc_done - pc_begin) * 1e6, step=self._step_count,
-                       work=t_total, chunk=c, emitted=emitted,
-                       host_sched_us=int((pc_sched - pc_begin) * 1e6),
-                       host_build_us=int((pc_step - pc_sched) * 1e6),
-                       host_dispatch_us=int((pc_disp - pc_step) * 1e6),
-                       host_fetch_us=int((pc_done - pc_disp) * 1e6))
-        self._step_count += 1
+            tr.record_span(name, fl.pc_step * 1e6,
+                           (pc_done - fl.pc_step) * 1e6, request=rid,
+                           step=fl.step, **args)
+        # the step from its schedule to its tokens on the host; with a
+        # step dispatched ahead of it that spans two calls
+        tr.record_span("serve_step", fl.pc_begin * 1e6,
+                       (pc_done - fl.pc_begin) * 1e6, step=fl.step,
+                       work=fl.t_total, chunk=fl.c, emitted=emitted,
+                       host_sched_us=int((fl.pc_sched - fl.pc_begin) * 1e6),
+                       host_build_us=int((fl.pc_step - fl.pc_sched) * 1e6),
+                       host_dispatch_us=int((fl.pc_disp - fl.pc_step) * 1e6),
+                       host_fetch_us=int((pc_done - pc_fetch) * 1e6))
         self._maybe_shrink_chunk()
-        # what step() pays for its own instrumentation (ROADMAP D7):
-        # histogram observes, gauges, the monitor's and the memory
-        # watch's ticks
+        # what a step pays for its own instrumentation (ROADMAP D7):
+        # histogram observes and counters
         with _tracing.annotation("serve.telemetry"):
-            _metrics.serve_step_seconds().observe(dur)
-            # decode against chunk steps, which the tail of the gap
-            # between tokens follows: dispatch to tokens on the host
-            if c <= 1 + self.spec_k and not prefilling:
-                kind = "decode"
-            else:
-                kind = "chunk"
+            # a step's share of the cadence: from the later of its own
+            # start and the previous step's tokens reaching the host to
+            # its own tokens reaching the host, so that a step queued
+            # behind another is not counted twice as long. A step read
+            # before the next is built starts after the previous one's
+            # tokens, and these are what they were
+            _metrics.serve_step_seconds().observe(
+                t_done - max(fl.t_begin, self._tokens_at_mono))
+            _metrics.serve_step_kind_seconds().labels(
+                kind=fl.kind).observe(
+                    pc_done - max(fl.pc_step, self._tokens_at))
+            self._tokens_at, self._tokens_at_mono = pc_done, t_done
+            if fl.kind == "chunk":
                 # live tokens over the rows the row-wise layers
                 # computed for them: the [max_batch, c] slab, or a wide
                 # slab's live row tiles (host arithmetic, no device read)
-                live = int(q_lens.sum())
                 slab_tokens = _metrics.serve_slab_tokens()
-                slab_tokens.labels(kind="live").inc(live)
+                slab_tokens.labels(kind="live").inc(fl.live)
                 slab_tokens.labels(kind="capacity").inc(
-                    step_rows(self.max_batch, c, live))
+                    step_rows(self.max_batch, fl.c, fl.live))
                 # and of the ragged kernel's query rows: the live ones
                 # of each work entry over the sub-tiles it visited
                 rows_live, rows_visited = attn_rows(
-                    work, pack, c, self._group_q, self.block_size)
+                    fl.work, fl.pack, fl.c, self._group_q,
+                    self.block_size)
                 attn = _metrics.serve_attn_rows()
                 attn.labels(kind="live").inc(rows_live)
                 attn.labels(kind="visited").inc(rows_visited)
-            _metrics.serve_step_kind_seconds().labels(kind=kind).observe(
-                pc_done - pc_step)
             if emitted:
                 _metrics.serve_tokens_total().inc(emitted)
-            # host-side cadence hooks: registry sample + burn-rate pass
-            # when the monitor's cadence elapsed, a monotonic compare
-            # otherwise — AFTER the step's own metrics landed, so a
-            # breach evaluation always sees this step's samples
-            if self.monitor is not None:
-                self.monitor.tick()
-            if self.memory_watch is not None:
-                # same cadence contract: HBM/census + hbm_pressure
-                self.memory_watch.tick()
-            pc_end = time.perf_counter()
-            phases = {"schedule": pc_sched - pc_begin,
-                      "build": pc_step - pc_sched,
-                      "dispatch": pc_disp - pc_step,
-                      "fetch": pc_done - pc_disp,
-                      "commit": pc_end - pc_done}
-            self._last_host_phases = phases
-            hp = _metrics.serve_host_phase_seconds()
-            hp.labels(phase="schedule").observe(phases["schedule"])
-            hp.labels(phase="build").observe(phases["build"])
-            hp.labels(phase="dispatch").observe(phases["dispatch"])
-            hp.labels(phase="fetch").observe(phases["fetch"])
-            hp.labels(phase="commit").observe(phases["commit"])
-        return len(self.queue) + self.num_active
 
     def _rewind_blocks(self, i, new_end):
         """Host half of the speculative rewind: shrink slot i's block
@@ -2059,7 +2294,7 @@ class ContinuousBatchingEngine:
             _metrics.serve_tpot().observe(now - req._last_token_time)
         req._last_token_time = now
         if self.on_token is not None:
-            self.on_token(req.request_id, [int(tok)], self._step_count)
+            self.on_token(req.request_id, [int(tok)], self._landing)
 
     def _append_span(self, req, toks, now):
         """Record a verified decode span (the mandatory token + accepted
@@ -2086,7 +2321,7 @@ class ContinuousBatchingEngine:
         req._last_token_time = now
         if self.on_token is not None:
             self.on_token(req.request_id, [int(t) for t in toks],
-                          self._step_count)
+                          self._landing)
 
     def declare_warm(self):
         """Mark the compile-bucket warmup phase over: from here on, any

@@ -811,6 +811,16 @@ class FusedMultiTransformerEngine:
                 logits = picked @ w["lm_head"]               # [B, W, V]
             return logits, [c.data for c in cts]
 
+        def feed_tokens(slab, prev, fed):
+            """The token slab of a step dispatched before the previous
+            step's samples were read: column 0 of every slot `fed` marks
+            is that slot's previous sample, `prev[:, 0]`, which never
+            left the device; everything else is the slab the host built.
+            One small program a slab shape, dispatched ahead of
+            `paged_step`, whose own programs it leaves as they were."""
+            return slab.at[:, 0].set(
+                jnp.where(fed, prev[:, 0], slab[:, 0]))
+
         def paged_copy(caches, src_block, dst_block):
             """Duplicate one physical cache block across every layer in
             ONE jitted program — the serving engine's copy-on-write
@@ -916,6 +926,27 @@ class FusedMultiTransformerEngine:
         self._paged_rewind = _dispatch_span(
             "paged_rewind", jit_paged_rewind, static_argnums=(4,))
         self._paged_copy = _dispatch_span("paged_copy", jit_paged_copy)
+        # under tp the fed slab is replicated over the mesh, where the
+        # step's samples it reads already are
+        self._feed_tokens = jax.jit(
+            feed_tokens, out_shardings=self._replicated())
+
+    def _replicated(self):
+        """The sharding of a host-built step input: None on one device,
+        replicated over the mesh under tp."""
+        if self.tp == 1:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+        return NamedSharding(self._mesh, PartitionSpec())
+
+    def new_sampled(self, batch):
+        """A step's samples before any step ran: zeros [batch, 1] where
+        `_paged_step` leaves its own, for `_feed_tokens` to read when
+        no slot is fed."""
+        import jax
+        import jax.numpy as jnp
+        z = jnp.zeros((batch, 1), jnp.int32)
+        return z if self.tp == 1 else jax.device_put(z, self._replicated())
 
     def _build_quant_mm(self, weights, dtype):
         """Repack the projection weights into the Pallas kernel's int4

@@ -16,7 +16,8 @@ from .metrics import DEFAULT_LATENCY_BUCKETS, get_registry
 
 __all__ = [
     "watch_ops", "serve_ttft", "serve_tpot", "serve_queue_wait",
-    "serve_step_seconds", "dispatch_seconds", "serve_tokens_total",
+    "serve_step_seconds", "serve_steps_dispatched", "dispatch_seconds",
+    "serve_tokens_total",
     "serve_requests_total",
     "serve_inflight", "serve_queue_depth",
     "kv_blocks_free", "kv_blocks_used", "kv_blocks_high_water",
@@ -84,11 +85,24 @@ def serve_host_phase_seconds():
 def serve_step_kind_seconds():
     return get_registry().histogram(
         "serve_step_kind_seconds",
-        help="dispatch of the compiled step to its tokens on the host "
-             "(the step as the synchronous scheduler waits for it), by "
-             "kind: decode (slab no wider than 1 + spec_k and no slot "
-             "prefilling) vs chunk (everything else)",
+        help="a compiled step's share of the cadence: from the later "
+             "of its own dispatch and the previous step's tokens reaching "
+             "the host to its own tokens reaching the host (dispatch to "
+             "tokens when steps are read one by one), by kind: decode "
+             "(slab no wider than 1 + spec_k and no slot prefilling) vs "
+             "chunk (everything else)",
         labels=("kind",))      # bounded: decode | chunk
+
+
+def serve_steps_dispatched():
+    return get_registry().counter(
+        "serve_steps_dispatched_total",
+        help="compiled steps dispatched, by what was in flight then: "
+             "ahead (the step before it was dispatched and not read: the "
+             "look-ahead engaged) vs drained (every earlier step's tokens "
+             "were on the host: the first step after an empty tick, and "
+             "every step of an engine whose next input needs values)",
+        labels=("mode",))      # bounded: ahead | drained
 
 
 def serve_slab_tokens():

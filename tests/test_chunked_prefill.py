@@ -312,12 +312,18 @@ class TestTokenBudgetScheduler:
                                           prefill_chunk=chunk)
             req = GenerationRequest(p, 2)
             cb.submit(req)
-            steps = 0
-            while not req.generated:
+            # the compiled step that sampled the first token, by the
+            # label `on_token` carries (steps count from 0 as they are
+            # dispatched); the token itself is committed one `step()`
+            # call later, the engine looks one step ahead
+            first, calls = [], 0
+            cb.on_token = lambda rid, toks, step: first.append(step)
+            while not first:
                 cb.step()
-                steps += 1
-                assert steps < 64
-            return steps
+                calls += 1
+                assert calls < 64
+            assert calls == first[0] + 2 and len(req.generated) == 1
+            return first[0] + 1
 
         assert steps_to_first(1) == 16
         assert steps_to_first(8) == 2

@@ -154,8 +154,14 @@ def test_the_seam_never_imports_jax_for_a_process_without_it(monkeypatch):
 
 # -- the stepper's states, in order and nesting ----------------------------------
 
-STEP = ["serve.schedule", "serve.build", "serve.dispatch", "serve.fetch",
-        "serve.commit"]
+# One request of two tokens through an engine that looks one step ahead:
+# the first turn dispatches the prompt's step and has nothing to read yet,
+# the second dispatches the decode step and then reads the first, the
+# third has nothing left to dispatch and reads the second.
+TURNS = [["serve.schedule", "serve.build", "serve.dispatch", "serve.commit"],
+         ["serve.schedule", "serve.build", "serve.dispatch", "serve.fetch",
+          "serve.commit"],
+         ["serve.schedule", "serve.fetch", "serve.commit"]]
 
 
 def test_one_request_through_a_stepper_emits_the_states_in_order(fake, eng):
@@ -180,18 +186,35 @@ def test_one_request_through_a_stepper_emits_the_states_in_order(fake, eng):
     assert heads[0] == heads[-1] == "stepper.idle"
     assert heads[1] == "stepper.commands"
     serve = [h for h in heads if h.startswith("serve.")]
-    full = steps - 1    # the last turn only retires: it ends in its schedule
-    assert full == 2 and len(serve) == 5 * full + 1
-    for k in range(full):
-        assert serve[5 * k:5 * k + 5] == STEP
-    assert serve[-1] == "serve.schedule"
-    assert order.count("serve.telemetry") == full
-    # dispatch and fetch carry the step's bucket, the same on both
-    buckets = [n.split(" ")[1] for n in order if " " in n]
-    assert buckets[0::2] == buckets[1::2]
-    assert all(b[0] == "w" and "c" in b for b in buckets)
+    assert steps == len(TURNS)
+    assert serve == [name for turn in TURNS for name in turn]
+    # one for each step read, one for each turn's close-out
+    assert order.count("serve.telemetry") == 2 + len(TURNS)
+    # a step is waited for under the bucket it was dispatched under, and
+    # in the order of the dispatches
+    dispatched = [n.split(" ")[1] for n in order
+                  if n.startswith("serve.dispatch ")]
+    fetched = [n.split(" ")[1] for n in order
+               if n.startswith("serve.fetch ")]
+    assert dispatched == fetched and len(fetched) == 2
+    assert all(b[0] == "w" and "c" in b for b in dispatched)
     # the first step prefills 8 tokens, the second decodes one
-    assert buckets[0].endswith("c8") and buckets[2].endswith("c1")
+    assert dispatched[0].endswith("c8") and dispatched[1].endswith("c1")
+
+
+def test_an_engine_that_reads_each_step_keeps_the_five_in_a_row(fake, eng):
+    """A speculative engine's next step needs the last one's values: every
+    turn dispatches and reads its own step, as every turn once did."""
+    cb = _cb(eng, spec_k=2)
+    cb.submit(GenerationRequest(np.arange(1, 9, dtype=np.int32), 2))
+    while cb.step():
+        pass
+    heads = [name.split(" ")[0] for _, kind, name in fake.log
+             if kind == "enter" and name != "serve.telemetry"]
+    turn = ["serve.schedule", "serve.build", "serve.dispatch", "serve.fetch",
+            "serve.commit"]
+    assert heads[:-1] == turn * ((len(heads) - 1) // 5) and len(heads) > 5
+    assert heads[-1] == "serve.schedule"    # the idle tick that retired it
 
 
 def test_an_idle_tick_closes_what_it_opened(fake, eng):
@@ -242,15 +265,17 @@ def test_step_kinds_and_slab_fill_match_a_hand_count(
     token, then two decode steps for tokens two and three (which leave
     the chunk steps' counters as they are)."""
     cb = _cb(eng, prefill_chunk=chunk)
+    rid = f"kinds-{prompt_len}-{chunk}"
     cb.submit(GenerationRequest(
-        np.arange(1, prompt_len + 1, dtype=np.int32), 3))
+        np.arange(1, prompt_len + 1, dtype=np.int32), 3, request_id=rid))
     snap0 = obs.get_registry().snapshot()
     steps = 0
     while cb.step():
         steps += 1
         assert steps < 20
     snap1 = obs.get_registry().snapshot()
-    assert steps == chunk_steps + 2     # and one idle tick that retired it
+    # and one turn that had nothing to dispatch and read the last step
+    assert steps == chunk_steps + 2
 
     def gained(kind):
         n0, s0 = _hist(snap0, "serve_step_kind_seconds", kind)
@@ -259,11 +284,24 @@ def test_step_kinds_and_slab_fill_match_a_hand_count(
     (n_chunk, s_chunk), (n_dec, s_dec) = gained("chunk"), gained("decode")
     assert (n_chunk, n_dec) == (chunk_steps, 2)
     assert s_chunk > 0 and s_dec > 0
-    # a step's dispatch-to-tokens is its dispatch and fetch phases
-    phases = {p: _hist(snap1, "serve_host_phase_seconds", p)[1]
-              - _hist(snap0, "serve_host_phase_seconds", p)[1]
-              for p in ("dispatch", "fetch")}
-    assert s_chunk + s_dec == pytest.approx(sum(phases.values()), rel=1e-6)
+    # a step's share of the cadence runs from the later of its own
+    # dispatch and the tokens of the step before it to its own tokens:
+    # the request's spans carry both stamps. Each step after the first
+    # was dispatched before the one before it was read, so the shares
+    # tile the time from the first dispatch to the last tokens
+    mine = sorted((s for s in tracing.get_tracer().spans()
+                   if s["request"] == rid
+                   and s["name"] in ("prefill_chunk", "decode")),
+                  key=lambda s: s["ts_us"])
+    assert len(mine) == chunk_steps + 2
+    done = [s["ts_us"] + s["dur_us"] for s in mine]
+    assert all(s["ts_us"] < d for s, d in zip(mine[1:], done))
+    assert s_chunk + s_dec == pytest.approx(
+        (done[-1] - mine[0]["ts_us"]) / 1e6, rel=1e-4)
+    # the host's five phases are the turns' own: every one was entered
+    phases = {p: _hist(snap1, "serve_host_phase_seconds", p)
+              for p in ("schedule", "build", "dispatch", "fetch", "commit")}
+    assert all(n > 0 and sec > 0 for n, sec in phases.values())
     for kind, want in (("live", live), ("capacity", capacity)):
         got = (_value(snap1, "serve_slab_tokens_total", kind)
                - _value(snap0, "serve_slab_tokens_total", kind))
@@ -272,6 +310,41 @@ def test_step_kinds_and_slab_fill_match_a_hand_count(
         got = (_value(snap1, "serve_attn_rows_total", kind)
                - _value(snap0, "serve_attn_rows_total", kind))
         assert got == want, kind
+
+
+def _dispatched(snap):
+    return tuple(_value(snap, "serve_steps_dispatched_total", mode)
+                 for mode in ("ahead", "drained"))
+
+
+def test_steps_dispatched_ahead_and_drained_match_a_hand_count(eng):
+    """A step is `ahead` when the step before it was dispatched and not
+    read, `drained` when every earlier step's tokens were on the host:
+    the first step of a busy engine, the first after an empty tick, and
+    every step of an engine whose next input needs values."""
+    def gained(fn):
+        a0, d0 = _dispatched(obs.get_registry().snapshot())
+        fn()
+        a1, d1 = _dispatched(obs.get_registry().snapshot())
+        return a1 - a0, d1 - d0
+
+    def serve(cb, new_tokens):
+        cb.submit(GenerationRequest(np.arange(1, 9, dtype=np.int32),
+                                    new_tokens))
+        while cb.step():
+            pass
+
+    cb = _cb(eng)
+    # the prompt's step, then three decode steps dispatched ahead
+    assert gained(lambda: serve(cb, 4)) == (3, 1)
+    # an empty tick dispatches nothing
+    assert gained(cb.step) == (0, 0)
+    # and the engine starts over: nothing is in flight
+    assert gained(lambda: serve(cb, 2)) == (1, 1)
+    # a speculative engine reads every step before it builds the next
+    spec = _cb(eng, spec_k=2)
+    ahead, drained = gained(lambda: serve(spec, 4))
+    assert ahead == 0 and 1 <= drained <= 4
 
 
 def test_request_spans_name_the_step_that_caused_them(eng):

@@ -331,8 +331,13 @@ def test_injected_alloc_failure_dumps_flight_record(tmp_path):
     digest = tracing.request_summary("victim", spans=dump["spans"])
     assert digest["stalls"]["alloc"] == 1
     assert digest["status"] == "failed"
-    assert digest["prefill_chunks"] == [{"granted": 4, "requested": 4},
-                                        {"granted": 4, "requested": 4}]
+    # the dump is written where the schedule fails the request, and the
+    # second chunk's step was dispatched and not read then (the engine
+    # looks one step ahead): the dump holds the chunk committed so far,
+    # the ring holds both once that step has landed
+    assert digest["prefill_chunks"] == [{"granted": 4, "requested": 4}]
+    assert tracing.request_summary("victim")["prefill_chunks"] == [
+        {"granted": 4, "requested": 4}, {"granted": 4, "requested": 4}]
     assert "victim" in rendered and "stall_alloc" in rendered
     # metrics snapshot rode along, including the alloc-failure counter
     fails = dump["metrics"]["kv_alloc_failures_total"]["children"]

@@ -159,6 +159,15 @@ def _pcts(ts, metric, window_s, now):
     return out
 
 
+def _ahead_share(by_mode):
+    """Share of the compiled steps dispatched while the step before them
+    was still in flight (`serve_steps_dispatched_total{mode}`): how often
+    the scheduler's look-ahead engaged."""
+    total = sum(by_mode.values())
+    return "-" if not total \
+        else f"{100 * by_mode.get('ahead', 0) / total:.0f}%"
+
+
 def render_dashboard(monitor, registry, tick, out=sys.stdout):
     """One text-dashboard line + per-objective burn rates from the
     monitor's windowed rings (what a production loop would push to a
@@ -178,12 +187,15 @@ def render_dashboard(monitor, registry, tick, out=sys.stdout):
                        fast, now=now)
     rate = ts.rate("serve_tokens_total", fast, now=now)
     drops = registry.timeline_stats()["dropped"]
+    modes = registry.snapshot().get(
+        "serve_steps_dispatched_total", {}).get("children", {})
     print(f"[monitor step {tick:4d}] inflight {g('serve_inflight_requests')}"
           f" queue {g('serve_queue_depth')}"
           f" | kv free {g('kv_blocks_free')}"
           f" | ttft p99 {'-' if ttft is None else f'{ttft * 1e3:.0f}ms'}"
           f" tpot p99 {'-' if tpot is None else f'{tpot * 1e3:.0f}ms'}"
           f" | tok/s {'-' if rate is None else f'{rate:.1f}'}"
+          f" | ahead {_ahead_share({m: c['value'] for m, c in modes.items()})}"
           f" | breaches {monitor.breaches_total}"
           + (f" | timeline drops {drops}" if drops else ""), file=out)
     rep = monitor.last_report
@@ -297,6 +309,8 @@ def scrape_leg(url, interval_s=2.0, count=0, out=sys.stdout):
             return "-" if v is None else f"{v:g}"
 
         breaches = _fam_sum(fams, "slo_breaches_total")
+        ahead = _ahead_share(_fam_per_label(
+            fams, "serve_steps_dispatched_total", "mode"))
         # mesh-aware view: a TP engine exports per-device KV/HBM
         # gauges — render every device's shard, not a silent device-0
         # aggregate (single-chip gateways simply lack the family)
@@ -320,6 +334,7 @@ def scrape_leg(url, interval_s=2.0, count=0, out=sys.stdout):
               f" sse-pending {g('gateway_sse_pending_events')}"
               f" | tokens {int(tokens) if tokens is not None else '-'}"
               f" ({'-' if rate is None else f'{rate:.1f}/s'})"
+              f" | ahead {ahead}"
               f" | breaches {int(breaches) if breaches is not None else 0}",
               file=out)
     return 0 if ok_polls else 1
