@@ -23,6 +23,7 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(os.path.dirname(_HERE), "lib"))
 import measure  # noqa: E402
+import readers  # noqa: E402
 import traffic  # noqa: E402
 
 TRACE_SECONDS = 4.0      # the profiler records this much of a traced window
@@ -41,7 +42,10 @@ def lattice_of(cfg, mix, seconds):
     every power of two up to `prefill_chunk`, since a prompt's last chunk
     has any length. Levels run from the mix's `warm_t_lo` to a hard bound
     on the blocks in flight: the cache, and for an open loop the
-    `max_batch` largest requests the mix's size multiset can pair."""
+    `max_batch` largest requests the mix's size multiset can pair. A mix
+    may stop the walk lower, at `warm_t_hi`, and says why: a bucket
+    beyond it that the window did reach is lowered there, and the run
+    reports `correct: false`, not a wrong number."""
     e = cfg["engine"]
     bs, mb = e["block_size"], e["max_batch"]
     cap = e["num_blocks"] - 1
@@ -52,6 +56,7 @@ def lattice_of(cfg, mix, seconds):
         per = -(-(mix["prompt"]["max"] + mix["output"]["max"]) // bs)
         cap = min(cap, mb * per)
     levels, t = [], next_pow2(int(mix.get("warm_t_lo", 64)))
+    cap = min(cap, int(mix.get("warm_t_hi", cap)))
     while t < 2 * cap and t <= next_pow2(cap):
         levels.append(t)
         t *= 2
@@ -280,6 +285,10 @@ async def _drive(ctx, cb, stepper, plan, mix, seconds, tag):
         facts["reg0"] = obs.get_registry().snapshot()
         facts["compile0"] = ctx["watch"].mark()
         jax.config.update("jax_log_compiles", True)   # names a stray one
+        # the allocator's high-water mark counts from here, so that it
+        # reads the window's own and not the walk's: the most blocks held
+        # at once, which no work list of the window was longer than
+        cb.allocator.high_water = cb.allocator.num_used
         tap = None
         if ctx["trace"]:
             tap = SpanTap()
@@ -297,6 +306,7 @@ async def _drive(ctx, cb, stepper, plan, mix, seconds, tag):
         jax.config.update("jax_log_compiles", False)
         facts["reg1"] = obs.get_registry().snapshot()
         facts["compile1"] = ctx["watch"].mark()
+        facts["blocks_high_water"] = int(cb.allocator.high_water)
         facts["device"] = measure.device_facts(ctx["devices"])
         out = await asyncio.wait_for(
             proc.stdout.readline(),
@@ -362,8 +372,11 @@ def end_to_end(records, mix, seconds):
         m["ttft_ms.p95"] = measure.percentile(ttft, 95)
         m["ttft_ms.p50"] = measure.percentile(ttft, 50)
     if gaps:
-        m["itl_ms.p95"] = measure.percentile(gaps, 95)
-        m["itl_ms.p50"] = measure.percentile(gaps, 50)
+        # p95 is the metric; p50, p90 and p99 are printed beside it to
+        # show which population of gaps (decode steps, chunk steps) the
+        # p95 sits in or between
+        for q in (50, 90, 95, 99):
+            m[f"itl_ms.p{q}"] = measure.percentile(gaps, q)
     m["serve_tokens_per_s"] = (out_tokens + prompt_tokens) / seconds
     counts = dict(attempted=len(counted), failed=len(failed),
                   finished=sum(r["status"] == "finished" for r in counted),
@@ -372,8 +385,26 @@ def end_to_end(records, mix, seconds):
                   out_tokens=out_tokens, prompt_tokens=prompt_tokens,
                   lateness_ms_p95=(measure.percentile(late, 95) or 0) * 1e3,
                   lateness_ms_max=(max(late) if late else 0) * 1e3,
-                  failed_statuses=sorted({r["status"] for r in failed}))
+                  failed_statuses=sorted({r["status"] for r in failed}),
+                  streams_first_5s=streams_in_flight(
+                      records, 0.0, min(5.0, seconds), seconds + grace),
+                  streams_window=streams_in_flight(
+                      records, 0.0, seconds, seconds + grace))
     return m, counts
+
+
+def streams_in_flight(records, lo, hi, horizon):
+    """Mean number of requests between sent and ended over [lo, hi), by
+    the client's clock: how the lead-in is sized (the window has to open
+    on its own load). One that never ended counts up to `horizon`."""
+    held = 0.0
+    for r in records:
+        if r.get("sent") is None:
+            continue
+        end = r.get("end")
+        held += max(0.0, min(hi, horizon if end is None else end)
+                    - max(lo, r["sent"]))
+    return held / (hi - lo)
 
 
 # -- correctness ---------------------------------------------------------------------------
@@ -443,11 +474,46 @@ def build_engine(ctx, cfg, family, weight_quant=None):
     return engine, cb
 
 
+def sweep_row(rate, records, facts, metrics, counts, seconds, limits):
+    """One offered rate's readings, and whether the system sustained it
+    by the mix's own `knee_limits`: nothing failed, the client kept up
+    (`lateness_ms_p95_max`), `ttft_ms.p95` under `ttft_ms_p95_max` and
+    not growing from the window's first half to its second. A backlog
+    that grows lifts the second half's by seconds; two halves of a steady
+    window differ by the noise of a 95th percentile over a hundred
+    requests each, so "no higher" has the room of `ttft_growth_share` of
+    the first half's or `ttft_growth_ms`, whichever is more."""
+    win = [r for r in records if r["phase"] == "window" and r["events"]]
+    half = lambda lo, hi: measure.percentile(
+        [(r["events"][0][0] - r["due"]) * 1e3 for r in win
+         if lo <= r["due"] < hi] or [0.0], 95)
+    first, second = half(0, seconds / 2), half(seconds / 2, seconds)
+    row = dict(rate=rate, attempted=counts["attempted"],
+               failed=counts["failed"],
+               lateness_ms_p95=counts["lateness_ms_p95"],
+               ttft_p50=metrics.get("ttft_ms.p50"),
+               ttft_p95=metrics.get("ttft_ms.p95"),
+               ttft_p95_first_half=first, ttft_p95_second_half=second,
+               **{f"itl_p{q}": metrics.get(f"itl_ms.p{q}")
+                  for q in (50, 90, 95, 99)},
+               itl_samples=counts["itl_samples"],
+               tokens_per_s=metrics["serve_tokens_per_s"],
+               **readers.window_steps(facts["reg0"], facts["reg1"]),
+               blocks_high_water=facts["blocks_high_water"],
+               streams_window=counts["streams_window"],
+               streams_first_5s=counts["streams_first_5s"])
+    row["sustained"] = bool(
+        row["failed"] == 0 and row["ttft_p95"] is not None
+        and row["lateness_ms_p95"] < limits["lateness_ms_p95_max"]
+        and row["ttft_p95"] < limits["ttft_ms_p95_max"]
+        and second - first <= max(limits["ttft_growth_share"] * first,
+                                  limits["ttft_growth_ms"]))
+    return row
+
+
 def sweep(ctx, cb, stepper, rates):
     """Not a measured run: one window per offered rate in one process,
-    to find the knee once. A rate is sustained when the queue does not
-    grow over the window: the last third's time to first token is no
-    worse than the first third's, and little is left unfinished."""
+    to find the knee once: the highest rate `sweep_row` calls sustained."""
     cfg, mix, seconds = ctx["config"], ctx["traffic"], ctx["seconds"]
     rows = []
     try:
@@ -457,26 +523,10 @@ def sweep(ctx, cb, stepper, rates):
             records, facts = asyncio.run(_drive(
                 ctx, cb, stepper, plan, m2, seconds, tag=f"r{i}"))
             metrics, counts = end_to_end(records, m2, seconds)
-            win = [r for r in records if r["phase"] == "window"
-                   and r["events"]]
-            third = lambda lo, hi: measure.percentile(
-                [(r["events"][0][0] - r["due"]) * 1e3 for r in win
-                 if lo <= r["due"] < hi] or [0.0], 50)
-            open_at_close = sum(
-                1 for r in records if r["phase"] != "lead_out"
-                and (r.get("end") is None or r["end"] > seconds)
-                and r["due"] is not None and r["due"] < seconds)
-            row = dict(rate=rate, ttft_p50=metrics.get("ttft_ms.p50"),
-                       ttft_p95=metrics.get("ttft_ms.p95"),
-                       itl_p50=metrics.get("itl_ms.p50"),
-                       itl_p95=metrics.get("itl_ms.p95"),
-                       tokens_per_s=metrics["serve_tokens_per_s"],
-                       ttft_p50_first_third=third(0, seconds / 3),
-                       ttft_p50_last_third=third(2 * seconds / 3, seconds),
-                       open_at_close=open_at_close, failed=counts["failed"],
-                       attempted=counts["attempted"],
-                       window_compiles=ctx["watch"].diff(
-                           facts["compile0"], facts["compile1"])["lowers"])
+            row = sweep_row(rate, records, facts, metrics, counts, seconds,
+                            mix["knee_limits"])
+            row["window_compiles"] = ctx["watch"].diff(
+                facts["compile0"], facts["compile1"])["lowers"]
             if ctx.get("control") or ctx.get("sweep_check"):
                 streams = sample_streams(records, cfg, ctx["seed"] + i,
                                          int(cfg["check"]["sample_requests"]))
@@ -503,7 +553,10 @@ def run(ctx):
     jax.block_until_ready(cb.caches)
     print(f"[setup] engine built at {time.monotonic() - ctx['t_start']:.1f} s",
           flush=True)
-    levels, widths = lattice_of(cfg, mix, seconds)
+    # a sweep walks the lattice of its highest rate
+    levels, widths = lattice_of(
+        cfg, dict(mix, rate_per_s=max(ctx["sweep"])) if ctx.get("sweep")
+        else mix, seconds)
     m0 = watch.mark()
     lower_s, compile_s = compile_ahead(
         cb, [(t, c) for t in levels for c in widths],
@@ -542,6 +595,9 @@ def run(ctx):
     window = watch.diff(facts["compile0"], facts["compile1"])
     facts["window_compiles"] = window["compiles"] + window["lowers"]
     metrics, counts = end_to_end(records, mix, seconds)
+    counts.update(readers.window_steps(facts["reg0"], facts["reg1"]),
+                  blocks_high_water=facts["blocks_high_water"],
+                  lattice_top=levels[-1])
     metrics["setup_s"] = facts["setup_s"]
     streams = sample_streams(records, cfg, ctx["seed"],
                              int(cfg["check"]["sample_requests"]))

@@ -47,22 +47,42 @@ def stepper_states(trace):
             if (state := state_of(n)) is not None]
 
 
-def step_windows(trace):
-    """[(start_ns, end_ns, slab width)], one for each step that lies
-    whole in the trace: from the opening of its `serve.dispatch
-    w<work>c<slab>` to the close of the `serve.fetch` of the same bucket
-    that follows it. The scheduler is synchronous (a step's tokens are
-    on the host before the next step is built), so the device ran this
-    step's program, and no other step's, inside the interval. A step the
-    trace's edge cuts is left out."""
-    out, opened = [], None
-    for a, b, n in stepper_events(trace):
-        head, _, bucket = n.partition(" ")
-        if head == "serve.dispatch" and bucket:
-            opened = (a, bucket)
-        elif head == "serve.fetch" and opened and opened[1] == bucket:
-            out.append((opened[0], b, int(bucket.rpartition("c")[2])))
-            opened = None
+# the jitted step's own name, as the device's `XLA Modules` line has it
+STEP_MODULE = "paged_step"
+
+
+def step_windows(trace, device=0):
+    """[(start_ns, end_ns, slab width)], one for each step whose program
+    ran whole inside the trace: the interval is the step's own
+    `jit_paged_step` event on the device's `XLA Modules` line, the width
+    that of the `serve.dispatch w<work>c<slab>` that launched it. The
+    two are joined in order, the k-th module event to the k-th dispatch:
+    the device runs the steps in the order they were dispatched, and a
+    program cannot start before the dispatch that launched it opened, so
+    the module events that start before the trace's first dispatch (a
+    step in flight when the profiler started) are stepped over. Not by
+    the `serve.fetch` that follows a dispatch: since the scheduler looks
+    one step ahead that fetch waits for the step BEFORE, and a window
+    from dispatch to fetch held the tail of one step and the head of the
+    next. A dispatch whose program the trace's edge cuts gets none."""
+    planes = xtrace.device_planes(trace)
+    line = xtrace.line_of(planes[device], xtrace.MODULES_LINE) \
+        if device < len(planes) else None
+    if line is None:
+        return []
+    modules = sorted((s, s + d) for n, s, d in line["events"]
+                     if STEP_MODULE in n)
+    out, k = [], 0
+    for opened, _, name in stepper_events(trace):
+        head, _, bucket = name.partition(" ")
+        if head != "serve.dispatch" or not bucket:
+            continue
+        while k < len(modules) and modules[k][0] < opened:
+            k += 1
+        if k == len(modules):
+            break
+        out.append((*modules[k], int(bucket.rpartition("c")[2])))
+        k += 1
     return out
 
 

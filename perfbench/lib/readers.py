@@ -8,24 +8,46 @@ import statistics
 import trace as xtrace
 
 
+def hist_gain(reg0, reg1, family, child=""):
+    """(sum, count) a histogram child gained between two snapshots of
+    the program's registry."""
+    def get(snap):
+        c = snap.get(family, {}).get("children", {}).get(child)
+        return (c["sum"], c["count"]) if c else (0.0, 0)
+    (s0, n0), (s1, n1) = get(reg0), get(reg1)
+    return s1 - s0, n1 - n0
+
+
+def counter_gain(reg0, reg1, family, child=""):
+    def get(snap):
+        c = snap.get(family, {}).get("children", {}).get(child)
+        return c["value"] if c else 0.0
+    return get(reg1) - get(reg0)
+
+
 def hist_delta(ctx, family, child=""):
     """(sum, count) a histogram child of the program's registry gained
     inside the window."""
     f = ctx["out"]["facts"]
-    def get(snap):
-        c = snap.get(family, {}).get("children", {}).get(child)
-        return (c["sum"], c["count"]) if c else (0.0, 0)
-    s0, n0 = get(f["reg0"])
-    s1, n1 = get(f["reg1"])
-    return s1 - s0, n1 - n0
+    return hist_gain(f["reg0"], f["reg1"], family, child)
 
 
 def counter_delta(ctx, family, child=""):
     f = ctx["out"]["facts"]
-    def get(snap):
-        c = snap.get(family, {}).get("children", {}).get(child)
-        return c["value"] if c else 0.0
-    return get(f["reg1"]) - get(f["reg0"])
+    return counter_gain(f["reg0"], f["reg1"], family, child)
+
+
+def window_steps(reg0, reg1):
+    """The steps between two snapshots by kind, and by whether the step
+    before was still in flight when each was dispatched: what the
+    driver prints with every run's counts and the two regime readers
+    (`chunk_step_share_pct.chat`, `steps_ahead_pct.chat`) divide."""
+    return dict(
+        steps={k: hist_gain(reg0, reg1, "serve_step_kind_seconds", k)[1]
+               for k in ("decode", "chunk")},
+        dispatched={k: counter_gain(reg0, reg1,
+                                    "serve_steps_dispatched_total", k)
+                    for k in ("ahead", "drained")})
 
 
 def host_step_ms(ctx):

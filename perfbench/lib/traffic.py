@@ -4,9 +4,12 @@ Every seed gets the SAME multiset of sizes and of gaps between arrivals
 — the distribution's quantiles at (i + 0.5) / n — in an order drawn from
 the seed. So two seeds do the same amount of work and differ only in
 which request meets which; a run-to-run difference is then the system's,
-not the draw's. Token ids come from (seed, request index) alone, so the
-client process and the reference check make the same prompt without
-passing it around.
+not the draw's. Where a mix names `order_block`, the order is drawn
+block by block (`dealt`), so that every stretch of that many requests
+also brings the same work at the same mean rate: which seed draws a
+long burst of long answers no longer decides a tail percentile. Token
+ids come from (seed, request index) alone, so the client process and
+the reference check make the same prompt without passing it around.
 
 numpy only: the client process imports this and must stay off jax.
 """
@@ -70,10 +73,31 @@ def prompt_tokens(seed, index, n, vocab):
     return rng_for(seed, 7, int(index)).integers(1, vocab, int(n))
 
 
-def _sized(mix, n, rng):
+def dealt(values, block, rng):
+    """The values in an order drawn from `rng`. Without `block` (or with
+    fewer than two blocks' worth) any order. With it, the n values fall
+    into b = round(n / block) consecutive blocks, and each run of b
+    consecutive order statistics gives one value to each block (which
+    to which is drawn), then each block's own order is drawn: every
+    block is a stratified sample of the whole multiset, about `block`
+    strata, and the multiset is what it was."""
+    values = np.sort(np.asarray(values))
+    n = len(values)
+    b = int(round(n / block)) if block else 1
+    if b < 2:
+        return rng.permutation(values)
+    blocks = [[] for _ in range(b)]
+    for lo in range(0, n, b):
+        run = values[lo:lo + b]
+        for v, k in zip(run, rng.permutation(b)[:len(run)]):
+            blocks[k].append(v)
+    return np.concatenate([rng.permutation(np.asarray(x)) for x in blocks])
+
+
+def _sized(mix, n, rng, block=None):
     p = lengths(mix["prompt"], n)
     o = lengths(mix["output"], n)
-    return rng.permutation(p), rng.permutation(o)
+    return dealt(p, block, rng), dealt(o, block, rng)
 
 
 def open_loop_plan(mix, seed, seconds):
@@ -87,14 +111,15 @@ def open_loop_plan(mix, seed, seconds):
     lead = float(mix.get("lead_in_s", 0.0))
     grace = float(mix.get("grace_s", 10.0))
     burst = int(mix.get("lead_in_burst", 0))
+    block = mix.get("order_block")
     plan = []
 
     def phase(name, n, t0, stream):
         if n <= 0:
             return
         rng = rng_for(seed, stream)
-        p, o = _sized(mix, n, rng)
-        cum = np.cumsum(rng.permutation(exp_gaps(rate, n)))
+        p, o = _sized(mix, n, rng, block)
+        cum = np.cumsum(dealt(exp_gaps(rate, n), block, rng))
         # n arrivals inside [t0, t0 + n / rate), the last strictly inside
         due = t0 + cum * ((n / rate) * n / (n + 1.0) / cum[-1])
         for i in range(n):

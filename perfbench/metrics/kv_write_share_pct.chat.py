@@ -6,7 +6,9 @@ layer (52.6-57.7%). The scope is in the op's metadata, which
 lib/xplane.py reads from the trace file itself; a decode step is one the
 stepper thread dispatched with a slab one column wide (`serve.dispatch
 w..c1`: chat decodes without drafts), and its ops are those that start
-between that dispatch and the end of its `serve.fetch`. Over all steps
+inside its own `jit_paged_step` program on the device
+(`annotations.step_windows`: the k-th program is the k-th dispatch's,
+whatever step the host was reading meanwhile). Over all steps
 the share would follow the slice's mix of chunk and decode steps (a
 chunk step is ten times a decode step, and its writer's scatter of the
 whole slab carries no scope: PERF.md, Open questions), not the decode

@@ -123,11 +123,6 @@ def main(argv=None):
     out = driver.run(ctx)
 
     setup = watch.mark()
-    for row in out["checks"]:
-        extra = {k: v for k, v in row.items()
-                 if k not in ("name", "value", "limit", "ok")}
-        print(f"[check] {row['name']}: {row['value']} (limit {row['limit']})"
-              f" {'ok' if row['ok'] else 'NOT OK'} {extra or ''}", flush=True)
     print(f"[counts] {json.dumps(out['counts'])}", flush=True)
     for k in sorted(out["metrics"]):
         print(f"[measured] {k} = {out['metrics'][k]}", flush=True)
@@ -136,13 +131,11 @@ def main(argv=None):
     result = {"correct": bool(out["correct"]),
               "attempted": int(out["counts"]["attempted"]),
               "failed": int(out["counts"]["failed"]), "metrics": {},
-              "device": device, "checks": out["checks"],
-              "counts": out["counts"]}
+              "device": device, "counts": out["counts"]}
     if args.rehearse or args.control or args.sweep:
         result["not_a_measured_run"] = ("rehearsal" if args.rehearse else
                                         args.control or "sweep")
-        measure.emit(result)
-        return 0
+        return finish(result, out["checks"])
     if not args.trace:
         for m in man.end_to_end_of(cell["name"]):
             result["metrics"][m["name"]] = measure.metric(
@@ -158,6 +151,22 @@ def main(argv=None):
             if value is not None:
                 result["metrics"][m["name"]] = measure.metric(value,
                                                               m["unit"])
+    return finish(result, out["checks"])
+
+
+def finish(result, checks):
+    """Each number compared beside its limit: the last lines of standard
+    output before the result line and of standard error, and the result
+    line's last key."""
+    for row in checks:
+        extra = {k: v for k, v in row.items()
+                 if k not in ("name", "value", "limit", "ok")}
+        text = (f"[check] {row['name']}: {row['value']} (limit "
+                f"{row['limit']}) {'ok' if row['ok'] else 'NOT OK'} "
+                f"{extra or ''}")
+        print(text, flush=True)
+        print(text, file=sys.stderr, flush=True)
+    result["checks"] = checks
     measure.emit(result)
     return 0
 

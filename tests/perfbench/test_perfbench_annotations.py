@@ -210,15 +210,48 @@ def test_scoped_ops_joins_each_event_to_its_own_metadata(trace_dir):
 
 
 # the stepper thread as it ran those five ops (data "xplane_steps"): a
-# decode step [900, 1600) around the first two, a chunk step
-# [2400, 3600) around the next two, a decode step [3900, 4350) around
-# the last, and a dispatch that the trace's edge cuts
-def test_step_windows_pair_a_dispatch_with_its_fetch():
+# decode step whose program ran [1000, 1500) around the first two, a
+# chunk step [2500, 3500) around the next two, a decode step
+# [4000, 4200) around the last, and a dispatch whose program the
+# trace's edge cuts
+def test_step_windows_are_the_programs_of_the_dispatches_in_order():
     assert annotations.step_windows(DATA["xplane_steps"]) == [
-        (900, 1600, 1), (2400, 3600, 8), (3900, 4350, 1)]
-    # chat's own stepper line opens in the middle of a step
-    assert annotations.step_windows(DATA["chat"]) == [(2250, 3600, 1)]
+        (1000, 1500, 1), (2500, 3500, 8), (4000, 4200, 1)]
+    # chat's own stepper line opens in the middle of a step: the program
+    # at 1000 was dispatched before the trace began and is stepped over
+    assert annotations.step_windows(DATA["chat"]) == [
+        (2500, 3500, 1), (5000, 5500, 8)]
     assert annotations.step_windows({"planes": []}) == []
+    # a trace without the device's modules line has no step to give
+    host_only = {"planes": [p for p in DATA["xplane_steps"]["planes"]
+                            if p["name"].startswith("/host:")]}
+    assert annotations.step_windows(host_only) == []
+
+
+with open(os.path.join(HERE, "data", "lookahead_trace.json")) as _f:
+    AHEAD = json.load(_f)["lookahead"]
+
+
+def test_step_windows_follow_a_scheduler_that_looks_one_step_ahead():
+    """Since PR 35 a turn dispatches step n+1 and then fetches step n:
+    the fetch that follows `serve.dispatch w8c128` is `serve.fetch w8c1`,
+    the step before. Joined in order, each dispatch gets its own
+    program: the one in flight when the trace began is stepped over, the
+    `jit__feed_tokens` programs between the steps are no steps, and the
+    last dispatch, whose program the trace's end cuts, gets none."""
+    got = annotations.step_windows(AHEAD)
+    assert got == [(1510, 2500, 1), (2510, 4500, 128), (4510, 5500, 1),
+                   (5510, 6500, 1)]
+    # whole steps: every window is one program's own interval, so the
+    # decode windows hold no part of the 128-wide step (a join of each
+    # dispatch to the next fetch of its bucket gave (750, 1500), the step
+    # BEFORE the first dispatch, and (2750, 5500), the chunk step's tail
+    # with a decode step)
+    decode = [(a, b) for a, b, slab in got if slab == 1]
+    assert sum(b - a for a, b in decode) == 3 * 990
+    ops = AHEAD["planes"][0]["lines"][1]["events"]
+    inside = [n for n, s, _ in ops if any(a <= s < b for a, b in decode)]
+    assert len(inside) == 3 and all("fusion.1" in n for n in inside)
 
 
 @pytest.mark.parametrize("within,want", [
@@ -240,10 +273,12 @@ def test_kv_write_share_is_taken_over_the_decode_steps(trace_dir):
     assert reader("kv_write_share_pct.chat")(ctx) \
         == pytest.approx(100.0 * 400 / 700)
     # a slice that holds chunk steps only has no decode share
-    chunks = {"planes": [{"name": "/host:CPU", "lines": [{
+    host, device = DATA["xplane_steps"]["planes"]
+    chunks = {"planes": [device, {"name": "/host:CPU", "lines": [{
         "name": "python3", "events": [
-            e for e in DATA["xplane_steps"]["planes"][0]["lines"][0]["events"]
+            e for e in host["lines"][0]["events"]
             if not e[0].endswith("c1")]}]}]}
+    assert annotations.step_windows(chunks) == [(2500, 3500, 8)]
     assert reader("kv_write_share_pct.chat")(
         {"trace_dir": trace_dir, "trace": chunks}) is None
 

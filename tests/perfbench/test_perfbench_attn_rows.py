@@ -1,15 +1,12 @@
 """`attn_rows_live_pct.chat` (ISSUE 31): the reader of the ragged
 kernel's live-row counter against a hand-made registry window, against a
 program that has no such counter (the parent of the PR that brought it:
-None, and no raise), and its place in the manifest, committed and with a
-foreign configuration appended."""
-import os
-
+None, and no raise), and its entry in the manifest, committed and with a
+foreign configuration appended: asked for by name, wherever later PRs'
+appended entries leave it."""
 import pytest
 
-from perfbench_fixtures import REPO, real  # noqa: F401
-
-import manifest as mf
+from perfbench_fixtures import real  # noqa: F401
 
 NAME = "attn_rows_live_pct.chat"
 
@@ -29,15 +26,14 @@ def window(reg0, reg1):
     return {"out": {"facts": {"reg0": reg0, "reg1": reg1}}}
 
 
-def test_the_entry_is_the_kernels_layers_and_the_last_of_the_committed(real):
+def test_the_entry_is_the_kernels_layers_wherever_it_stands(real):
     entry, _ = reader(real)
     assert entry == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
         "moves": "itl_ms.p95",
         "workloads": ["mistral7b-serve-1chip.chat"]}
-    committed = mf.Manifest(os.path.join(REPO, "BENCHMARK.json"))
-    assert committed.per_layer[-1]["name"] == NAME
+    assert entry in real.per_layer_of("mistral7b-serve-1chip.chat")
 
 
 @pytest.mark.parametrize("reg0,reg1,want", [

@@ -215,12 +215,90 @@ def test_traffic_is_reproducible_and_every_seed_gets_the_same_work():
     assert traffic.prompt_tokens(big, 3, 50, 1000).min() >= 1
 
 
-def test_open_loop_times_count_from_when_a_request_was_due():
+@pytest.mark.parametrize("n,block", [(272, 16), (160, 8), (48, 4)])
+def test_every_block_of_the_order_is_a_stratified_sample(n, block):
+    """`dealt`, where the blocks come out even: each block of the order
+    holds exactly one value of every run of b consecutive order
+    statistics."""
+    vals = traffic.exp_gaps(5.6, n)
+    out = traffic.dealt(vals, block, np.random.default_rng(n))
+    b = n // block
+    ranks = np.searchsorted(vals, out)
+    for lo in range(0, n, block):
+        assert sorted(r // b for r in ranks[lo:lo + block]) \
+            == list(range(block))
+    assert traffic.dealt(vals, block, np.random.default_rng(n + 1)).tolist() \
+        != out.tolist()
+
+
+@pytest.mark.parametrize("n,block", [(269, 16), (45, 16), (168, 16),
+                                     (100, 8), (33, 4)])
+def test_a_block_wise_order_keeps_the_multiset(n, block):
+    """Any n, where the last run of order statistics is short and some
+    blocks hold one value more: the same values, the same order from
+    the same draw, and sums over whole blocks nearer their share than a
+    free order's (over a dozen draws, at the blocks' mean length)."""
+    vals = traffic.exp_gaps(5.6, n)
+    m = n // round(n / block)
+    stray = lambda order: np.abs(
+        np.cumsum(order)[m - 1::m] - np.arange(m, n + 1, m) / n
+        * vals.sum()).mean()
+    blocked, free = [], []
+    for k in range(12):
+        out = traffic.dealt(vals, block, np.random.default_rng([n, k]))
+        assert sorted(out) == sorted(vals)
+        assert (out == traffic.dealt(vals, block,
+                                     np.random.default_rng([n, k]))).all()
+        blocked.append(stray(out))
+        free.append(stray(np.random.default_rng([n, k, 1])
+                          .permutation(vals)))
+    assert np.mean(blocked) < np.mean(free)
+
+
+def test_without_a_block_or_with_too_few_values_any_order_is_drawn():
+    vals = traffic.lengths(dict(dist="uniform", min=1, max=100), 20)
+    for block in (None, 0, 16, 40):        # 20 / 16 rounds to one block
+        a = traffic.dealt(vals, block, np.random.default_rng(3))
+        b = np.random.default_rng(3).permutation(vals)
+        assert (a == b).all()
+
+
+def test_chats_plan_is_one_multiset_in_a_block_wise_order():
+    """The committed mix, whatever its rate: every seed the same sizes
+    and gaps, rate x seconds of them, and every sixth of the window
+    brings about a sixth of the requests and of the tokens, whichever
+    seed."""
+    with open(os.path.join(BENCH, "traffic", "chat.json")) as f:
+        mix = json.load(f)
+    assert mix["order_block"] >= 4 and len(mix["order_block_why"]) > 100
+    n = round(mix["rate_per_s"] * 48)
+    plans = [[r for r in traffic.open_loop_plan(mix, s, 48)
+              if r["phase"] == "window"]
+             for s in (2 ** 31 + 3, 3000002011, 7)]
+    for key in ("prompt_len", "max_new_tokens"):
+        assert len({tuple(sorted(r[key] for r in p)) for p in plans}) == 1
+    assert plans[0] != plans[1]
+    total = sum(r["prompt_len"] + r["max_new_tokens"] for r in plans[0])
+    for p in plans:
+        assert len(p) == n
+        for k in range(6):
+            part = [r for r in p if 8 * k <= r["due_s"] < 8 * (k + 1)]
+            assert 0.75 * n / 6 < len(part) < 1.25 * n / 6
+            work = sum(r["prompt_len"] + r["max_new_tokens"] for r in part)
+            assert 0.7 * total / 6 < work < 1.3 * total / 6
+
+
+def _serve_driver():
     import importlib.util
     spec = importlib.util.spec_from_file_location(
         "serve_driver", os.path.join(BENCH, "drivers", "serve.py"))
     serve = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(serve)
+    return serve
+
+
+def test_open_loop_times_count_from_when_a_request_was_due():
+    serve = _serve_driver()
     rec = lambda due, sent, first: dict(
         phase="window", due=due, sent=sent, status="finished",
         max_new_tokens=2, tokens=[1, 2], prompt_len=5, index=0,
@@ -234,6 +312,165 @@ def test_open_loop_times_count_from_when_a_request_was_due():
     m, counts = serve.end_to_end(late + failed, dict(loop="open",
                                                      grace_s=5), 10.0)
     assert counts["failed"] == 1 and counts["attempted"] == 21
+
+
+def _stream(due, first, n, gap_ms, end=None, phase="window", late=0.0):
+    """One finished request: first token at `first`, then one every
+    `gap_ms`."""
+    events = [(first + i * gap_ms / 1e3, 1) for i in range(n)]
+    return dict(phase=phase, due=due, sent=due + late, status="finished",
+                max_new_tokens=n, tokens=list(range(n)), prompt_len=5,
+                index=0, events=events,
+                end=events[-1][0] if end is None else end)
+
+
+def test_the_percentiles_beside_the_p95_show_the_two_populations():
+    """800 gaps of 11 ms and 200 of 20: the p95 sits among the chunk
+    steps' gaps, and p50 / p90 / p99, printed beside it, say so."""
+    serve = _serve_driver()
+    recs = [_stream(0.5, 1.0, 801, 11.0), _stream(0.5, 1.0, 201, 20.0)]
+    m, counts = serve.end_to_end(recs, dict(loop="open", grace_s=5), 10.0)
+    assert counts["itl_samples"] == 1000
+    assert m["itl_ms.p50"] == pytest.approx(11.0)
+    assert m["itl_ms.p90"] == pytest.approx(20.0)
+    assert m["itl_ms.p95"] == pytest.approx(20.0)
+    assert m["itl_ms.p99"] == pytest.approx(20.0)
+
+
+@pytest.mark.parametrize("lo,hi,want", [
+    (0.0, 10.0, (4.0 + 2.0 + 5.0) / 10.0),
+    (0.0, 5.0, (4.0 + 0.0 + 0.0) / 5.0),    # the window's opening
+    (5.0, 10.0, (0.0 + 2.0 + 5.0) / 5.0),
+])
+def test_streams_in_flight_is_a_mean_over_the_clients_clock(lo, hi, want):
+    """Sent to ended, clipped to the interval: one stream over [-1, 4),
+    one over [6, 8), one sent at 5 that never ended (held to the
+    horizon), one never sent."""
+    serve = _serve_driver()
+    recs = [dict(sent=-1.0, end=4.0), dict(sent=6.0, end=8.0),
+            dict(sent=5.0, end=None), dict(sent=None, end=None)]
+    assert serve.streams_in_flight(recs, lo, hi, 40.0) \
+        == pytest.approx(want)
+
+
+def _sweep_facts(decode, chunk):
+    kind = lambda d, c: {"serve_step_kind_seconds": {"children": {
+        "decode": {"sum": 0.0, "count": d}, "chunk": {"sum": 0.0,
+                                                      "count": c}}}}
+    return {"reg0": kind(100, 50), "reg1": kind(100 + decode, 50 + chunk),
+            "blocks_high_water": 57}
+
+
+@pytest.mark.parametrize("why,ttft_first,ttft_second,late,failed,want", [
+    ("steady", 0.30, 0.30, 0.0, 0, True),
+    ("a backlog that grows: the second half waits longer", 0.30, 0.45,
+     0.0, 0, False),
+    ("the noise of two halves: 60 ms more on 300", 0.30, 0.36, 0.0, 0,
+     True),
+    ("a fifth more is noise where a tenth of the limit is not", 0.70, 0.83,
+     0.0, 0, True),
+    ("a backlog that shrinks", 0.45, 0.30, 0.0, 0, True),
+    ("time to first token over the limit of a second", 1.2, 1.1, 0.0, 0,
+     False),
+    ("the client did not keep up", 0.30, 0.30, 0.080, 0, False),
+    ("a request failed", 0.30, 0.30, 0.0, 1, False),
+])
+def test_a_rate_is_sustained_by_the_knees_own_definition(
+        why, ttft_first, ttft_second, late, failed, want):
+    serve = _serve_driver()
+    recs = [_stream(t, t + late + (ttft_first if t < 5.0 else ttft_second),
+                    4, 11.0, late=late) for t in np.arange(0.0, 10.0, 0.25)]
+    recs += [dict(_stream(9.0, 9.3, 4, 11.0), status="truncated",
+                  events=[])] * failed
+    mix = dict(loop="open", grace_s=5)
+    m, counts = serve.end_to_end(recs, mix, 10.0)
+    with open(os.path.join(BENCH, "traffic", "chat.json")) as f:
+        limits = json.load(f)["knee_limits"]
+    row = serve.sweep_row(4.0, recs, _sweep_facts(3000, 900), m, counts,
+                          10.0, limits)
+    assert row["sustained"] is want, why
+    assert row["steps"] == {"decode": 3000, "chunk": 900}
+    assert row["blocks_high_water"] == 57
+    assert row["attempted"] == 40 + failed and row["failed"] == failed
+    assert {"itl_p50", "itl_p90", "itl_p95", "itl_p99", "ttft_p50",
+            "ttft_p95", "lateness_ms_p95", "tokens_per_s",
+            "streams_window", "streams_first_5s"} <= set(row)
+
+
+KNEE_LIMITS = {"lateness_ms_p95_max", "ttft_ms_p95_max",
+               "ttft_growth_share", "ttft_growth_ms"}
+
+
+def rate_faults(mix):
+    """What a test can hold of the rules a serving cell is proven by
+    (PERF.md section 4): an open-loop mix states the knee a sweep found,
+    the limits that defined it, and offers four fifths of it."""
+    if mix["loop"] != "open":
+        return []
+    bad = []
+    if not is_number(mix.get("knee_per_s")):
+        return ["no knee_per_s"]
+    if set(mix.get("knee_limits", ())) - {"why"} != KNEE_LIMITS:
+        bad.append(f"knee_limits is not {sorted(KNEE_LIMITS)}")
+    if mix["rate_per_s"] != round(0.8 * mix["knee_per_s"], 1):
+        bad.append(f"rate_per_s {mix['rate_per_s']} is not 0.8 of the knee "
+                   f"{mix['knee_per_s']}")
+    return bad
+
+
+def test_every_open_loop_cell_offers_four_fifths_of_its_swept_knee(real):
+    for name, cell in real.cells.items():
+        assert rate_faults(real.traffic(cell)) == [], name
+
+
+@pytest.mark.parametrize("edit,complaint", [
+    (lambda m: m.pop("knee_per_s"), "no knee_per_s"),
+    (lambda m: m.update(rate_per_s=1.7), "is not 0.8 of the knee"),
+    (lambda m: m.update(knee_per_s=2.1), "is not 0.8 of the knee"),
+    (lambda m: m.pop("knee_limits"), "knee_limits is not"),
+    (lambda m: m["knee_limits"].pop("ttft_growth_ms"), "knee_limits is not"),
+])
+def test_a_stale_rate_or_knee_is_told(real, edit, complaint):
+    mix = json.loads(json.dumps(
+        real.traffic(real.cell("mistral7b-serve-1chip.chat"))))
+    edit(mix)
+    assert any(complaint in c for c in rate_faults(mix)), rate_faults(mix)
+
+
+def test_chats_walk_stops_where_the_mix_says(real):
+    """The lattice follows the rate: the more requests a window holds,
+    the further into the size tails its `max_batch` largest reach, and
+    the hard bound on the blocks in flight passes a power of two that
+    the machines' compile cache has no room for (it holds 64 of these
+    bucket programs). The mix stops the walk at `warm_t_hi` and says
+    why; without the key the hard bound decides, and a bound under the
+    mix's stop is kept."""
+    serve = _serve_driver()
+    cell = real.cell("mistral7b-serve-1chip.chat")
+    cfg, mix = real.config(cell), real.traffic(cell)
+    e = cfg["engine"]
+    levels, widths = serve.lattice_of(cfg, mix, real.run_seconds)
+    assert levels[0] == mix["warm_t_lo"] == 1
+    assert levels == [1 << i for i in range(len(levels))]
+    assert widths[-1] == e["prefill_chunk"]
+    assert levels[-1] == serve.next_pow2(mix["warm_t_hi"])
+    assert len(levels) * len(widths) <= 64
+    assert len(mix["warm_t_hi_why"]) > 100
+    n = round(mix["rate_per_s"] * real.run_seconds)
+    hard_bound = traffic.footprint_bound(mix, n, e["max_batch"],
+                                         e["block_size"])
+    assert hard_bound > mix["warm_t_hi"]        # or the key has no use
+    free = {k: v for k, v in mix.items() if k != "warm_t_hi"}
+    hard, _ = serve.lattice_of(cfg, free, real.run_seconds)
+    assert hard[:len(levels)] == levels
+    assert hard[-1] == serve.next_pow2(hard_bound) > levels[-1]
+    low = dict(mix, warm_t_hi=40)
+    assert serve.lattice_of(cfg, low, real.run_seconds)[0][-1] == 64
+    few = dict(mix, rate_per_s=0.4)     # 19 requests pair to few blocks
+    assert traffic.footprint_bound(few, 19, e["max_batch"],
+                                   e["block_size"]) < mix["warm_t_hi"]
+    assert serve.lattice_of(cfg, few, real.run_seconds)[0][-1] \
+        <= levels[-1]
 
 
 def test_the_result_line_has_exactly_the_contract_keys():

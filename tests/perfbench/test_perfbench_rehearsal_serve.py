@@ -47,9 +47,14 @@ def test_a_token_altered_where_it_is_produced_is_not_correct(
 def test_a_lower_precision_engine_is_not_correct(tmp_path, capsys):
     """The control, at a size a test can hold: the tiny configuration
     states float32, so the engine built in bfloat16 (the nearest
-    precision below) must fail the limits float32 passes."""
+    precision below) must fail the limits float32 passes. The mix is an
+    open loop, whose plan is a fixed number of requests each run to its
+    end (the grace is a minute): how many tokens are compared follows
+    from the plan, not from how fast this machine steps."""
     import json
     import os
+
+    import traffic
     path = tiny_manifest(str(tmp_path))
     low = os.path.join(str(tmp_path), "low")
     os.makedirs(os.path.join(low, "configs"))
@@ -57,7 +62,7 @@ def test_a_lower_precision_engine_is_not_correct(tmp_path, capsys):
                            "tiny-serve.json")) as f:
         cfg = json.load(f)
     cfg["dtype"] = "bfloat16"
-    cfg["check"]["sample_requests"] = 200    # some hundreds of tokens
+    cfg["check"]["sample_requests"] = 200    # every finished request
     with open(os.path.join(low, "configs", "tiny-serve-bf16.json"),
               "w") as f:
         json.dump(cfg, f)
@@ -67,14 +72,25 @@ def test_a_lower_precision_engine_is_not_correct(tmp_path, capsys):
     man["configs"].append({"name": "tiny-serve-bf16", "source": "tests",
                            "file": "low/configs/tiny-serve-bf16.json",
                            "reduced": [], "why": "control"})
-    man["workloads"].append({"name": "tiny-serve-bf16.tiny-docs",
-                             "config": "tiny-serve-bf16",
-                             "traffic": "tiny-docs", "chips": 1,
+    cell = "tiny-serve-bf16.tiny-control"
+    man["workloads"].append({"name": cell, "config": "tiny-serve-bf16",
+                             "traffic": "tiny-control", "chips": 1,
                              "why": "control"})
-    man["end_to_end"][2]["workloads"].append("tiny-serve-bf16.tiny-docs")
+    man["end_to_end"][1]["workloads"].append(cell)
     with open(path, "w") as f:
         json.dump(man, f)
-    rc, line, out = rehearse(capsys, path, "tiny-serve-bf16.tiny-docs")
+    seed, seconds = 3000000019, 3
+    with open(os.path.join(str(tmp_path), "tiny", "traffic",
+                           "tiny-control.json")) as f:
+        plan = traffic.open_loop_plan(json.load(f), seed, seconds)
+    due = [r for r in plan if r["phase"] != "lead_out"]
+    want = sum(r["max_new_tokens"] for r in due)
+    assert len(due) == 42 and want >= 300   # some hundreds of tokens
+    rc, line, out = rehearse(capsys, path, cell, seed=seed, seconds=seconds)
     assert rc == 0 and line["correct"] is False, out
+    assert line["attempted"] == 30 and line["failed"] == 0, out
+    # it is a number of the comparison that fails it, nothing beside
+    bad = {c["name"] for c in line["checks"] if not c["ok"]}
+    assert bad and bad <= {"mean_gap", "worst_gap", "nonargmax_share"}
     compared = [c for c in line["checks"] if c["name"] == "tokens_compared"]
-    assert compared[0]["value"] >= 300
+    assert compared[0]["value"] == want
