@@ -57,6 +57,7 @@ import numpy as np
 from ...observability import instrument as _metrics
 from ...observability import tracing as _tracing
 from ...ops.pallas.paged_attention import (RaggedWorkBuilder, attn_rows,
+                                           window_entries, window_span,
                                            build_ragged_work, default_pack,
                                            next_pow2, step_rows)
 
@@ -141,14 +142,15 @@ class _StepInputs:
     keyed by the bucketed widths that key the compiles, so steady state
     allocates nothing."""
 
-    def __init__(self, batch, max_blocks):
+    def __init__(self, batch, table_width):
         self.batch = batch
         self.slabs = {}         # c -> [B, c] int32
         self.sels = {}          # w_sel -> [B, w_sel] int32
         self.works = {}         # t_total -> nine [t_total] int32
         self.q = np.zeros(batch, np.int32)
         self.fed = np.zeros(batch, bool)
-        self.tables = np.zeros((batch, max_blocks), np.int32)
+        # every block table of a sequence, side by side
+        self.tables = np.zeros((batch, table_width), np.int32)
         self.lens = np.zeros(batch, np.int32)
 
     def zeroed(self, pool, width):
@@ -180,6 +182,7 @@ class _Flight:
 
     __slots__ = ("step", "toks", "snapshot", "entries", "c", "t_total",
                  "pack", "work", "bucket", "kind", "live", "comm_task",
+                 "held", "visited", "pairs",
                  "t_begin", "pc_begin", "pc_sched", "pc_step", "pc_disp")
 
 
@@ -238,7 +241,10 @@ class BlockAllocator:
     raises instead of corrupting the free list; `num_used` counts
     PHYSICAL blocks held by requests (pooled blocks are reusable cache,
     not in use) and is structurally non-negative; `high_water` tracks
-    peak physical use — a block shared by 8 requests counts once."""
+    peak physical use — a block shared by 8 requests counts once.
+    `block_bytes` is what one block takes on a device over the layers
+    that live by this pool, so that two pools of one engine (full and
+    window layers) are counted in bytes (`bytes_used`)."""
 
     # the exhaustion type, reachable from an allocator handle (fault
     # injectors raise `type(cb.allocator).OutOfBlocks` without an
@@ -250,12 +256,15 @@ class BlockAllocator:
     # realistic workload; overflow just costs one full-walk rebuild
     INDEX_LOG = 128
 
-    def __init__(self, num_blocks, reserved=1):
+    def __init__(self, num_blocks, reserved=1, block_bytes=0):
         if num_blocks <= reserved:
             raise ValueError(
                 f"need more than {reserved} blocks (got {num_blocks})")
         self.num_blocks = num_blocks
         self.reserved = reserved
+        # what one block of this pool takes on a device, over the layers
+        # that live by it: pools of two kinds of layer count in bytes
+        self.block_bytes = int(block_bytes)
         self._free = list(range(num_blocks - 1, reserved - 1, -1))
         self._free_set = set(self._free)  # O(1) double-free check
         self._ref = {}          # block -> refcount, held blocks only
@@ -287,6 +296,11 @@ class BlockAllocator:
         cache, not use; a block shared by N requests counts once."""
         return (self.num_blocks - self.reserved) - len(self._free) \
             - len(self._pool)
+
+    @property
+    def bytes_used(self):
+        """Device bytes of the blocks requests hold (per device)."""
+        return self.num_used * self.block_bytes
 
     @property
     def num_shared(self):
@@ -665,6 +679,19 @@ class ContinuousBatchingEngine:
     (priority 0, no deadlines, shedding off) is bit-identical to the
     pre-resilience engine.
 
+    Window layers: an engine whose block description has window layers
+    (`engine.window`) is served with TWO block tables a sequence. The
+    full-attention layers keep every block (`tables`, `allocator`: what
+    `num_blocks` sizes and admission reserves); the window layers hold
+    the blocks their window touches (`window_tables`,
+    `window_allocator`, sized here from `max_batch`, `prefill_chunk`,
+    the window and the block size) and give the others back to their
+    own pool as the sequence grows. The step gets both tables side by
+    side in one array and builds the window layers' work list on the
+    device at a length the bucket bounds, so the (work-list length,
+    chunk width) compile keys are untouched. `prefix_cache=True` and
+    `spec_k > 0` are refused with window layers.
+
     Tensor-parallel serving: hand in an engine built with ``tp > 1``
     and the SAME scheduler drives the whole device mesh — admission,
     chunk budgeting, spec accept/rewind, prefix matching, and
@@ -728,8 +755,44 @@ class ContinuousBatchingEngine:
         self.max_blocks = engine.max_seq_len // self.block_size
         if self.max_blocks < 1:
             raise ValueError("block_size larger than engine.max_seq_len")
-        self.allocator = BlockAllocator(num_blocks)
-        self.caches = engine.new_paged_caches(num_blocks, self.block_size)
+        # A model with window layers keeps TWO block tables a sequence:
+        # `tables` and `allocator` for the layers that attend over
+        # everything and keep every block (what `num_blocks` sizes, and
+        # what admission reserves), `window_tables` and
+        # `window_allocator` for the window layers, which hold only the
+        # blocks their window touches and give the others back to their
+        # own pool as the sequence grows (`_slide_window`). That pool is
+        # sized here so that it can never run out: every slot's widest
+        # span, plus the parking block.
+        self.window = engine.window
+        if self.window and prefix_cache:
+            raise ValueError(
+                "prefix_cache=True with window layers: a cached prefix's "
+                "window-layer blocks were given back behind the window, "
+                "so mapping it would attend over nothing (ROADMAP M3)")
+        if self.window and self.spec_k:
+            raise ValueError(
+                "spec_k > 0 with window layers: a rejected draft span "
+                "rewinds one block table; the window layers' table and "
+                "the blocks it gave back are not rewound (ROADMAP M3, "
+                "M6)")
+        block_bytes = engine.kv_device_block_bytes
+        self.allocator = BlockAllocator(
+            num_blocks, block_bytes=block_bytes(self.block_size))
+        self.window_allocator = self.window_tables = None
+        if self.window:
+            self.window_allocator = BlockAllocator(
+                1 + self.max_batch * window_entries(
+                    self.prefill_chunk, self.window, self.block_size),
+                block_bytes=block_bytes(self.block_size, 1))
+            self.window_tables = np.zeros(
+                (self.max_batch, self.max_blocks), np.int32)
+            self.caches = engine.new_paged_caches(
+                num_blocks, self.block_size,
+                window_blocks=self.window_allocator.num_blocks)
+        else:
+            self.caches = engine.new_paged_caches(num_blocks,
+                                                  self.block_size)
         self.tables = np.zeros((self.max_batch, self.max_blocks), np.int32)
         self.lens = np.zeros(self.max_batch, np.int32)
         self.slots = [None] * self.max_batch
@@ -816,10 +879,16 @@ class ContinuousBatchingEngine:
         # submit()) like any scheduler bug would.
         self.on_token = None
         self.on_terminal = None
-        kvh = self.caches[0].shape[1]
+        # query heads per kv head, of the full layers (whose work list
+        # the host builds and counts rows of) and of the layer kind with
+        # the fewest: the pack that fills a sublane tile for that kind
+        # fills it for every kind, and one pack serves all work lists
         num_q = engine.num_heads
-        self._group_q = num_q // kvh
-        self._pack = default_pack(self.max_batch, self._group_q)
+        groups = [num_q // (sp.kv_heads or num_q)
+                  for sp in engine.layer_specs]
+        kvh = num_q // groups[0]
+        self._group_q = groups[0]
+        self._pack = default_pack(self.max_batch, min(groups))
         # committed autotune winners (ops/pallas/autotune.py): passing a
         # cache (path or dict) opts the scheduler into the swept
         # (pack, prefill_chunk) for this EXACT shape class — resolved
@@ -860,8 +929,9 @@ class ContinuousBatchingEngine:
         # `_finishing` the requests whose last token is in that step:
         # their slots and blocks went back by count, their records are
         # made when the token lands.
-        self._inputs = (_StepInputs(self.max_batch, self.max_blocks),
-                        _StepInputs(self.max_batch, self.max_blocks))
+        width = self.max_blocks * (2 if self.window else 1)
+        self._inputs = (_StepInputs(self.max_batch, width),
+                        _StepInputs(self.max_batch, width))
         self._attn_buf = np.zeros(self.max_batch, np.int32)
         self._flight = None
         self._sampled = engine.new_sampled(self.max_batch)
@@ -1093,6 +1163,10 @@ class ContinuousBatchingEngine:
         req = self.slots[i]
         self.allocator.free(req.blocks)
         req.blocks = []
+        if self.window:
+            self.window_allocator.free(req.window_blocks.values())
+            req.window_blocks = {}
+            self.window_tables[i] = 0
         self.slots[i] = None
         self.tables[i] = 0
         self.lens[i] = 0
@@ -1405,6 +1479,7 @@ class ContinuousBatchingEngine:
             self.queue.remove(req)
             reserved += need
             req.blocks = []
+            req.window_blocks = {}      # block position -> window block
             req.progress = 0
             req.cached_prefix = 0
             req._prefix_key = None
@@ -1735,6 +1810,8 @@ class ContinuousBatchingEngine:
                     req.blocks.append(blk)
                     self.tables[i, len(req.blocks) - 1] = blk
                     self._dirty_slot(i)
+                if self.window and q_lens[i]:
+                    self._slide_window(i, int(q_lens[i]))
                 return
             except KVAllocFailure:
                 # the allocator's exhaustion type ONLY: a device-side
@@ -1747,6 +1824,28 @@ class ContinuousBatchingEngine:
                     return
                 self._preempt_slot(victim, "kv_alloc", q_lens=q_lens,
                                    drafts=drafts)
+
+    def _slide_window(self, i, n):
+        """Slot i's window-layer blocks for a step of n tokens from
+        `lens[i]`: the blocks `window_span` gives are held (fresh ones
+        for the positions the step writes), every block before them goes
+        back to the window pool: no later query of this sequence sees
+        it. A block given back while the step in flight still reads it
+        may be granted to this very step: the device runs steps in
+        order, so the new holder's writes follow the old one's reads
+        (`_vacate_slot`). The pool holds every slot's widest span, so
+        `alloc` cannot fail."""
+        req = self.slots[i]
+        lo, hi = window_span(np, int(self.lens[i]), n, self.window,
+                             self.block_size)
+        held = req.window_blocks
+        for at in [at for at in held if at < lo]:
+            self.window_allocator.free([held.pop(at)])
+            self.window_tables[i, at] = 0
+        for at in range(int(lo), min(int(hi), self.max_blocks - 1) + 1):
+            if at not in held:
+                held[at] = self.window_allocator.alloc()
+                self.window_tables[i, at] = held[at]
 
     def step(self):
         """One scheduler tick. Builds and dispatches the next compiled
@@ -1948,12 +2047,34 @@ class ContinuousBatchingEngine:
         q_arr[:] = q_lens
         attn_lens = self._attn_buf
         np.add(self.lens, q_arr, out=attn_lens)
-        work, _, t_total, pack = self._work_builder.build(
+        work, t_real, t_total, pack = self._work_builder.build(
             self.tables, attn_lens, q_arr)
+        if self.window:
+            # by block table (full, window): the blocks held for this
+            # step and the work entries one layer's kernel call visits
+            lo, hi = window_span(np, self.lens, q_arr, self.window,
+                                 self.block_size)
+            fl.held = (self.allocator.num_used,
+                       self.window_allocator.num_used)
+            fl.visited = (t_real, int(((np.minimum(hi, self.max_blocks - 1)
+                                        - lo + 1) * (q_arr > 0)).sum()))
+            # (query, key) pairs a layer of each kind needs for the
+            # step's tokens: the query at lens + j sees lens + j + 1
+            # positions, a window layer's the last `window` of them
+            n, at = q_arr.astype(np.int64), self.lens.astype(np.int64)
+            every = n * at + n * (n + 1) // 2
+            short = at + 1 - self.window        # keys past the window at j=0
+            seen = np.maximum(n - np.maximum(-short, 0), 0)
+            cut = seen * np.maximum(short, 0) + seen * (seen - 1) // 2
+            fl.pairs = (int(every.sum()), int((every - cut).sum()))
         if self._host_debug:
             self._check_host_state(attn_lens, q_arr, work, t_total, pack)
         work = ins.work(work, t_total)
-        np.copyto(ins.tables, self.tables)
+        if self.window:
+            np.copyto(ins.tables[:, :self.max_blocks], self.tables)
+            np.copyto(ins.tables[:, self.max_blocks:], self.window_tables)
+        else:
+            np.copyto(ins.tables, self.tables)
         np.copyto(ins.lens, self.lens)
         fl.snapshot = None
         if self._host_debug:
@@ -2224,6 +2345,32 @@ class ContinuousBatchingEngine:
                 attn.labels(kind="visited").inc(rows_visited)
             if emitted:
                 _metrics.serve_tokens_total().inc(emitted)
+            if self.window:
+                held = _metrics.serve_kv_block_steps()
+                visited = _metrics.serve_attn_entries()
+                pairs = _metrics.serve_attn_pairs()
+                held.labels(kind="full").inc(fl.held[0])
+                held.labels(kind="window").inc(fl.held[1])
+                visited.labels(kind="full").inc(fl.visited[0])
+                visited.labels(kind="window").inc(fl.visited[1])
+                pairs.labels(kind="full").inc(fl.pairs[0])
+                pairs.labels(kind="window").inc(fl.pairs[1])
+            experts = self.engine.expert_specs
+            if experts:
+                # the routers' counts came with the samples, in the
+                # columns past them: assignments on held experts, and
+                # held experts that got one, over the expert layers. The
+                # grouped product visits no tile of an expert without a
+                # row, so the experts it computed are the touched ones
+                here, touched = (int(x) for x in toks2[
+                    :, min(fl.c, 1 + self.spec_k):].T.reshape(-1)[:2])
+                routed = _metrics.serve_moe_assignments()
+                routed.labels(where="here").inc(here)
+                routed.labels(where="elsewhere").inc(
+                    fl.live * sum(ex.top_k for ex in experts) - here)
+                multiplied = _metrics.serve_moe_experts()
+                multiplied.labels(state="touched").inc(touched)
+                multiplied.labels(state="computed").inc(touched)
 
     def _rewind_blocks(self, i, new_end):
         """Host half of the speculative rewind: shrink slot i's block

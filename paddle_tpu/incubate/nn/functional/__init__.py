@@ -414,7 +414,14 @@ def _rms(h, eps, scale=None):
 
 
 def _apply_rope_pair(q, k, cos, sin, neox):
-    """q/k: [B, S, H, D]; cos/sin broadcastable [B, S, 1, D]."""
+    """q/k: [B, S, H, D]; cos/sin broadcastable [B, S, 1, D], or
+    narrower: a table of R < D columns rotates the first R dimensions of
+    each head and passes the rest through."""
+    rd = cos.shape[-1]
+    if rd < q.shape[-1]:
+        qr, kr = _apply_rope_pair(q[..., :rd], k[..., :rd], cos, sin, neox)
+        return (jnp.concatenate([qr, q[..., rd:]], -1),
+                jnp.concatenate([kr, k[..., rd:]], -1))
     if neox:
         half = q.shape[-1] // 2
 
@@ -443,6 +450,86 @@ class _LayerWeights(typing.NamedTuple):
     f1_b: object
     f2: object
     f2_b: object
+    router: object = None       # [E, n_routed] where the layer has experts
+    router_b: object = None     # [n_routed]: selects, does not weigh
+    sink: object = None         # [H] where the layer's softmax has a sink
+
+
+class ExpertSpec(typing.NamedTuple):
+    """A layer's routed experts as one device sees them: the router
+    scores all `n_routed` and picks `top_k` a token; this device holds
+    experts lo .. lo + held - 1 and computes their share of the sum."""
+    n_routed: int
+    top_k: int
+    lo: int
+    held: int
+
+
+class LayerSpec(typing.NamedTuple):
+    """One decoder layer's block description: attention kind x
+    feed-forward kind x which cache. `fused_multi_transformer`'s loop
+    reads every per-layer choice off it; the op's own global arguments
+    (`gqa_group_size`, `activation`, one `rotary_embs`) are the
+    description with one kind of layer. Which block table a layer's
+    paged cache lives by follows from its attention: 0 for the layers
+    that keep every block, 1 for window layers (`table`)."""
+    kv_heads: int = 0           # 0: as many as there are query heads
+    head_dim: int = 0           # q/k width; needed where v_head_dim is set
+    v_head_dim: int = 0         # 0: the q/k width
+    window: typing.Optional[int] = None     # None: full causal attention
+    sink: bool = False          # a learned logit per head in the softmax
+    rope: typing.Optional[int] = 0  # which rotary table; None: no rope
+    value_scale: float = 1.0
+    activation: str = "gelu"    # of the dense feed-forward, or the experts'
+    experts: typing.Optional[ExpertSpec] = None     # None: a dense FFN
+
+    @property
+    def table(self):
+        return 1 if self.window else 0
+
+
+def expert_ffn(z, router, router_b, w13, w2, ex, live, act):
+    """Routed experts over the rows z [R, E], this device's share: the
+    router scores all ex.n_routed experts in float32 (sigmoid), the
+    ex.top_k largest of score + bias are a token's experts, weighed by
+    their scores normalised over ALL of them; the assignments that fall
+    on a held expert are grouped by expert and multiplied with a grouped
+    matrix product over the held experts' stacked weights (w13
+    [held, E, 2F] gate|up, w2 [held, F, E]); the weighted partial sum
+    comes back per row. Rows that are not `live` route nowhere. Also
+    returns how many assignments each held expert got, [held] int32.
+
+    Rows of the grouped product: R x top_k, the live assignments on held
+    experts sorted to the front; XLA's grouped product visits only the
+    row tiles its group sizes cover, and no tile of an empty group."""
+    r = z.shape[0]
+    with jax.named_scope("moe_route"):
+        sigma = jax.nn.sigmoid(jnp.dot(
+            z.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, sel = jax.lax.top_k(sigma + router_b.astype(jnp.float32),
+                               ex.top_k)
+        wt = jnp.take_along_axis(sigma, sel, axis=1)
+        wt = wt / jnp.sum(wt, axis=1, keepdims=True)
+        local = sel - ex.lo
+        here = (local >= 0) & (local < ex.held) & live[:, None]
+        eid = jnp.where(here, local, ex.held).reshape(-1)
+    with jax.named_scope("moe_experts"):
+        order = jnp.argsort(eid, stable=True)
+        counts = jnp.sum(eid[:, None] == jnp.arange(ex.held)[None, :],
+                         axis=0, dtype=jnp.int32)
+        tok = order // ex.top_k
+        gu = jax.lax.ragged_dot(z[tok], w13.astype(z.dtype), counts)
+        f = gu.shape[-1] // 2
+        y = jax.lax.ragged_dot(
+            (act(gu[:, :f]) * gu[:, f:]).astype(z.dtype),
+            w2.astype(z.dtype), counts,
+            preferred_element_type=jnp.float32)
+        # rows past the groups are not the product's to write
+        y = jnp.where((jnp.arange(eid.shape[0]) < jnp.sum(counts))[:, None],
+                      y * wt.reshape(-1)[order][:, None], 0.0)
+        out = jnp.zeros((r, y.shape[1]), jnp.float32).at[tok].add(y)
+    return out.astype(z.dtype), counts
 
 
 def fused_multi_transformer(
@@ -456,8 +543,10 @@ def fused_multi_transformer(
         trans_qkvw=True, ring_id=-1, norm_type="layernorm",
         use_neox_rotary_style=False, gqa_group_size=-1, name=None,
         block_tables=None, ragged_work=None, ragged_pack=None,
-        chunk_lens=None, kv_buffer_depth=2, _dequant=None, _mm=None,
-        _tp_reduce=None, _live_rows=None):
+        chunk_lens=None, kv_buffer_depth=2, layers=None,
+        router_weights=None, router_biases=None, attn_sinks=None,
+        _dequant=None, _mm=None, _tp_reduce=None, _live_rows=None,
+        _expert_counts=None):
     """Whole-decoder-stack fused transformer (reference
     fused_multi_transformer op: python/paddle/incubate/nn/functional/
     fused_transformer.py:1053 over
@@ -500,6 +589,22 @@ def fused_multi_transformer(
     (ops/pallas/paged_attention.py `live_rows`). A caller that has the
     packing already (`_live_rows`, the engine's paged step) passes x and
     takes the result as the packed [1, R, E] buffer itself.
+
+    `layers` (a `LayerSpec` per layer) is the per-layer block
+    description a model whose layers differ brings: kv-head count, value
+    width, window, sink, which rotary table (`rotary_embs` is then a
+    tuple of tables), value scale, activation, routed experts. Where
+    some layers have a window, `block_tables` is two tables side by
+    side, [B, 2 x max_blocks]: the full layers', then the window
+    layers', whose work list is built on the device from their own
+    (`ops/pallas/paged_attention.window_work`).
+    Without it the global arguments describe every layer. A layer with
+    experts takes `router_weights[l]` [E, n_routed], `router_biases[l]`
+    and stacked expert weights in ffn1_weights[l] [held, E, 2F] /
+    ffn2_weights[l] [held, F, E]; a layer with a sink `attn_sinks[l]`
+    [H]; a qkv weight of two dimensions is q, k and v rows of unequal
+    widths, [(H + G) x head_dim + G x v_head_dim, E]. Window layers,
+    experts and unequal widths run on the paged path only.
 
     Returns the output hidden states [B, S, E]; caches are updated
     in place (dygraph reference semantics).
@@ -551,8 +656,29 @@ def fused_multi_transformer(
                     f"list (built with pack={ragged_work[3]})")
             ragged_pack = ragged_work[3]
             ragged_work = ragged_work[0]
-    G = gqa_group_size if gqa_group_size and gqa_group_size > 0 else 0
     n_layers = len(qkv_weights)
+    if layers is None:
+        layers = (LayerSpec(
+            kv_heads=gqa_group_size
+            if gqa_group_size and gqa_group_size > 0 else 0,
+            activation=activation),) * n_layers
+    layers = tuple(layers)
+    if len(layers) != n_layers:
+        raise ValueError(
+            f"fused_multi_transformer: {len(layers)} layer descriptions "
+            f"for {n_layers} layers")
+    if block_tables is None and any(
+            sp.window or sp.experts or sp.v_head_dim or sp.sink
+            for sp in layers):
+        raise NotImplementedError(
+            "fused_multi_transformer: window layers, sinks, routed "
+            "experts and values narrower than keys run on the paged "
+            "path only (ROADMAP M1): pass block_tables")
+    n_tables = 1 + max(sp.table for sp in layers)
+    if len({sp.window for sp in layers if sp.window}) > 1:
+        raise ValueError(
+            "fused_multi_transformer: window layers of ONE window size "
+            "share the second block table (ROADMAP M3)")
     caches_in = cache_kvs if cache_kvs is not None else []
     pre_in = pre_caches if pre_caches is not None else []
     dq = _dequant or (lambda w, kind, li: w)
@@ -570,7 +696,7 @@ def fused_multi_transformer(
 
     def impl(xa, lns, lnb, qkvw, qkvb, linw, linb, flns, flnb, f1w, f1b,
              f2w, f2b, caches, pres, rotary, tstep, mask, slens, qlens,
-             tables_a, rwork, dkeys, rows):
+             tables_a, rwork, dkeys, rows, extras):
         b, s, e = xa.shape
         norm = (lambda h, sc, bi: _rms(h, epsilon, sc)) \
             if norm_type == "rmsnorm" else \
@@ -581,17 +707,45 @@ def fused_multi_transformer(
             return _LayerWeights(*(
                 xs[li] if xs else None
                 for xs in (lns, lnb, qkvw, qkvb, linw, linb, flns, flnb,
-                           f1w, f1b, f2w, f2b)))
+                           f1w, f1b, f2w, f2b, extras["router"],
+                           extras["router_b"], extras["sink"])))
+
+        def table_of(sp):
+            """The layer's rotary table: `rotary_embs` itself, or the
+            one of several its description names."""
+            if rotary is None or sp.rope is None:
+                return None
+            return rotary[sp.rope] if isinstance(rotary, (list, tuple)) \
+                else rotary
 
         # The layer's row-wise halves, over any [b, s] of rows: the whole
         # slab, or one tile of a wide paged step's packed rows ([1,
         # ROW_TILE]). Attention, between them, is what knows sequences.
         # They take the layer's weights as `lw` and its index only for
         # the quantized engines' hooks.
-        def project(h, lw, li):
+        def project(h, lw, li, sp):
             """norm + qkv projection + bias: h [b, s, E] -> q [b, s, H, D]
-            and k, v [b, s, KVH, D]."""
+            and k, v [b, s, KVH, D] (v [b, s, KVH, Dv] where the layer's
+            values are narrower)."""
+            G = sp.kv_heads
             z = norm(h, lw.ln, lw.ln_b) if pre_layer_norm else h
+            if lw.qkv.ndim == 2:
+                # rows of unequal widths: H q heads and G k heads of
+                # head_dim, then G v heads of v_head_dim
+                w = dq(lw.qkv, "qkv", li)
+                dk, dv = sp.head_dim, sp.v_head_dim or sp.head_dim
+                nh = (w.shape[0] - G * dv) // dk - G
+                qkv = jnp.einsum("bse,ne->bsn", z.astype(w.dtype), w)
+                q, k, v = jnp.split(qkv, [nh * dk, (nh + G) * dk], axis=-1)
+                v = v.reshape(z.shape[:2] + (G, dv))
+                if sp.value_scale != 1.0:
+                    v = v * jnp.asarray(sp.value_scale, v.dtype)
+                return (q.reshape(z.shape[:2] + (nh, dk)),
+                        k.reshape(z.shape[:2] + (G, dk)), v)
+            if sp.value_scale != 1.0 or sp.v_head_dim:
+                raise NotImplementedError(
+                    "a value scale or a value width of its own needs the "
+                    "two-dimensional qkv layout")
             if _mm is not None and trans_qkvw:
                 qkv = _mm(z.reshape(-1, e), lw.qkv, "qkv",
                           li).reshape(z.shape[:2] + _mm.qkv_out)
@@ -663,11 +817,15 @@ def fused_multi_transformer(
                 cos, sin = cos[:, :s], sin[:, :s]
             return _apply_rope_pair(q, k, cos, sin, use_neox_rotary_style)
 
-        def finish(resid, ctx, lw, li, dkey):
+        def finish(resid, ctx, lw, li, dkey, sp, live=None):
             """Output projection, residual and feed-forward: the layer's
             input `resid` [b, s, E] and its attention output ctx
-            [b, s, H, D] -> the layer's output [b, s, E]."""
+            [b, s, H, D] -> the layer's output [b, s, E], and for a layer
+            of routed experts the assignments each held expert got
+            (None otherwise). `live` [b, s] marks the rows that hold a
+            token (None: all)."""
             b, s = ctx.shape[:2]    # this call's rows, not the slab's
+            counts = None
             if _mm is not None:
                 attn = _mm(ctx.reshape(b * s, -1), lw.lin,
                            "lin", li).reshape(b, s, -1)
@@ -688,37 +846,50 @@ def fused_multi_transformer(
             with jax.named_scope("ffn"):
                 resid2 = h
                 z2 = norm(h, lw.fln, lw.fln_b) if pre_layer_norm else h
-                if _mm is not None:
-                    f1 = _mm(z2.reshape(b * s, -1), lw.f1, "f1",
-                             li).reshape(b, s, -1)
+                act = jax.nn.silu if sp.activation == "swiglu" \
+                    else jax.nn.relu if sp.activation == "relu" \
+                    else jax.nn.gelu
+                if sp.experts is not None:
+                    f2, counts = expert_ffn(
+                        z2.reshape(b * s, -1), lw.router, lw.router_b,
+                        dq(lw.f1, "f1", li), dq(lw.f2, "f2", li),
+                        sp.experts, jnp.ones(b * s, bool) if live is None
+                        else live.reshape(-1), act)
+                    f2 = f2.reshape(b, s, -1)
                 else:
-                    f1 = z2 @ dq(lw.f1, "f1", li)
-                if lw.f1_b is not None:
-                    f1 = f1 + lw.f1_b
-                if activation.endswith("glu"):
-                    a, g = jnp.split(f1, 2, axis=-1)
-                    act = jax.nn.silu if activation == "swiglu" \
-                        else jax.nn.gelu
-                    f1 = act(a) * g
-                elif activation == "relu":
-                    f1 = jax.nn.relu(f1)
-                else:
-                    f1 = jax.nn.gelu(f1)
-                if _mm is not None:
-                    f2 = _mm(f1.reshape(b * s, -1), lw.f2, "f2",
-                             li).reshape(b, s, -1)
-                else:
-                    f2 = f1 @ dq(lw.f2, "f2", li)
+                    if _mm is not None:
+                        f1 = _mm(z2.reshape(b * s, -1), lw.f1, "f1",
+                                 li).reshape(b, s, -1)
+                    else:
+                        f1 = z2 @ dq(lw.f1, "f1", li)
+                    if lw.f1_b is not None:
+                        f1 = f1 + lw.f1_b
+                    if sp.activation.endswith("glu"):
+                        a, g = jnp.split(f1, 2, axis=-1)
+                        f1 = act(a) * g
+                    else:
+                        f1 = act(f1)
+                    if _mm is not None:
+                        f2 = _mm(f1.reshape(b * s, -1), lw.f2, "f2",
+                                 li).reshape(b, s, -1)
+                    else:
+                        f2 = f1 @ dq(lw.f2, "f2", li)
                 f2 = tp_red(f2)
                 if lw.f2_b is not None:
                     f2 = f2 + lw.f2_b
                 h = resid2 * residual_alpha + f2
                 if not pre_layer_norm:
                     h = norm(h, lw.fln, lw.fln_b)
-            return h
+            return h, counts
+
+        def attend_kw(sp, lw):
+            """What the ragged kernel is told of the layer's attention."""
+            return dict(buffer_depth=kv_buffer_depth, window=sp.window,
+                        sink=lw.sink if sp.sink else None,
+                        v_dim=sp.v_head_dim or None)
 
         @functools.partial(jax.jit, static_argnums=0)
-        def packed_paged_layer(li, lw, table, tables, ln, ql, rows, work,
+        def packed_paged_layer(key, lw, table, tables, ln, ql, rows, work,
                                dkey, hp, qp, cache):
             """One layer of a WIDE paged step, over its live rows only:
             hp [R, E] holds the slab's live tokens packed at its front
@@ -733,17 +904,23 @@ def fused_multi_transformer(
             out of the kernel's own tiles (`tile_rows`). qp [R, H, D] is
             the packed q rows' buffer, any layer's.
 
-            Jitted, with everything traced among its arguments: the
-            layers of a dense engine differ in `lw` only, so one trace
-            and one lowered function serve them all (the hooks of a
-            quantized engine key their scales on `li`, which is why it
-            is static: there each layer is its own)."""
+            Jitted, with everything traced among its arguments: layers
+            of one description differ in `lw` only, so one trace and one
+            lowered function serve them all. The static `key` is (layer
+            index, description); the index is 0 for every layer except
+            under the hooks of a quantized engine, which key their
+            scales on it (there each layer is its own). `tables` and
+            `work` are the layer's own kind's. A layer of routed experts
+            groups its assignments per row tile, inside the second loop,
+            and carries the held experts' counts beside the rows; it
+            returns them last (None otherwise)."""
+            li, sp = key
             pos = ln[rows.slot] + rows.col                     # [R]
 
             def before(r0, carry):
                 qp, cache = carry
                 slot, at = row_tile(rows.slot, r0), row_tile(pos, r0)
-                q, k, v = project(row_tile(hp, r0)[None], lw, li)
+                q, k, v = project(row_tile(hp, r0)[None], lw, li, sp)
                 q, k = rope(q, k, table, (slot, at))
                 with jax.named_scope("kv_write"):
                     cache = append_paged_kv_rows(
@@ -756,20 +933,31 @@ def fused_multi_transformer(
                 tiles = ragged_attention_tiles(
                     qp[rows.back], cache,
                     (work, None, work[0].shape[0], ragged_pack),
-                    buffer_depth=kv_buffer_depth)
+                    **attend_kw(sp, lw))
 
-            def after(r0, hp):
+            def after(r0, carry):
+                hp, counts = carry if sp.experts else (carry, None)
                 with jax.named_scope("attention"):
+                    slot, col = row_tile(rows.slot, r0), row_tile(rows.col, r0)
+                    live = row_tile(rows.live, r0)
                     ctx = tile_rows(
-                        tiles, row_tile(rows.slot, r0),
-                        row_tile(rows.col, r0), row_tile(rows.live, r0),
-                        ragged_pack, rows.back.shape[1], *qp.shape[1:])
-                return put_row_tile(hp, finish(
+                        tiles, slot, col, live, ragged_pack,
+                        rows.back.shape[1], qp.shape[1],
+                        sp.v_head_dim or qp.shape[2])
+                out, got = finish(
                     row_tile(hp, r0)[None], ctx.astype(hp.dtype)[None],
                     lw, li, None if dkey is None
-                    else jax.random.fold_in(dkey, r0))[0], r0)
+                    else jax.random.fold_in(dkey, r0), sp,
+                    live[None] if sp.experts else None)
+                hp = put_row_tile(hp, out[0], r0)
+                return (hp, counts + got) if sp.experts else hp
 
-            return over_row_tiles(rows.n_tiles, after, hp), qp, cache
+            if sp.experts:
+                hp, counts = over_row_tiles(
+                    rows.n_tiles, after,
+                    (hp, jnp.zeros(sp.experts.held, jnp.int32)))
+                return hp, qp, cache, counts
+            return over_row_tiles(rows.n_tiles, after, hp), qp, cache, None
 
         padded = False
         if tables_a is not None:
@@ -778,7 +966,34 @@ def fused_multi_transformer(
                 live_rows, over_row_tiles, put_row_tile,
                 ragged_attention_tiles, ragged_paged_attention, row_tile,
                 tile_rows)
+            from ....ops.pallas.paged_attention import window_work
             padded = rows is None and b * s > ROW_TILE
+            # a block table per cache kind, side by side in one array
+            tabs = jnp.split(tables_a, n_tables, axis=1) \
+                if n_tables > 1 else [tables_a]
+            ln = jnp.asarray(slens).reshape(-1)
+            ql = jnp.asarray(qlens).reshape(-1)
+            width = s if rows is None else rows.back.shape[1]
+            works = {}
+
+            def work_of(sp, cache):
+                """The layer's work list: the host's, or for a window
+                layer one built here from its own table, once a step for
+                every layer of its kind."""
+                if sp.window is None:
+                    return tuple(rwork)
+                k = (sp.window, sp.table)
+                if k not in works:
+                    works[k] = window_work(
+                        tabs[sp.table], ln, ql, window=sp.window,
+                        block_size=cache.shape[3], chunk=width,
+                        pack=ragged_pack)
+                return works[k]
+
+        def count(got):
+            if got is not None and _expert_counts is not None:
+                _expert_counts.append(got)
+
         if padded:
             # a wide slab handed over as [B, C, E]: packed here, and
             # handed back in the slab's geometry
@@ -788,30 +1003,31 @@ def fused_multi_transformer(
         if rows is not None:
             # a wide paged step: xa is [1, R, E], the packed live rows
             q_rows = jax.eval_shape(
-                lambda z: project(z, layer(0), 0)[0], xa)
+                lambda z: project(z, layer(0), 0, layers[0])[0], xa)
             hp, qp = xa[0], jnp.zeros(q_rows.shape[1:], q_rows.dtype)
-            ln = jnp.asarray(slens).reshape(-1)
-            ql = jnp.asarray(qlens).reshape(-1)
-            for li in range(n_layers):
-                hp, qp, cache = packed_paged_layer(
-                    li if _dequant or _mm else 0, layer(li), rotary,
-                    tables_a, ln, ql, rows, tuple(rwork),
+            for li, sp in enumerate(layers):
+                hp, qp, cache, got = packed_paged_layer(
+                    (li if _dequant or _mm else 0, sp), layer(li),
+                    table_of(sp), tabs[sp.table], ln, ql, rows,
+                    work_of(sp, caches[li]),
                     dkeys[li] if dkeys else None, hp, qp, caches[li])
                 new_caches.append(cache)
+                count(got)
             return tuple([hp[rows.back] if padded else hp[None]]
                          + new_caches)
         h = xa
-        for li in range(n_layers):
+        live = None
+        for li, sp in enumerate(layers):
             lw = layer(li)
             resid = h
-            q, k, v = project(h, lw, li)
-            q, k = rope(q, k, rotary)
+            q, k, v = project(h, lw, li, sp)
+            q, k = rope(q, k, table_of(sp))
             nh, hd = q.shape[2:]
             scale = 1.0 / math.sqrt(hd)
             # grouped-attention geometry: kv heads g, queries-per-group r
             # (r == 1 and g == nh for MHA; the einsums below serve both —
             # no jnp.repeat materialisation of KV on the decode hot path)
-            g_eff = G or nh
+            g_eff = sp.kv_heads or nh
             r = nh // g_eff
             if tstep is not None and caches and tables_a is not None:
                 # paged step (continuous batching): append this step's
@@ -831,17 +1047,17 @@ def fused_multi_transformer(
                 # (On the v5e the chunk writer's fused scatter keeps no
                 # op metadata, so the trace shows it under no scope.)
                 cache = caches[li]             # [2, KVH, NB, BS, D]
-                ln = jnp.asarray(slens).reshape(-1)
-                work = (tuple(rwork), None, rwork[0].shape[0], ragged_pack)
-                ql = jnp.asarray(qlens).reshape(-1)
+                work = work_of(sp, cache)
+                work = (work, None, work[0].shape[0], ragged_pack)
+                if sp.experts:  # the rows its router may send anywhere
+                    live = jnp.arange(s)[None, :] < ql[:, None]   # [B, C]
                 with jax.named_scope("kv_write"):
                     cache = append_paged_kv_chunk(
-                        cache, k, v, tables_a, ln, ql)
+                        cache, k, v, tabs[sp.table], ln, ql)
                 with jax.named_scope("attention"):
                     ctx = ragged_paged_attention(
-                        q, cache, tables_a, ln + ql, scale=scale,
-                        work=work, q_lens=ql,
-                        buffer_depth=kv_buffer_depth
+                        q, cache, tabs[sp.table], ln + ql, scale=scale,
+                        work=work, q_lens=ql, **attend_kw(sp, lw)
                         ).astype(xa.dtype)                # [B, C, H, D]
                 new_caches.append(cache)
             elif tstep is not None and caches:
@@ -923,7 +1139,9 @@ def fused_multi_transformer(
                     vc = jax.lax.dynamic_update_slice_in_dim(
                         cache[1], vv.transpose(0, 2, 1, 3), 0, axis=2)
                     new_caches.append(jnp.stack([kc, vc]))
-            h = finish(resid, ctx, lw, li, dkeys[li] if dkeys else None)
+            h, got = finish(resid, ctx, lw, li,
+                            dkeys[li] if dkeys else None, sp, live)
+            count(got)
         return tuple([h] + new_caches)
 
     out = apply_op(
@@ -939,7 +1157,10 @@ def fused_multi_transformer(
          # per-layer dropout keys as input leaves (vjp-cacheable +
          # trace-safe, like the other fused ops)
          [_random.fresh_key_tensor() for _ in range(n_layers)]
-         if training and dropout_rate else [], _live_rows),
+         if training and dropout_rate else [], _live_rows,
+         dict(router=list(router_weights or []),
+              router_b=list(router_biases or []),
+              sink=list(attn_sinks or []))),
         {}, differentiable=bool(training) and not caches_in)
     outs = out if isinstance(out, tuple) else (out,)
     h = outs[0]

@@ -420,6 +420,11 @@ __all__ += ["DataType", "PlaceType", "Tensor", "PredictorPool", "XpuConfig",
             "convert_to_mixed_precision", "_get_phi_kernel_name"]
 
 
+# what a step of a model with routed experts sends out beside its samples,
+# in columns past them, summed over the expert layers
+ROUTER_STATS = ("assignments_here", "experts_touched")
+
+
 class FusedMultiTransformerEngine:
     """Serving engine over the fused_multi_transformer op (role of the
     reference's fused_multi_transformer-based inference stack:
@@ -430,7 +435,20 @@ class FusedMultiTransformerEngine:
 
     weights: dict with keys matching fused_multi_transformer's list args
     (ln_scales, qkv_weights, ...), plus 'embedding' [V, E] and 'lm_head'
-    [E, V]. All values may be paddle Tensors or jax arrays.
+    [E, V]. All values may be paddle Tensors or jax arrays. A
+    'final_norm_scale' [E], where the dict has one, is the norm applied
+    before the head.
+
+    ``layers`` is the per-layer block description of a model whose
+    layers differ (a dict or `LayerSpec` per layer: kv-head count, value
+    width, window, sink, rotary table, value scale, activation, routed
+    experts; `incubate.nn.functional.LayerSpec`). The paged
+    step is compiled from it; without it `gqa_group_size` and
+    `activation` describe every layer, which is the description with one
+    kind of layer. A model with window layers keeps two block tables a
+    sequence (every block for its full layers; the blocks the window
+    touches for its window layers), its caches have one shape per
+    layer, and it serves through the paged path only.
 
     ``tp > 1`` shards the PAGED serving path over a one-axis tensor-
     parallel device mesh (inference/tp_layout.py): qkv/ffn1 weights
@@ -453,13 +471,16 @@ class FusedMultiTransformerEngine:
                  norm_type="layernorm", activation="gelu",
                  use_neox_rotary_style=False, dtype="bfloat16",
                  gqa_group_size=-1, weight_quant=None, tp=1,
-                 kv_buffer_depth=None, autotune_cache=None):
+                 kv_buffer_depth=None, autotune_cache=None, layers=None):
         import jax
         import jax.numpy as jnp
-        from ..incubate.nn.functional import fused_multi_transformer
+        from ..incubate.nn.functional import (ExpertSpec, LayerSpec,
+                                              fused_multi_transformer)
 
         def arr(v):
             from ..core.tensor import Tensor as _T
+            if v is None:       # a layer without that tensor
+                return None
             a = v.data if isinstance(v, _T) else jnp.asarray(v)
             return a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) \
                 else a
@@ -475,9 +496,32 @@ class FusedMultiTransformerEngine:
         # the cache is allocated at the kv-head count
         self._gqa = gqa_group_size if gqa_group_size and gqa_group_size > 0 \
             else 0
-        kw = dict(norm_type=norm_type, activation=activation,
-                  use_neox_rotary_style=use_neox_rotary_style,
-                  gqa_group_size=gqa_group_size)
+        # the per-layer block description the layer loop reads: given,
+        # or the one kind of layer the global arguments describe
+        if layers is None:
+            specs = (LayerSpec(kv_heads=self._gqa,
+                               activation=activation),) * self._n_layers
+        else:
+            specs = tuple(
+                sp if isinstance(sp, LayerSpec) else LayerSpec(**dict(
+                    sp, head_dim=head_dim, experts=ExpertSpec(**sp["experts"])
+                    if sp.get("experts") else None))
+                for sp in layers)
+            if len(specs) != self._n_layers:
+                raise ValueError(
+                    f"{len(specs)} layer descriptions for "
+                    f"{self._n_layers} layers of weights")
+        self.layer_specs = specs
+        windows = {sp.window for sp in specs if sp.window}
+        if len(windows) > 1:
+            raise ValueError(
+                "a model keeps at most two block tables a sequence: one "
+                "for its full-attention layers, one for window layers "
+                f"of ONE window size, not {sorted(windows)} (ROADMAP M3)")
+        # the window its window layers attend over, None without any
+        self.window = windows.pop() if windows else None
+        kw = dict(norm_type=norm_type, layers=specs,
+                  use_neox_rotary_style=use_neox_rotary_style)
         # tensor-parallel serving (tp_layout.py): weights repacked +
         # device_put onto a one-axis mesh, and the paged programs below
         # become shard_map'd mesh programs. paged_kw is the PER-DEVICE
@@ -498,6 +542,16 @@ class FusedMultiTransformerEngine:
             from jax.sharding import Mesh
             from ..ops.pallas.paged_attention import kv_head_shard
             from .tp_layout import validate_tp
+            if any(sp.experts for sp in specs):
+                raise ValueError(
+                    "tp > 1 with routed experts: inference/tp_layout.py "
+                    "knows column/row splits and kv-head shards, no "
+                    "expert placement (ROADMAP M2)")
+            if len({sp.kv_heads for sp in specs}) > 1 or self.window:
+                raise ValueError(
+                    "tp > 1 with two kinds of attention layer: "
+                    "inference/tp_layout.py shards one kv-head count "
+                    "(ROADMAP M3)")
             kvh_n = self._gqa or num_heads
             ffn_dim = int(self._w["ffn2_weights"][0].shape[0])
             validate_tp(num_heads, kvh_n, ffn_dim, self.tp)
@@ -509,8 +563,10 @@ class FusedMultiTransformerEngine:
                     f"have {len(devs)}")
             self._mesh = Mesh(_np.array(devs[:self.tp]), ("tp",))
             paged_kw = dict(kw)
-            if self._gqa:
-                paged_kw["gqa_group_size"] = self._gqa // self.tp
+            if self._gqa:       # each device's share of the kv heads
+                paged_kw["layers"] = tuple(
+                    sp._replace(kv_heads=sp.kv_heads // self.tp)
+                    for sp in specs)
             paged_kw["_tp_reduce"] = lambda x: jax.lax.psum(x, "tp")
             if weight_quant == "int4":
                 # the row-parallel specs split the PACKED nibble axis
@@ -585,19 +641,21 @@ class FusedMultiTransformerEngine:
                 # token-exact vs the dense weight_quant generate()
                 self._w["qkv_weights"] = _quant(
                     "qkv", self._w["qkv_weights"], -1)
+                # the INPUT axis: the first of a [K, N] matrix, the
+                # middle one of a layer's stacked experts [held, K, N]
                 self._w["linear_weights"] = _quant(
-                    "lin", self._w["linear_weights"], 0)
+                    "lin", self._w["linear_weights"], -2)
                 self._w["ffn1_weights"] = _quant(
-                    "f1", self._w["ffn1_weights"], 0)
+                    "f1", self._w["ffn1_weights"], -2)
                 self._w["ffn2_weights"] = _quant(
-                    "f2", self._w["ffn2_weights"], 0)
+                    "f2", self._w["ffn2_weights"], -2)
                 cdt = dtype
                 if self.tp == 1:
                     def dq(w, kind, li):
                         sc = qscales[kind][li]
                         if weight_quant == "int4":
                             full = _unpack_int4(
-                                w, axis=-1 if kind == "qkv" else 0)
+                                w, axis=-1 if kind == "qkv" else -2)
                         else:
                             full = w
                         return (full.astype(jnp.float32) * sc).astype(cdt)
@@ -622,9 +680,9 @@ class FusedMultiTransformerEngine:
                         w = dict(w)
                         for key, skey, axis in (
                                 ("qkv_weights", "qkv_wscales", -1),
-                                ("linear_weights", "linear_wscales", 0),
-                                ("ffn1_weights", "ffn1_wscales", 0),
-                                ("ffn2_weights", "ffn2_wscales", 0)):
+                                ("linear_weights", "linear_wscales", -2),
+                                ("ffn1_weights", "ffn1_wscales", -2),
+                                ("ffn2_weights", "ffn2_wscales", -2)):
                             scs = w.pop(skey)
                             w[key] = [
                                 ((_unpack_int4(p, axis=axis) if is4
@@ -645,7 +703,7 @@ class FusedMultiTransformerEngine:
         self._autotune_cache = None if autotune_cache is None \
             else _autotune.load_serve_cache(autotune_cache)
         if kv_buffer_depth is None:
-            kvh_l = self._gqa or num_heads
+            kvh_l = specs[0].kv_heads or num_heads
             cfg = _autotune.serve_winner_for_engine(
                 self._autotune_cache, kvh_l, num_heads // kvh_l,
                 head_dim, dtype) if self._autotune_cache else None
@@ -660,6 +718,28 @@ class FusedMultiTransformerEngine:
                     g("qkv_biases"), w["linear_weights"], g("linear_biases"),
                     w["ffn_ln_scales"], g("ffn_ln_biases"), w["ffn1_weights"],
                     g("ffn1_biases"), w["ffn2_weights"], g("ffn2_biases"))
+
+        def per_layer(w):
+            """The per-layer tensors only some layers have (None where a
+            layer has none)."""
+            return dict(router_weights=w.get("router_weights"),
+                        router_biases=w.get("router_biases"),
+                        attn_sinks=w.get("attn_sinks"))
+
+        # the routed-expert layers' descriptions, for the scheduler's
+        # counters (its host arithmetic needs their count and top_k)
+        self.expert_specs = tuple(
+            sp.experts for sp in specs if sp.experts is not None)
+
+        def head(h, w):
+            """The final norm, where the weights have one, and the
+            untied head: h [..., E] -> logits [..., V]."""
+            g = w.get("final_norm_scale")
+            if g is not None:
+                from ..incubate.nn.functional import _ln, _rms
+                h = _rms(h, 1e-5, g) if norm_type == "rmsnorm" else \
+                    _ln(h, 1e-5, g, w.get("final_norm_bias"))
+            return h @ w["lm_head"]
 
         def select(logits, temp, topp, key):
             """Greedy when temp<=0, else temperature + nucleus (top-p)
@@ -686,13 +766,13 @@ class FusedMultiTransformerEngine:
             out = fused_multi_transformer(
                 Tensor(h), *lists(w), cache_kvs=cts,
                 seq_lens=None if lens is None else Tensor(lens),
-                rotary_embs=w.get("rotary_embs"), **kw)
+                rotary_embs=w.get("rotary_embs"), **per_layer(w), **kw)
             if lens is None:
-                logits = out.data[:, -1] @ w["lm_head"]
+                logits = head(out.data[:, -1], w)
             else:
                 # ragged prompts: each row's LAST VALID hidden state
                 bidx = jnp.arange(out.data.shape[0])
-                logits = out.data[bidx, lens - 1] @ w["lm_head"]
+                logits = head(out.data[bidx, lens - 1], w)
             return select(logits, temp, topp, key), [c.data for c in cts]
 
         def step(w, caches, tok, t, temp, topp, key, lens=None):
@@ -703,8 +783,8 @@ class FusedMultiTransformerEngine:
                 Tensor(h), *lists(w), cache_kvs=cts,
                 time_step=Tensor(t),
                 seq_lens=None if lens is None else Tensor(lens),
-                rotary_embs=w.get("rotary_embs"), **kw)
-            logits = out.data[:, 0] @ w["lm_head"]
+                rotary_embs=w.get("rotary_embs"), **per_layer(w), **kw)
+            logits = head(out.data[:, 0], w)
             return select(logits, temp, topp, key), [c.data for c in cts]
 
         def steps(w, caches, tok, t0, n, temp, topp, key, lens0=None):
@@ -762,16 +842,32 @@ class FusedMultiTransformerEngine:
             read on the device, so arguments, shapes and buckets are
             what they were. A slab of at most ROW_TILE rows is one tile
             whatever is live: straight-line code, no packing."""
+            counts = []
             logits, caches = paged_logits(w, caches, toks, qlens, sel,
-                                          tables, lens, rwork, rpack)
+                                          tables, lens, rwork, rpack,
+                                          counts)
             with jax.named_scope("sampler"):
                 toks_out = select(logits, temp, topp, key)
+            if counts:
+                # what the step's routers did rides out beside its
+                # samples, in columns past them: (assignments that fell
+                # on a held expert, held experts that got one), summed
+                # over the expert layers; the host reads one array
+                got = jnp.stack(counts)                  # [layers, held]
+                stats = jnp.stack([got.sum(), (got > 0).sum()])
+                b = toks_out.shape[0]
+                stats = jnp.pad(stats, (0, -len(ROUTER_STATS) % b))
+                toks_out = jnp.concatenate(
+                    [toks_out, stats.reshape(-1, b).T.astype(
+                        toks_out.dtype)], axis=1)
             return toks_out, caches
 
         def paged_logits(w, caches, toks, qlens, sel, tables, lens, rwork,
-                         rpack):
+                         rpack, expert_counts=None):
             """`paged_step` up to its sampler: the logits [B, W, V] at
-            the slab columns `sel` names, and the appended caches."""
+            the slab columns `sel` names, and the appended caches.
+            `expert_counts`, a list, takes each expert layer's
+            assignments per held expert."""
             from ..ops.pallas.paged_attention import (
                 ROW_TILE, live_rows, over_row_tiles, put_row_tile,
                 row_tile)
@@ -801,14 +897,15 @@ class FusedMultiTransformerEngine:
                 seq_lens=Tensor(lens), chunk_lens=Tensor(qlens),
                 rotary_embs=w.get("rotary_embs"),
                 block_tables=tables, ragged_work=rwork,
-                ragged_pack=rpack, _live_rows=rows, **paged_kw)
+                ragged_pack=rpack, _live_rows=rows,
+                _expert_counts=expert_counts, **per_layer(w), **paged_kw)
             with jax.named_scope("head"):
                 bidx = jnp.arange(toks.shape[0])[:, None]
                 if rows is None:
                     picked = out.data[bidx, sel]             # [B, W, E]
                 else:
                     picked = out.data[0][rows.back[bidx, sel]]
-                logits = picked @ w["lm_head"]               # [B, W, V]
+                logits = head(picked, w)                     # [B, W, V]
             return logits, [c.data for c in cts]
 
         def feed_tokens(slab, prev, fed):
@@ -940,12 +1037,15 @@ class FusedMultiTransformerEngine:
         return NamedSharding(self._mesh, PartitionSpec())
 
     def new_sampled(self, batch):
-        """A step's samples before any step ran: zeros [batch, 1] where
-        `_paged_step` leaves its own, for `_feed_tokens` to read when
-        no slot is fed."""
+        """A step's samples before any step ran: zeros in the shape
+        `_paged_step` leaves its own ([batch, 1], wider by the routers'
+        columns where the model has experts), for `_feed_tokens` to read
+        when no slot is fed."""
         import jax
         import jax.numpy as jnp
-        z = jnp.zeros((batch, 1), jnp.int32)
+        cols = 1 + (-(-len(ROUTER_STATS) // batch)
+                    if self.expert_specs else 0)
+        z = jnp.zeros((batch, cols), jnp.int32)
         return z if self.tp == 1 else jax.device_put(z, self._replicated())
 
     def _build_quant_mm(self, weights, dtype):
@@ -1008,13 +1108,19 @@ class FusedMultiTransformerEngine:
                            self.head_dim), dtype)
                 for _ in range(self._n_layers)]
 
-    def new_paged_caches(self, num_blocks, block_size, dtype=None):
-        """Per-layer paged KV caches [2, KVH, num_blocks, block_size, Dc]
+    def new_paged_caches(self, num_blocks, block_size, dtype=None,
+                         window_blocks=None):
+        """Per-layer paged KV caches [2, KVH, NB, block_size, Dc]
         for the continuous-batching serving path
-        (incubate.nn.ContinuousBatchingEngine owns the block allocator
-        that hands slices of these out to requests). Dc is the head dim
-        rounded up to the 128-lane tile the ragged kernel DMAs
-        (`paged_head_dim`; pad lanes stay zero). Under tp > 1 each
+        (incubate.nn.ContinuousBatchingEngine owns the block allocators
+        that hand slices of these out to requests). Each layer's shape
+        is its own description's: its kv-head count, and NB =
+        `num_blocks` for a layer of block table 0, `window_blocks` for a
+        window layer (table 1), whose pool is another. Dc is the wider
+        of the key and value widths rounded up to the 128-lane tile the
+        ragged kernel DMAs (`paged_head_dim`; pad lanes stay zero):
+        one Dc for both halves, so values narrower than the keys pay
+        the keys' lanes. Under tp > 1 each
         layer's cache is placed sharded over KV HEADS — the GLOBAL
         (logical) shape is unchanged, each device holds a
         [2, KVH/tp, num_blocks, block_size, Dc] shard, so the host-side
@@ -1023,32 +1129,39 @@ class FusedMultiTransformerEngine:
         import jax.numpy as jnp
         from ..ops.pallas.paged_attention import paged_head_dim
         dtype = dtype or self._dtype
-        kvh = self._gqa or self.num_heads
-        shape = (2, kvh, num_blocks, block_size,
-                 paged_head_dim(self.head_dim))
+        if self.window and not window_blocks:
+            raise ValueError(
+                "a model with window layers needs its window pool's size")
+        shapes = [(2, sp.kv_heads or self.num_heads,
+                   window_blocks if sp.table else num_blocks, block_size,
+                   paged_head_dim(max(self.head_dim, sp.v_head_dim)))
+                  for sp in self.layer_specs]
         if self.tp > 1:
             import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
             sh = NamedSharding(self._mesh, P(None, "tp"))
             return [jax.device_put(jnp.zeros(shape, dtype), sh)
-                    for _ in range(self._n_layers)]
-        return [jnp.zeros(shape, dtype) for _ in range(self._n_layers)]
+                    for shape in shapes]
+        return [jnp.zeros(shape, dtype) for shape in shapes]
 
     # -- tensor-parallel accounting (host math; tp == 1 degenerates) ------
-    def kv_device_block_bytes(self, block_size):
-        """Bytes ONE allocator block occupies PER DEVICE across every
-        layer's cache shard: L x 2(K,V) x KVH/tp x block_size x Dc x
-        itemsize (Dc = the lane-padded row the cache really stores).
+    def kv_device_block_bytes(self, block_size, table=0):
+        """Bytes ONE allocator block of block table `table` occupies PER
+        DEVICE across the cache shards of that table's layers: per layer
+        2(K,V) x KVH/tp x block_size x Dc x itemsize (Dc = the
+        lane-padded row the cache really stores).
         The per-device KV high-water in bytes is
         `allocator.high_water * this` — the capacity win the TP gate
         asserts (1/tp of the single-chip figure for the same
         workload)."""
         import jax.numpy as jnp
         from ..ops.pallas.paged_attention import paged_head_dim
-        kvh = self._gqa or self.num_heads
         itemsize = jnp.dtype(self._dtype).itemsize
-        return (self._n_layers * 2 * (kvh // self.tp) * int(block_size)
-                * paged_head_dim(self.head_dim) * itemsize)
+        return sum(
+            2 * ((sp.kv_heads or self.num_heads) // self.tp)
+            * int(block_size)
+            * paged_head_dim(max(self.head_dim, sp.v_head_dim)) * itemsize
+            for sp in self.layer_specs if sp.table == table)
 
     def tp_step_comm_bytes(self, batch, width, live=None):
         """Analytic per-step collective payload of the TP paged step:

@@ -127,6 +127,53 @@ def serve_attn_rows():
         labels=("kind",))      # bounded: live | visited
 
 
+def serve_moe_assignments():
+    return get_registry().counter(
+        "serve_moe_assignments_total",
+        help="(token, expert) assignments the steps' routers made, summed "
+             "over the expert layers: here (on an expert this device "
+             "holds) vs elsewhere (on one of the deployment's other "
+             "devices: not computed, nothing stands in)",
+        labels=("where",))     # bounded: here | elsewhere
+
+
+def serve_moe_experts():
+    return get_registry().counter(
+        "serve_moe_experts_total",
+        help="held experts per step and expert layer: touched (got at "
+             "least one token) vs computed (their weights went through "
+             "the grouped product) — touched over computed is the share "
+             "of the experts' weight traffic that did work",
+        labels=("state",))     # bounded: touched | computed
+
+
+def serve_kv_block_steps():
+    return get_registry().counter(
+        "serve_kv_block_steps_total",
+        help="cache blocks held, summed over steps, by block table: full "
+             "(layers that keep every block) vs window (layers that give "
+             "blocks back behind their window)",
+        labels=("kind",))      # bounded: full | window
+
+
+def serve_attn_entries():
+    return get_registry().counter(
+        "serve_attn_entries_total",
+        help="work-list entries (one cache block of one slot) a layer's "
+             "ragged kernel call visits, summed over steps, by block "
+             "table: full vs window",
+        labels=("kind",))      # bounded: full | window
+
+
+def serve_attn_pairs():
+    return get_registry().counter(
+        "serve_attn_pairs_total",
+        help="(query, key) pairs the model's attention needs for the "
+             "tokens stepped, per layer, by kind of layer: full (every "
+             "earlier position) vs window (the window's positions)",
+        labels=("kind",))      # bounded: full | window
+
+
 def dispatch_seconds():
     return get_registry().histogram(
         "dispatch_seconds",

@@ -423,6 +423,61 @@ class RaggedWorkBuilder:
         return arrs, t_real, t_total, self.pack
 
 
+def window_span(xp, lens, q_lens, window, block_size):
+    """(lo, hi): the first and the last block position a window layer's
+    step touches for each slot, whose span of q_lens queries starts at
+    position lens: the block of the first key its first query sees
+    (lens - window + 1) to the block its last query is written to. Blocks
+    before `lo` are never read again, by this step or a later one. One
+    arithmetic for the device's work list (`xp` = jnp) and the host's
+    block table (`xp` = numpy)."""
+    lo = xp.maximum(lens - (window - 1), 0) // block_size
+    hi = (lens + xp.maximum(q_lens, 1) - 1) // block_size
+    return lo, hi
+
+
+def window_entries(chunk, window, block_size):
+    """Work entries a slot can need in a window layer's list at slab
+    width `chunk`: the blocks chunk + window - 1 positions can straddle."""
+    return -(-(int(chunk) + int(window) - 1) // int(block_size)) + 1
+
+
+def window_work(block_tables, lens, q_lens, *, window, block_size, chunk,
+                pack):
+    """A window layer's work list, built on the device from the window
+    layers' block table [B, max_blocks] and the step's lens / q_lens: the
+    same nine arrays as `build_ragged_work`, at the fixed length
+    B x window_entries(chunk, window, block_size), so that a step's compile
+    key knows nothing of it. Slot-major (so group-major); a slot lists
+    the blocks `window_span` gives and pads the rest of its entries
+    (q_len 0, a block position past every length). Block positions and
+    the query start count from the slot's FIRST LISTED block, not from
+    the sequence's: the kernel starts a slot over at position 0 and its
+    masks only ever compare positions of one slot, so the shift changes
+    nothing but lets a list begin anywhere."""
+    tables = jnp.asarray(block_tables, jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32).reshape(-1)
+    ql = jnp.minimum(jnp.asarray(q_lens, jnp.int32).reshape(-1), chunk)
+    b, max_nb = tables.shape
+    nw = window_entries(chunk, window, block_size)
+    lo, hi = window_span(jnp, lens, ql, window, block_size)
+    j = jnp.arange(nw, dtype=jnp.int32)[None, :]
+    at = lo[:, None] + j                                      # [B, nw]
+    valid = (ql[:, None] > 0) & (at <= hi[:, None]) & (at < max_nb)
+    blk = jnp.take_along_axis(tables, jnp.minimum(at, max_nb - 1), axis=1)
+    slot = jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[:, None],
+                            (b, nw))
+    zero = jnp.zeros((b, nw), jnp.int32)
+    pad_pos = (1 << 30) // block_size
+    cols = (slot, slot // pack, slot % pack,
+            jnp.where(valid, blk, 0),
+            jnp.where(valid, j, pad_pos),
+            zero, zero,
+            jnp.where(valid, (lens - lo * block_size)[:, None], 0),
+            jnp.where(valid, ql[:, None], 0))
+    return tuple(c.reshape(-1).astype(jnp.int32) for c in cols)
+
+
 # Rows of one query sub-tile. A grid step multiplies only the sub-tiles
 # that hold live query rows of its own entry's slot, so the height trades
 # the rows a decode entry pays for beyond its G live ones against the
@@ -497,9 +552,15 @@ def attn_rows(work, pack, chunk, group_q, block_size):
 
 
 def _ragged_kernel(ws, wg, wr, wblk, wpos, wfirst, wlast, wqs, wql,
-                   q_ref, kv_hbm, o_ref,
-                   kbuf, vbuf, ksem, vsem, m_scr, l_scr, acc,
-                   *, block_size, scale, group_q, chunk, sub, depth=2):
+                   q_ref, kv_hbm, *refs,
+                   block_size, scale, group_q, chunk, sub, depth=2,
+                   window=None):
+    # told by its arguments: a layer with a learned sink logit per query
+    # head hands `sink_ref` [1, rows, LANES] (the row's head's logit), a
+    # window layer the static `window`; without either this is the plain
+    # causal kernel, op for op
+    sink_ref = refs[0] if len(refs) == 9 else None
+    o_ref, kbuf, vbuf, ksem, vsem, m_scr, l_scr, acc = refs[-8:]
     hh = pl.program_id(0)
     t = pl.program_id(1)
     nt = pl.num_programs(1)
@@ -514,8 +575,12 @@ def _ragged_kernel(ws, wg, wr, wblk, wpos, wfirst, wlast, wqs, wql,
         # (including a -1 free-slot sentinel) doesn't fault on TPU, it
         # reads whatever block aliases (graftlint GL301)
         blk = jnp.clip(wblk[idx], 0, kv_hbm.shape[2] - 1)
-        return pltpu.make_async_copy(
-            kv_hbm.at[half, hh, blk], buf.at[slot], sem.at[slot])
+        src = kv_hbm.at[half, hh, blk]
+        if buf.shape[-1] != kv_hbm.shape[-1]:
+            # values narrower than the cache's rows (keys wider than
+            # values, one Dc for both): only their lane tiles move
+            src = src.at[:, pl.ds(0, buf.shape[-1])]  # graftlint: disable=GL301 - a static lane slice of the clamped block
+        return pltpu.make_async_copy(src, buf.at[slot], sem.at[slot])
 
     kdma = functools.partial(dma, 0, kbuf, ksem)
     vdma = functools.partial(dma, 1, vbuf, vsem)
@@ -611,6 +676,10 @@ def _ragged_kernel(ws, wg, wr, wblk, wpos, wfirst, wlast, wqs, wql,
             pos = wpos[t] * block_size + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
             mask = live & (pos <= wqs[t] + j)
+            if window is not None:
+                # a window layer: the query at q_start + j sees the
+                # `window` positions that end with its own
+                mask = mask & (pos > wqs[t] + j - window)
             m_prev = m_scr[rows, :1]
             m_new = jnp.maximum(
                 m_prev,
@@ -637,6 +706,13 @@ def _ragged_kernel(ws, wg, wr, wblk, wpos, wfirst, wlast, wqs, wql,
     def _final():
         def body(rows, r0):
             l = l_scr[rows, :1]
+            if sink_ref is not None:
+                # the sink logit joins the softmax's denominator and
+                # nothing else: exp(sink - m) beside the running sum
+                m = m_scr[rows, :1]
+                l = l + jnp.where(
+                    m > 0.5 * NEG_INF,
+                    jnp.exp(sink_ref[0, rows, :1] - m), 0.0)
             l = jnp.where(l == 0.0, 1.0, l)
             live, _ = live_rows_of(r0, (sub, acc.shape[1]))
             o_ref[0, 0, rows, :] = jnp.where(
@@ -685,7 +761,8 @@ def default_pack(batch, group_q):
 
 def ragged_paged_attention(q, kv_cache, block_tables, context_lens,
                            scale=None, pack=None, work=None, q_lens=None,
-                           buffer_depth=2):
+                           buffer_depth=2, window=None, sink=None,
+                           v_dim=None):
     """Mixed decode/prefill attention over a paged KV cache, ragged grid.
 
     q:            [B, H, D] — one query token per sequence (decode), or
@@ -725,7 +802,18 @@ def ragged_paged_attention(q, kv_cache, block_tables, context_lens,
                   deeper keeps more blocks in flight at depth x
                   2 x block_size x D x itemsize VMEM. Pure scheduling —
                   results are bit-identical across depths.
-    returns       [B, H, D] or [B, C, H, D], matching q
+    window:       static int or None. A window layer's query at position
+                  p sees positions p - window + 1 .. p; its work list
+                  may then hold only the blocks that window touches,
+                  with block positions and query starts counted from the
+                  slot's first listed block (`window_work`)
+    sink:         [H] learned logit per query head, or None: it joins
+                  the softmax's denominator and weighs no value
+    v_dim:        width of the values where it is not the queries' (keys
+                  wider than values in one Dc-wide cache): the output's
+                  minor dim, and the V lane tiles the kernel moves
+    returns       [B, H, D] or [B, C, H, D], matching q (minor dim v_dim
+                  where given)
     """
     buffer_depth = int(buffer_depth)
     if not 1 <= buffer_depth <= 8:
@@ -765,7 +853,7 @@ def ragged_paged_attention(q, kv_cache, block_tables, context_lens,
         work_arrs, _, t_total, pack = build_ragged_work(
             block_tables, context_lens, block_size, pack, q_lens=q_lens)
     if t_total == 0:
-        out = jnp.zeros((b, c, h, d_q), q.dtype)
+        out = jnp.zeros((b, c, h, v_dim or d_q), q.dtype)
         return out[:, 0] if squeeze else out
     # only a slot's live rows are ever written: every other row (a
     # len 0 / q_len 0 slot's, the columns past q_len) carries
@@ -777,15 +865,18 @@ def ragged_paged_attention(q, kv_cache, block_tables, context_lens,
         n_valid = jnp.asarray(q_lens).reshape(-1)
     out = _ragged_call(
         tuple(jnp.asarray(a, jnp.int32) for a in work_arrs), q, kv_cache,
-        n_valid.astype(jnp.int32), scale=float(scale), pack=pack,
-        depth=buffer_depth, interpret=_interpret_mode())
+        n_valid.astype(jnp.int32), sink, scale=float(scale), pack=pack,
+        depth=buffer_depth, interpret=_interpret_mode(), window=window,
+        v_dim=v_dim)
     return out[:, 0] if squeeze else out
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "pack", "depth", "interpret"))
-def _ragged_call(work, q, kv_cache, n_valid, *, scale, pack, depth,
-                 interpret):
+_KERNEL_STATICS = ("scale", "pack", "depth", "interpret", "window", "v_dim")
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _ragged_call(work, q, kv_cache, n_valid, sink=None, *, scale, pack,
+                 depth, interpret, window=None, v_dim=None):
     """The kernel over q [B, C, H, D], its packing and its output laid
     back into the slab, as ONE jitted function: the layers of a step
     call it with the same shapes, so one trace of the kernel's body and
@@ -793,27 +884,39 @@ def _ragged_call(work, q, kv_cache, n_valid, *, scale, pack, depth,
     would trace and lower it 16 times; the set-up of a serving process
     is mostly that)."""
     b, c, h, d_q = q.shape
-    out = _ragged_tiles(work, q, kv_cache, scale=scale, pack=pack,
-                        depth=depth, interpret=interpret)
+    out = _ragged_tiles(work, q, kv_cache, sink, scale=scale, pack=pack,
+                        depth=depth, interpret=interpret, window=window,
+                        v_dim=v_dim)
     out = _unpack_outputs(
-        out, b, c, h, h // kv_cache.shape[1], pack)[..., :d_q]
+        out, b, c, h, h // kv_cache.shape[1], pack)[..., :v_dim or d_q]
     valid = jnp.arange(c)[None, :] < n_valid[:, None]            # [B, C]
     return jnp.where(valid[:, :, None, None], out, 0.0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "pack", "depth", "interpret"))
-def _ragged_tiles(work, q, kv_cache, *, scale, pack, depth, interpret):
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _ragged_tiles(work, q, kv_cache, sink=None, *, scale, pack, depth,
+                  interpret, window=None, v_dim=None):
     """q [B, C, H, D] packed into the kernel's query tiles and the
-    kernel's output tiles [ngroups, KVH, rows, Dc] as it leaves them, in
+    kernel's output tiles [ngroups, KVH, rows, Dv] as it leaves them, in
     the same row order (`_pack_queries`): only the rows some slot had
-    live were written."""
+    live were written. Dv is the cache's Dc, or the values' own lane
+    tiles where `v_dim` says they are narrower than the keys."""
     b, c, h, _ = q.shape
     _, kvh, _, block_size, d = kv_cache.shape
     g = h // kvh
     ngroups = -(-b // pack)
     sub, pg = query_subtile(pack, c, g)
     qp = _pack_queries(_lane_pad(q, d), kvh, g, pack, pg)
+    dv = d if v_dim is None else paged_head_dim(v_dim)
+    ins, specs = [qp, kv_cache], []
+    if sink is not None:
+        # row (slot*C + j)*G + gr of kv head hh is query head hh*G + gr
+        per = jnp.tile(sink.astype(jnp.float32).reshape(kvh, 1, g),
+                       (1, pack * c, 1)).reshape(kvh, pack * c * g)
+        per = jnp.pad(per, [(0, 0), (0, pg - pack * c * g)])
+        ins.append(jnp.broadcast_to(per[:, :, None], (kvh, pg, LANES)))
+        specs.append(pl.BlockSpec(
+            (1, pg, LANES), lambda hh, t, *_: (hh, 0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=9,
@@ -822,35 +925,37 @@ def _ragged_tiles(work, q, kv_cache, *, scale, pack, depth, interpret):
             pl.BlockSpec((1, 1, pg, d),
                          lambda hh, t, ws, wg, *_: (wg[t], hh, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # the cache stays in
-        ],                                       # HBM; blocks DMA'd by hand
+        ] + specs,                               # HBM; blocks DMA'd by hand
         out_specs=pl.BlockSpec(
-            (1, 1, pg, d), lambda hh, t, ws, wg, *_: (wg[t], hh, 0, 0)),
+            (1, 1, pg, dv), lambda hh, t, ws, wg, *_: (wg[t], hh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((depth, block_size, d), kv_cache.dtype),
-            pltpu.VMEM((depth, block_size, d), kv_cache.dtype),
+            pltpu.VMEM((depth, block_size, dv), kv_cache.dtype),
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.VMEM((pg, LANES), jnp.float32),
             pltpu.VMEM((pg, LANES), jnp.float32),
-            pltpu.VMEM((pg, d), jnp.float32),
+            pltpu.VMEM((pg, dv), jnp.float32),
         ],
     )
+    statics = {} if window is None else {"window": int(window)}
     return pl.pallas_call(
         functools.partial(_ragged_kernel, block_size=block_size,
                           scale=scale, group_q=g, chunk=c, sub=sub,
-                          depth=depth),
+                          depth=depth, **statics),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ngroups, kvh, pg, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((ngroups, kvh, pg, dv), q.dtype),
         # the HLO instruction takes this name (`%paged_step_ragged_attn.N
         # = ... custom-call`): it begins `paged_step` because that is
         # what the instruction was called after the enclosing jit before
         # it had a name, and what the benchmark's first reader matches
         name="paged_step_ragged_attn",
         interpret=interpret,
-    )(*work, qp, kv_cache)
+    )(*work, *ins)
 
 
-def ragged_attention_tiles(q, kv_cache, work, scale=None, buffer_depth=2):
+def ragged_attention_tiles(q, kv_cache, work, scale=None, buffer_depth=2,
+                           window=None, sink=None, v_dim=None):
     """`ragged_paged_attention` over a prebuilt `work` 4-tuple, stopping
     at the kernel's own output tiles: for a caller that wants a few of
     the slab's rows back (`tile_rows`) and not the [B, C, H, D] slab,
@@ -860,11 +965,14 @@ def ragged_attention_tiles(q, kv_cache, work, scale=None, buffer_depth=2):
     kvh, d = kv_cache.shape[1], kv_cache.shape[-1]
     if t_total == 0:
         return jnp.zeros((-(-b // pack), kvh,
-                          query_subtile(pack, c, h // kvh)[1], d), q.dtype)
+                          query_subtile(pack, c, h // kvh)[1],
+                          d if v_dim is None else paged_head_dim(v_dim)),
+                         q.dtype)
     return _ragged_tiles(
         tuple(jnp.asarray(a, jnp.int32) for a in work_arrs), q, kv_cache,
-        scale=float(1.0 / math.sqrt(d_q) if scale is None else scale),
-        pack=pack, depth=int(buffer_depth), interpret=_interpret_mode())
+        sink, scale=float(1.0 / math.sqrt(d_q) if scale is None else scale),
+        pack=pack, depth=int(buffer_depth), interpret=_interpret_mode(),
+        window=window, v_dim=v_dim)
 
 
 def tile_rows(tiles, slot, col, live, pack, chunk, heads, head_dim):

@@ -43,19 +43,25 @@ def one_chip():
 
 
 def _compile(one_chip, *, c, t, kvh=8, g=4, d=128, bs=128, nb=321, b=16,
-             pack=2, dtype=jnp.bfloat16, depth=2):
+             pack=2, dtype=jnp.bfloat16, depth=2, window=None, sink=False,
+             v_dim=None):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    def call(q, kv, tables, lens, q_lens, *work):
+    def call(q, kv, tables, lens, q_lens, sink_h, *work):
+        if window is not None:      # a window layer builds its own list
+            work = pa.window_work(tables, lens, q_lens, window=window,
+                                  block_size=bs, chunk=c, pack=pack)
         return pa.ragged_paged_attention(
-            q, kv, tables, lens, work=(work, None, t, pack),
-            q_lens=q_lens, buffer_depth=depth)
+            q, kv, tables, lens, work=(work, None, work[0].shape[0], pack),
+            q_lens=q_lens, buffer_depth=depth, window=window,
+            sink=sink_h if sink else None, v_dim=v_dim)
 
     args = [sds((b, c, kvh * g, d), dtype),
             sds((2, kvh, nb, bs, pa.paged_head_dim(d)), dtype),
-            sds((b, 32), jnp.int32), sds((b,), jnp.int32),
-            sds((b,), jnp.int32)] + [sds((t,), jnp.int32)] * 9
+            sds((b, 36), jnp.int32), sds((b,), jnp.int32),
+            sds((b,), jnp.int32), sds((kvh * g,), dtype)] \
+        + [sds((t,), jnp.int32)] * 9
     compiled = jax.jit(call).trace(*args).lower(
         lowering_platforms=("tpu",)).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -76,3 +82,18 @@ def test_chat_cell_widths_compile_for_a_v5e(one_chip, c):
 ], ids=["padded_bf16", "padded_f32", "depth1", "smoke_tp4_shard"])
 def test_other_tilings_compile_for_a_v5e(one_chip, kw):
     _compile(one_chip, **kw)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(c=1, t=16, kvh=8, g=8, window=128, sink=True),
+    dict(c=16, t=16, kvh=8, g=8, window=128, sink=True),
+    dict(c=128, t=16, kvh=8, g=8, window=128, sink=True),
+    dict(c=1, t=64, kvh=4, g=16),
+    dict(c=128, t=64, kvh=4, g=16),
+], ids=["window_decode", "window_c16", "window_c128", "full_decode",
+        "full_c128"])
+def test_mixed_window_and_full_layers_compile_for_a_v5e(one_chip, kw):
+    # keys 192 wide in 256-lane cache rows, values 128 (their own lane
+    # tile is all the kernel moves), 64 query heads over 8 kv heads with
+    # a window and a sink, or over 4 without; pack 1, as 16 slots give
+    _compile(one_chip, d=192, v_dim=128, pack=1, nb=65, **kw)
