@@ -2355,22 +2355,14 @@ class ContinuousBatchingEngine:
                 visited.labels(kind="window").inc(fl.visited[1])
                 pairs.labels(kind="full").inc(fl.pairs[0])
                 pairs.labels(kind="window").inc(fl.pairs[1])
-            experts = self.engine.expert_specs
-            if experts:
-                # the routers' counts came with the samples, in the
-                # columns past them: assignments on held experts, and
-                # held experts that got one, over the expert layers. The
-                # grouped product visits no tile of an expert without a
-                # row, so the experts it computed are the touched ones
-                here, touched = (int(x) for x in toks2[
-                    :, min(fl.c, 1 + self.spec_k):].T.reshape(-1)[:2])
+            _metrics.serve_tokens_stepped().inc(fl.live)
+            here = self.engine.held_assignments(toks2)
+            if here is not None:
                 routed = _metrics.serve_moe_assignments()
                 routed.labels(where="here").inc(here)
                 routed.labels(where="elsewhere").inc(
-                    fl.live * sum(ex.top_k for ex in experts) - here)
-                multiplied = _metrics.serve_moe_experts()
-                multiplied.labels(state="touched").inc(touched)
-                multiplied.labels(state="computed").inc(touched)
+                    fl.live * sum(ex.top_k for ex in
+                                  self.engine.expert_specs) - here)
 
     def _rewind_blocks(self, i, new_end):
         """Host half of the speculative rewind: shrink slot i's block

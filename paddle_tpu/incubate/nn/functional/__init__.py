@@ -545,8 +545,7 @@ def fused_multi_transformer(
         block_tables=None, ragged_work=None, ragged_pack=None,
         chunk_lens=None, kv_buffer_depth=2, layers=None,
         router_weights=None, router_biases=None, attn_sinks=None,
-        _dequant=None, _mm=None, _tp_reduce=None, _live_rows=None,
-        _expert_counts=None):
+        _dequant=None, _mm=None, _tp_reduce=None, _live_rows=None):
     """Whole-decoder-stack fused transformer (reference
     fused_multi_transformer op: python/paddle/incubate/nn/functional/
     fused_transformer.py:1053 over
@@ -607,7 +606,10 @@ def fused_multi_transformer(
     experts and unequal widths run on the paged path only.
 
     Returns the output hidden states [B, S, E]; caches are updated
-    in place (dygraph reference semantics).
+    in place (dygraph reference semantics). Where some layers have
+    routed experts it returns (hidden states, counts): the assignments
+    each held expert got in each expert layer, [expert layers, held]
+    int32.
     """
     from ....core.tensor import Tensor
 
@@ -990,9 +992,11 @@ def fused_multi_transformer(
                         pack=ragged_pack)
                 return works[k]
 
+        gots = []       # each expert layer's assignments per held expert
+
         def count(got):
-            if got is not None and _expert_counts is not None:
-                _expert_counts.append(got)
+            if got is not None:
+                gots.append(got)
 
         if padded:
             # a wide slab handed over as [B, C, E]: packed here, and
@@ -1014,7 +1018,7 @@ def fused_multi_transformer(
                 new_caches.append(cache)
                 count(got)
             return tuple([hp[rows.back] if padded else hp[None]]
-                         + new_caches)
+                         + new_caches + ([jnp.stack(gots)] if gots else []))
         h = xa
         live = None
         for li, sp in enumerate(layers):
@@ -1142,7 +1146,7 @@ def fused_multi_transformer(
             h, got = finish(resid, ctx, lw, li,
                             dkeys[li] if dkeys else None, sp, live)
             count(got)
-        return tuple([h] + new_caches)
+        return tuple([h] + new_caches + ([jnp.stack(gots)] if gots else []))
 
     out = apply_op(
         "fused_multi_transformer", impl,
@@ -1168,6 +1172,8 @@ def fused_multi_transformer(
     for cache_t, new_t in zip(caches_in, outs[1:]):
         if isinstance(cache_t, Tensor):
             cache_t._data = new_t._data
+    if any(sp.experts for sp in layers):
+        return h, outs[-1]
     return h
 
 

@@ -420,11 +420,6 @@ __all__ += ["DataType", "PlaceType", "Tensor", "PredictorPool", "XpuConfig",
             "convert_to_mixed_precision", "_get_phi_kernel_name"]
 
 
-# what a step of a model with routed experts sends out beside its samples,
-# in columns past them, summed over the expert layers
-ROUTER_STATS = ("assignments_here", "experts_touched")
-
-
 class FusedMultiTransformerEngine:
     """Serving engine over the fused_multi_transformer op (role of the
     reference's fused_multi_transformer-based inference stack:
@@ -842,32 +837,27 @@ class FusedMultiTransformerEngine:
             read on the device, so arguments, shapes and buckets are
             what they were. A slab of at most ROW_TILE rows is one tile
             whatever is live: straight-line code, no packing."""
-            counts = []
-            logits, caches = paged_logits(w, caches, toks, qlens, sel,
-                                          tables, lens, rwork, rpack,
-                                          counts)
+            logits, caches, counts = paged_logits(
+                w, caches, toks, qlens, sel, tables, lens, rwork, rpack)
             with jax.named_scope("sampler"):
                 toks_out = select(logits, temp, topp, key)
-            if counts:
-                # what the step's routers did rides out beside its
-                # samples, in columns past them: (assignments that fell
-                # on a held expert, held experts that got one), summed
-                # over the expert layers; the host reads one array
-                got = jnp.stack(counts)                  # [layers, held]
-                stats = jnp.stack([got.sum(), (got > 0).sum()])
-                b = toks_out.shape[0]
-                stats = jnp.pad(stats, (0, -len(ROUTER_STATS) % b))
+            if counts is not None:
+                # the assignments that fell on a held expert, over the
+                # expert layers, ride out beside the step's samples, in
+                # row 0 of one column past them (`held_assignments` reads
+                # it): the host fetches one array
+                col = jnp.zeros((toks_out.shape[0], 1), toks_out.dtype)
                 toks_out = jnp.concatenate(
-                    [toks_out, stats.reshape(-1, b).T.astype(
-                        toks_out.dtype)], axis=1)
+                    [toks_out, col.at[0, 0].set(
+                        counts.sum().astype(toks_out.dtype))], axis=1)
             return toks_out, caches
 
         def paged_logits(w, caches, toks, qlens, sel, tables, lens, rwork,
-                         rpack, expert_counts=None):
+                         rpack):
             """`paged_step` up to its sampler: the logits [B, W, V] at
-            the slab columns `sel` names, and the appended caches.
-            `expert_counts`, a list, takes each expert layer's
-            assignments per held expert."""
+            the slab columns `sel` names, the appended caches, and each
+            expert layer's assignments per held expert, [expert layers,
+            held] (None without experts)."""
             from ..ops.pallas.paged_attention import (
                 ROW_TILE, live_rows, over_row_tiles, put_row_tile,
                 row_tile)
@@ -898,7 +888,10 @@ class FusedMultiTransformerEngine:
                 rotary_embs=w.get("rotary_embs"),
                 block_tables=tables, ragged_work=rwork,
                 ragged_pack=rpack, _live_rows=rows,
-                _expert_counts=expert_counts, **per_layer(w), **paged_kw)
+                **per_layer(w), **paged_kw)
+            counts = None
+            if isinstance(out, tuple):
+                out, counts = out[0], out[1].data
             with jax.named_scope("head"):
                 bidx = jnp.arange(toks.shape[0])[:, None]
                 if rows is None:
@@ -906,7 +899,7 @@ class FusedMultiTransformerEngine:
                 else:
                     picked = out.data[0][rows.back[bidx, sel]]
                 logits = head(picked, w)                     # [B, W, V]
-            return logits, [c.data for c in cts]
+            return logits, [c.data for c in cts], counts
 
         def feed_tokens(slab, prev, fed):
             """The token slab of a step dispatched before the previous
@@ -1038,15 +1031,19 @@ class FusedMultiTransformerEngine:
 
     def new_sampled(self, batch):
         """A step's samples before any step ran: zeros in the shape
-        `_paged_step` leaves its own ([batch, 1], wider by the routers'
-        columns where the model has experts), for `_feed_tokens` to read
-        when no slot is fed."""
+        `_paged_step` leaves its own ([batch, 1], one column wider where
+        the model has experts), for `_feed_tokens` to read when no slot
+        is fed."""
         import jax
         import jax.numpy as jnp
-        cols = 1 + (-(-len(ROUTER_STATS) // batch)
-                    if self.expert_specs else 0)
-        z = jnp.zeros((batch, cols), jnp.int32)
+        z = jnp.zeros((batch, 2 if self.expert_specs else 1), jnp.int32)
         return z if self.tp == 1 else jax.device_put(z, self._replicated())
+
+    def held_assignments(self, sampled):
+        """The (token, expert) assignments that fell on a held expert in
+        the step whose fetched samples these are, over the expert
+        layers; None for a model without experts."""
+        return int(sampled[0, -1]) if self.expert_specs else None
 
     def _build_quant_mm(self, weights, dtype):
         """Repack the projection weights into the Pallas kernel's int4

@@ -137,16 +137,6 @@ def serve_moe_assignments():
         labels=("where",))     # bounded: here | elsewhere
 
 
-def serve_moe_experts():
-    return get_registry().counter(
-        "serve_moe_experts_total",
-        help="held experts per step and expert layer: touched (got at "
-             "least one token) vs computed (their weights went through "
-             "the grouped product) — touched over computed is the share "
-             "of the experts' weight traffic that did work",
-        labels=("state",))     # bounded: touched | computed
-
-
 def serve_kv_block_steps():
     return get_registry().counter(
         "serve_kv_block_steps_total",
@@ -185,6 +175,13 @@ def dispatch_seconds():
 def serve_tokens_total():
     return get_registry().counter(
         "serve_tokens_total", help="generated tokens")
+
+
+def serve_tokens_stepped():
+    return get_registry().counter(
+        "serve_tokens_stepped_total",
+        help="tokens the steps consumed: a prompt chunk's tokens and one "
+             "for every decoding slot")
 
 
 def serve_requests_total():

@@ -5,9 +5,9 @@ cost functions: per stepped token the projections, the dense GLU and the
 routers; per (token, held expert) assignment that fell on this chip the
 expert's three matrices; per sampled token the head's vocabulary slice;
 per (query, key) pair of each kind of layer q . k and p v over all
-heads. Counted from the registry, window's gain: stepped tokens =
-assignments made (here + elsewhere) over the experts a token picks in
-all expert layers (serve_moe_assignments_total), sampled tokens
+heads. Counted from the registry, window's gain: stepped tokens
+serve_tokens_stepped_total, assignments on this chip
+serve_moe_assignments_total{where=here}, sampled tokens
 serve_tokens_total, pairs serve_attn_pairs_total{kind}. What the program
 computes beyond that (padding rows, whole blocks where a window needs
 part of one, every slot's head row) is not the model's and not counted:
@@ -20,13 +20,10 @@ def read(ctx):
     fam, cfg = ctx["family"], ctx["config"]
     if not hasattr(fam, "token_matmul_flops"):
         return None
-    here = readers.counter_delta(ctx, "serve_moe_assignments_total", "here")
-    made = here + readers.counter_delta(
-        ctx, "serve_moe_assignments_total", "elsewhere")
-    if not made:
+    tokens = readers.counter_delta(ctx, "serve_tokens_stepped_total")
+    if not tokens:
         return None
-    d = fam.dims(cfg)
-    tokens = made / (d.top_k * sum(d.moe))
+    here = readers.counter_delta(ctx, "serve_moe_assignments_total", "here")
     full, window = fam.attention_pair_flops(cfg)
     ops = (tokens * fam.token_matmul_flops(cfg)
            + here * fam.expert_flops(cfg)

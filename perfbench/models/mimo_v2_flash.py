@@ -347,8 +347,8 @@ _ref_layer = jax.jit(block, static_argnums=(2, 3))
 
 
 def forward(seed, cfg, ids):
-    """The reference's hidden states after the final norm, [B, S, E]
-    float32, for token ids [B, S]: layer by layer, one layer's weights
+    """The reference's hidden states after the final norm, a [S, E]
+    float32 array per sequence, for token ids [B, S]: layer by layer, one layer's weights
     made from the seed in the served type and widened at a time."""
     d, dtype, key = dims(cfg), jnp.dtype(cfg["dtype"]), _key(seed)
     S = ids.shape[1]
@@ -357,12 +357,16 @@ def forward(seed, cfg, ids):
               for th in set(d.theta)}
     with jax.default_matmul_precision("highest"):
         outer = outer_tensors(key, d, dtype)
-        xs = [_f32(outer["embedding"][jnp.asarray(row)]) for row in ids]
+        # between layers the sequences wait on the host: beside a live
+        # engine the device holds one layer's weights and one sequence
+        xs = [np.asarray(_f32(outer["embedding"][jnp.asarray(row)]))
+              for row in ids]
         for li in range(d.L):
             t = layer_tensors(key, li, d, dtype)
-            xs = [_ref_layer(x, t, kind_of(d, li), d, *tables[d.theta[li]])
+            xs = [np.asarray(_ref_layer(x, t, kind_of(d, li), d,
+                                        *tables[d.theta[li]]))
                   for x in xs]       # a sequence at a time
-        return jnp.stack([_rms(x, outer["norm"], d.eps) for x in xs]), outer
+        return [_rms(jnp.asarray(x), outer["norm"], d.eps) for x in xs], outer
 
 
 @jax.jit
@@ -389,7 +393,7 @@ def served_token_gaps(seed, cfg, streams, length=None, pad_to=128):
     out = []
     with jax.default_matmul_precision("highest"):
         for i, (p, t) in enumerate(streams):
-            rows = x[i, len(p) - 1:len(p) - 1 + len(t)]
+            rows = x[i][len(p) - 1:len(p) - 1 + len(t)]
             gap, is_best = _ref_gaps(rows, outer["lm_head"],
                                      jnp.asarray(t, jnp.int32))
             out.append((np.asarray(gap), np.asarray(is_best)))

@@ -76,24 +76,19 @@ def test_the_cost_functions_count_what_the_shapes_say():
 
 def test_the_counter_readers_on_a_registry_computed_by_hand():
     reg0 = registry(
-        serve_moe_experts_total={"touched": 10.0, "computed": 20.0},
         serve_kv_block_steps_total={"full": 100.0, "window": 50.0},
         serve_attn_entries_total={"full": 40.0, "window": 30.0})
     reg1 = registry(
-        serve_moe_experts_total={"touched": 70.0, "computed": 100.0},
         serve_kv_block_steps_total={"full": 1100.0, "window": 300.0},
         serve_attn_entries_total={"full": 440.0, "window": 130.0})
     ctx = window(reg0, reg1)
-    assert reader("moe_experts_live_pct.mixedlen")(ctx) \
-        == pytest.approx(100.0 * 60 / 80)
     assert reader("window_blocks_held_pct.mixedlen")(ctx) \
         == pytest.approx(25.0)
     assert reader("window_attn_entries_pct.mixedlen")(ctx) \
         == pytest.approx(25.0)
     # a program that counts none of it: nothing to read
     silent = window(registry(), registry())
-    for name in ("moe_experts_live_pct.mixedlen",
-                 "window_blocks_held_pct.mixedlen",
+    for name in ("window_blocks_held_pct.mixedlen",
                  "window_attn_entries_pct.mixedlen"):
         assert reader(name)(silent) is None
 
@@ -104,6 +99,7 @@ def test_the_steps_share_of_the_peak_from_counts():
     # 1000 tokens stepped: 80 assignments each over the ten expert
     # layers, a sixteenth of them here; 100 sampled; pairs by kind
     reg1 = registry(
+        serve_tokens_stepped_total={"": 1000.0},
         serve_moe_assignments_total={"here": 5000.0, "elsewhere": 75000.0},
         serve_tokens_total={"": 100.0},
         serve_attn_pairs_total={"full": 1e6, "window": 1e5})

@@ -234,7 +234,7 @@ def test_wide_step_matches_the_padded_computation(case, entry):
             jax.random.PRNGKey(0))
         logits = want_logits        # the mesh program hands out tokens only
         if entry == "engine":
-            logits, _ = jax.jit(eng._paged_logits, static_argnums=(8,))(
+            logits, *_ = jax.jit(eng._paged_logits, static_argnums=(8,))(
                 eng._w, _eng_caches(eng, caches), toks, q_lens, sel,
                 tables, lens, work, pack)
 

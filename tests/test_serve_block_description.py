@@ -85,7 +85,7 @@ def served():
         seen = {}
 
         def step(w, caches, slab, q, sel, tables, lens, work, pack, *rest):
-            lg, _ = logits_of(w, caches, slab, q, sel, tables, lens, work,
+            lg, *_ = logits_of(w, caches, slab, q, sel, tables, lens, work,
                               pack)
             lg = np.asarray(lg)
             for i, req in enumerate(cb.slots):
@@ -290,6 +290,44 @@ def test_window_blocks_come_back_and_both_tables_are_returned(served):
     assert not cb.window_tables.any() and not cb.tables.any()
     cb.run()
     assert len(cb.finished["low"]) == 8
+
+
+def test_the_steps_count_their_tokens_and_their_routers_assignments():
+    """`serve_tokens_stepped_total` rises by every token a step consumed
+    (a request of n prompt tokens and m answers steps n + m - 1), the
+    routers' assignments by top_k a token and expert layer, split into
+    those on a held expert (the count that rides out with the samples,
+    `engine.held_assignments`) and those elsewhere."""
+    from paddle_tpu.observability import get_registry
+
+    def value(snap, family, child=""):
+        c = snap.get(family, {}).get("children", {}).get(child)
+        return c["value"] if c else 0.0
+
+    old, fa._INTERPRET = fa._INTERPRET, True
+    try:
+        fam = _family()
+        engine, cb = _engines(fam)
+        assert engine.held_assignments(np.asarray(cb._sampled)) == 0
+        snap0 = get_registry().snapshot()
+        rng = np.random.default_rng(9)
+        sizes = ((13, 6), (5, 9))
+        for i, (n, m) in enumerate(sizes):
+            cb.submit(GenerationRequest(rng.integers(1, 96, n), m,
+                                        request_id=f"r{i}"))
+        cb.run()
+        snap1 = get_registry().snapshot()
+    finally:
+        fa._INTERPRET = old
+    gain = lambda *a: value(snap1, *a) - value(snap0, *a)
+    stepped = sum(n + m - 1 for n, m in sizes)
+    assert gain("serve_tokens_stepped_total") == stepped
+    here = gain("serve_moe_assignments_total", "here")
+    made = here + gain("serve_moe_assignments_total", "elsewhere")
+    assert made == stepped * CFG["num_experts_per_tok"] \
+        * sum(CFG["moe_layer_freq"])
+    # 4 of 16 experts are held: about a quarter of the assignments
+    assert 0 < here < made / 2
 
 
 def test_what_the_description_cannot_serve_is_refused_at_construction():
