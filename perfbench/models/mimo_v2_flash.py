@@ -48,7 +48,10 @@ import numpy as np
 INIT_STD = 0.02          # normal weights
 NORM_JITTER = 0.05       # norm scales 1 + 0.05 n: a dropped scale shows
 BIAS_STD = 0.1           # the router's correction bias b: not zero, so
-#                          that adding it to the weights shows
+#                          that adding it to the weights shows. It decides
+#                          the selection (the eight largest sigmas of 256
+#                          lie 0.01 apart), so each chip's share of the
+#                          experts gets the SAME values, `share_biases`
 SINK_MEAN, SINK_STD = 4.0, 1.0   # sink logits: beside scores of std ~1.6
 #                          over 128 keys a sink of e^4 takes about a tenth
 #                          of the softmax's mass, so dropping it shows
@@ -73,6 +76,7 @@ class Dims(typing.NamedTuple):
     top_k: int           # num_experts_per_tok
     lo: int              # first expert held here
     held: int            # experts held here
+    shares: int          # chips a layer's experts are dealt over
     vscale: float        # attention_value_scale
     eps: float
 
@@ -107,6 +111,7 @@ def dims(cfg):
         routed=cfg["experts_routed_over"],
         top_k=cfg["num_experts_per_tok"], lo=cfg["expert_first"],
         held=cfg["n_routed_experts"],
+        shares=cfg.get("deployment_chips", 1),
         vscale=float(cfg["attention_value_scale"]),
         eps=float(cfg["layernorm_epsilon"]))
 
@@ -140,8 +145,7 @@ def _layer(key, k, d, dtype):
             ks[4], (d.H,), jnp.float32)).astype(dtype)
     if k.moe:
         t["router"] = mat(ks[5], d.E, d.routed)
-        t["router_b"] = (BIAS_STD * jax.random.normal(
-            ks[6], (d.routed,), jnp.float32)).astype(dtype)
+        t["router_b"] = share_biases(ks[6], d).astype(dtype)
         # all `routed` experts are the model's; the first axis is cut to
         # the ones held, each from its own key so that a share holds the
         # same expert whatever else it holds
@@ -154,6 +158,22 @@ def _layer(key, k, d, dtype):
         t["gate_up_t"] = mat(ks[8], d.E, 2 * d.F)
         t["down_t"] = mat(ks[9], d.F, d.E)
     return t
+
+
+def share_biases(key, d):
+    """The router's correction bias [routed]: each of the deployment's
+    `shares` chips gets the same multiset, BIAS_STD times the normal
+    distribution's quantiles at (i + 0.5) / width, in an order of its own
+    drawn from the key. With free draws the seed decided how many of the
+    held experts any token reaches (7.4-9.2 of 16 in a 128-row chunk step
+    over twelve seeds; 8.3-8.7 dealt so), and with that the step's time:
+    the same work for every seed, as the traffic's sizes are dealt
+    (`lib/traffic.py`)."""
+    width = d.routed // d.shares
+    values = BIAS_STD * jax.scipy.special.ndtri(
+        (jnp.arange(width, dtype=jnp.float32) + 0.5) / width)
+    return jax.vmap(lambda k: jax.random.permutation(k, values))(
+        jax.random.split(key, d.shares)).reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))

@@ -59,6 +59,43 @@ def test_the_cell_resolves_and_is_held_to_its_source():
     assert mix["prompt"]["max"] + mix["output"]["max"] <= e["max_seq_len"]
 
 
+def test_every_seed_gives_every_chips_share_the_same_router_biases():
+    """The refused check of PR 40: the router's correction bias decides
+    the selection, so drawn freely the seed decided how many held experts
+    a step reaches, and with that the cell's step time. Each share's
+    biases are one multiset in a seeded order: none zero, and the share
+    of a token's assignments that fall on the held experts, router and
+    biases at the cell's widths, stays within a few per cent of 1/16 over
+    seeds (free draws of the same spread read 0.41-0.59 of a token's 8)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    cfg = MANIFEST.config(MANIFEST.cell(CELL))
+    fam = MANIFEST.family(cfg)
+    d = fam.dims(cfg)
+    assert d.shares == cfg["deployment_chips"] == d.routed // d.held
+    here = []
+    for seed in (3, 2 ** 31 + 5, 2 ** 32 + 7, 11):
+        kr, kb, kz = jax.random.split(fam._key(seed, 9), 3)
+        b = np.asarray(fam.share_biases(kb, d), np.float32)
+        shares = np.sort(b.reshape(d.shares, -1), axis=1)
+        assert (shares == shares[0]).all() and (b != 0).all()
+        assert b.std() == pytest.approx(fam.BIAS_STD, rel=0.1)
+        assert len({tuple(np.argsort(r)) for r in b.reshape(d.shares, -1)}) \
+            > 1                         # each share in an order of its own
+        router = fam.INIT_STD * jax.random.normal(kr, (d.E, d.routed))
+        z = jax.random.normal(kz, (2048, d.E))
+        w = fam.routing(z, router, jnp.asarray(b), d.top_k)
+        assert float((w > 0).sum(1).mean()) == d.top_k
+        here.append(float((w[:, d.lo:d.lo + d.held] > 0).sum(1).mean()))
+    assert max(here) - min(here) < 0.08 * d.top_k / d.shares, here
+    # a share's biases do not change with what this chip holds
+    whole = dict(cfg, n_routed_experts=d.routed)
+    np.testing.assert_array_equal(
+        np.asarray(fam.share_biases(kb, fam.dims(whole))), b)
+
+
 def test_the_cost_functions_count_what_the_shapes_say():
     cfg = MANIFEST.config(MANIFEST.cell(CELL))
     fam = MANIFEST.family(cfg)
