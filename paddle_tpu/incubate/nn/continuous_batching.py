@@ -2363,6 +2363,8 @@ class ContinuousBatchingEngine:
                 routed.labels(where="elsewhere").inc(
                     fl.live * sum(ex.top_k for ex in
                                   self.engine.expert_specs) - here)
+                _metrics.serve_moe_slab_rows().inc(
+                    self.engine.product_rows(toks2))
 
     def _rewind_blocks(self, i, new_end):
         """Host half of the speculative rewind: shrink slot i's block

@@ -488,6 +488,19 @@ class LayerSpec(typing.NamedTuple):
         return 1 if self.window else 0
 
 
+# The most sorted assignment rows one grouped product of `expert_ffn` is
+# handed (the routed experts' counterpart of `paged_attention.ROW_TILE`,
+# kept here because that file's line numbers key every Mosaic program).
+# XLA tiles the product by up to 512 rows of its STATIC row count and a
+# group that holds any row pays a whole tile, so the static count is
+# what a touched expert costs beyond its weights' stream: 0.083 / 0.101
+# / 0.159 ms over both products at 128 / 256 / 512 rows on a v5e
+# (PERF.md section 6, PR 41, where the cell was measured at this value;
+# 128 is faster for the function alone and not yet measured in a cell).
+# A decode step's 16 x 8 assignment rows are one narrower slab.
+MOE_SLAB = 256
+
+
 def expert_ffn(z, router, router_b, w13, w2, ex, live, act):
     """Routed experts over the rows z [R, E], this device's share: the
     router scores all ex.n_routed experts in float32 (sigmoid), the
@@ -497,11 +510,16 @@ def expert_ffn(z, router, router_b, w13, w2, ex, live, act):
     matrix product over the held experts' stacked weights (w13
     [held, E, 2F] gate|up, w2 [held, F, E]); the weighted partial sum
     comes back per row. Rows that are not `live` route nowhere. Also
-    returns how many assignments each held expert got, [held] int32.
+    returns how many assignments each held expert got, [held] int32, and
+    how many rows the grouped products were handed, a scalar int32.
 
-    Rows of the grouped product: R x top_k, the live assignments on held
-    experts sorted to the front; XLA's grouped product visits only the
-    row tiles its group sizes cover, and no tile of an empty group."""
+    The live assignments on held experts are sorted to the front of the
+    R x top_k there are, and the products run over slabs of MOE_SLAB
+    sorted rows (of all R x top_k where those are fewer), as many slabs
+    as the held assignments fill (a trip count read on the device, none
+    where nothing fell here): every gather, product and scatter is at
+    most MOE_SLAB rows whatever R is, and every held assignment is
+    multiplied whatever their number."""
     r = z.shape[0]
     with jax.named_scope("moe_route"):
         sigma = jax.nn.sigmoid(jnp.dot(
@@ -518,18 +536,44 @@ def expert_ffn(z, router, router_b, w13, w2, ex, live, act):
         order = jnp.argsort(eid, stable=True)
         counts = jnp.sum(eid[:, None] == jnp.arange(ex.held)[None, :],
                          axis=0, dtype=jnp.int32)
-        tok = order // ex.top_k
-        gu = jax.lax.ragged_dot(z[tok], w13.astype(z.dtype), counts)
-        f = gu.shape[-1] // 2
-        y = jax.lax.ragged_dot(
-            (act(gu[:, :f]) * gu[:, f:]).astype(z.dtype),
-            w2.astype(z.dtype), counts,
-            preferred_element_type=jnp.float32)
-        # rows past the groups are not the product's to write
-        y = jnp.where((jnp.arange(eid.shape[0]) < jnp.sum(counts))[:, None],
-                      y * wt.reshape(-1)[order][:, None], 0.0)
-        out = jnp.zeros((r, y.shape[1]), jnp.float32).at[tok].add(y)
-    return out.astype(z.dtype), counts
+        # once, not once a slab (dequantized weights come wider than z)
+        w13, w2 = w13.astype(z.dtype), w2.astype(z.dtype)
+
+        def multiply(rows, sizes):
+            """The sorted assignments `rows` [S], of which the leading
+            sum(sizes) are grouped by held expert as `sizes` [held]:
+            each one's token row, and its expert's output for that token
+            times its routing weight [S, E] (0 past the groups)."""
+            tok = rows // ex.top_k
+            gu = jax.lax.ragged_dot(z[tok], w13, sizes)
+            f = gu.shape[-1] // 2
+            y = jax.lax.ragged_dot(
+                (act(gu[:, :f]) * gu[:, f:]).astype(z.dtype), w2, sizes,
+                preferred_element_type=jnp.float32)
+            # rows past the groups are not the product's to write
+            return tok, jnp.where(
+                (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None],
+                y * wt.reshape(-1)[rows][:, None], 0.0)
+
+        n = eid.shape[0]
+        width = min(n, MOE_SLAB)
+        ends = jnp.cumsum(counts)
+        order = jnp.pad(order, (0, -n % width))     # whole slabs
+
+        def slab(j, out):
+            # this slab's share of each group: the assignments of sorted
+            # rows j x width .. + width - 1, a group that straddles an
+            # edge cut there
+            upto = jnp.clip(ends - j * width, 0, width)
+            tok, y = multiply(
+                jax.lax.dynamic_slice_in_dim(order, j * width, width),
+                jnp.diff(upto, prepend=0))
+            return out.at[tok].add(y)
+
+        trips = -(-ends[-1] // width)
+        out = jax.lax.fori_loop(
+            0, trips, slab, jnp.zeros((r, w2.shape[-1]), jnp.float32))
+    return out.astype(z.dtype), counts, trips * width
 
 
 def fused_multi_transformer(
@@ -607,9 +651,10 @@ def fused_multi_transformer(
 
     Returns the output hidden states [B, S, E]; caches are updated
     in place (dygraph reference semantics). Where some layers have
-    routed experts it returns (hidden states, counts): the assignments
-    each held expert got in each expert layer, [expert layers, held]
-    int32.
+    routed experts it returns (hidden states, counts, handed): the
+    assignments each held expert got in each expert layer, [expert
+    layers, held] int32, and the rows each expert layer's grouped
+    products were handed, [expert layers] int32 (`expert_ffn`).
     """
     from ....core.tensor import Tensor
 
@@ -823,11 +868,12 @@ def fused_multi_transformer(
             """Output projection, residual and feed-forward: the layer's
             input `resid` [b, s, E] and its attention output ctx
             [b, s, H, D] -> the layer's output [b, s, E], and for a layer
-            of routed experts the assignments each held expert got
+            of routed experts `expert_ffn`'s two counts: the assignments
+            each held expert got and the rows its products were handed
             (None otherwise). `live` [b, s] marks the rows that hold a
             token (None: all)."""
             b, s = ctx.shape[:2]    # this call's rows, not the slab's
-            counts = None
+            got = None
             if _mm is not None:
                 attn = _mm(ctx.reshape(b * s, -1), lw.lin,
                            "lin", li).reshape(b, s, -1)
@@ -852,7 +898,7 @@ def fused_multi_transformer(
                     else jax.nn.relu if sp.activation == "relu" \
                     else jax.nn.gelu
                 if sp.experts is not None:
-                    f2, counts = expert_ffn(
+                    f2, *got = expert_ffn(
                         z2.reshape(b * s, -1), lw.router, lw.router_b,
                         dq(lw.f1, "f1", li), dq(lw.f2, "f2", li),
                         sp.experts, jnp.ones(b * s, bool) if live is None
@@ -882,7 +928,7 @@ def fused_multi_transformer(
                 h = resid2 * residual_alpha + f2
                 if not pre_layer_norm:
                     h = norm(h, lw.fln, lw.fln_b)
-            return h, counts
+            return h, got
 
         def attend_kw(sp, lw):
             """What the ragged kernel is told of the layer's attention."""
@@ -914,7 +960,7 @@ def fused_multi_transformer(
             scales on it (there each layer is its own). `tables` and
             `work` are the layer's own kind's. A layer of routed experts
             groups its assignments per row tile, inside the second loop,
-            and carries the held experts' counts beside the rows; it
+            and carries `expert_ffn`'s two counts beside the rows; it
             returns them last (None otherwise)."""
             li, sp = key
             pos = ln[rows.slot] + rows.col                     # [R]
@@ -938,7 +984,7 @@ def fused_multi_transformer(
                     **attend_kw(sp, lw))
 
             def after(r0, carry):
-                hp, counts = carry if sp.experts else (carry, None)
+                hp, *sums = carry if sp.experts else (carry,)
                 with jax.named_scope("attention"):
                     slot, col = row_tile(rows.slot, r0), row_tile(rows.col, r0)
                     live = row_tile(rows.live, r0)
@@ -952,13 +998,16 @@ def fused_multi_transformer(
                     else jax.random.fold_in(dkey, r0), sp,
                     live[None] if sp.experts else None)
                 hp = put_row_tile(hp, out[0], r0)
-                return (hp, counts + got) if sp.experts else hp
+                if sp.experts:
+                    return (hp, *(a + b for a, b in zip(sums, got)))
+                return hp
 
             if sp.experts:
-                hp, counts = over_row_tiles(
+                hp, *sums = over_row_tiles(
                     rows.n_tiles, after,
-                    (hp, jnp.zeros(sp.experts.held, jnp.int32)))
-                return hp, qp, cache, counts
+                    (hp, jnp.zeros(sp.experts.held, jnp.int32),
+                     jnp.int32(0)))
+                return hp, qp, cache, sums
             return over_row_tiles(rows.n_tiles, after, hp), qp, cache, None
 
         padded = False
@@ -992,11 +1041,16 @@ def fused_multi_transformer(
                         pack=ragged_pack)
                 return works[k]
 
-        gots = []       # each expert layer's assignments per held expert
+        gots = []       # each expert layer's two counts (`expert_ffn`)
 
         def count(got):
             if got is not None:
                 gots.append(got)
+
+        def counted():
+            """[[expert layers, held], [expert layers]] where some layer
+            has experts."""
+            return [jnp.stack(c) for c in zip(*gots)]
 
         if padded:
             # a wide slab handed over as [B, C, E]: packed here, and
@@ -1018,7 +1072,7 @@ def fused_multi_transformer(
                 new_caches.append(cache)
                 count(got)
             return tuple([hp[rows.back] if padded else hp[None]]
-                         + new_caches + ([jnp.stack(gots)] if gots else []))
+                         + new_caches + counted())
         h = xa
         live = None
         for li, sp in enumerate(layers):
@@ -1146,7 +1200,7 @@ def fused_multi_transformer(
             h, got = finish(resid, ctx, lw, li,
                             dkeys[li] if dkeys else None, sp, live)
             count(got)
-        return tuple([h] + new_caches + ([jnp.stack(gots)] if gots else []))
+        return tuple([h] + new_caches + counted())
 
     out = apply_op(
         "fused_multi_transformer", impl,
@@ -1173,7 +1227,7 @@ def fused_multi_transformer(
         if isinstance(cache_t, Tensor):
             cache_t._data = new_t._data
     if any(sp.experts for sp in layers):
-        return h, outs[-1]
+        return h, outs[-2], outs[-1]
     return h
 
 

@@ -837,27 +837,30 @@ class FusedMultiTransformerEngine:
             read on the device, so arguments, shapes and buckets are
             what they were. A slab of at most ROW_TILE rows is one tile
             whatever is live: straight-line code, no packing."""
-            logits, caches, counts = paged_logits(
+            logits, caches, counts, handed = paged_logits(
                 w, caches, toks, qlens, sel, tables, lens, rwork, rpack)
             with jax.named_scope("sampler"):
                 toks_out = select(logits, temp, topp, key)
             if counts is not None:
-                # the assignments that fell on a held expert, over the
+                # the assignments that fell on a held expert and the
+                # rows the grouped products were handed, each over the
                 # expert layers, ride out beside the step's samples, in
-                # row 0 of one column past them (`held_assignments` reads
-                # it): the host fetches one array
-                col = jnp.zeros((toks_out.shape[0], 1), toks_out.dtype)
+                # row 0 of two columns past them (`held_assignments` and
+                # `product_rows` read them): the host fetches one array
+                cols = jnp.zeros((toks_out.shape[0], 2), toks_out.dtype)
                 toks_out = jnp.concatenate(
-                    [toks_out, col.at[0, 0].set(
-                        counts.sum().astype(toks_out.dtype))], axis=1)
+                    [toks_out, cols.at[0].set(jnp.stack(
+                        [counts.sum(), handed.sum()]
+                    ).astype(toks_out.dtype))], axis=1)
             return toks_out, caches
 
         def paged_logits(w, caches, toks, qlens, sel, tables, lens, rwork,
                          rpack):
             """`paged_step` up to its sampler: the logits [B, W, V] at
-            the slab columns `sel` names, the appended caches, and each
+            the slab columns `sel` names, the appended caches, each
             expert layer's assignments per held expert, [expert layers,
-            held] (None without experts)."""
+            held], and the rows its grouped products were handed,
+            [expert layers] (None and None without experts)."""
             from ..ops.pallas.paged_attention import (
                 ROW_TILE, live_rows, over_row_tiles, put_row_tile,
                 row_tile)
@@ -889,9 +892,9 @@ class FusedMultiTransformerEngine:
                 block_tables=tables, ragged_work=rwork,
                 ragged_pack=rpack, _live_rows=rows,
                 **per_layer(w), **paged_kw)
-            counts = None
+            counts = handed = None
             if isinstance(out, tuple):
-                out, counts = out[0], out[1].data
+                out, counts, handed = out[0], out[1].data, out[2].data
             with jax.named_scope("head"):
                 bidx = jnp.arange(toks.shape[0])[:, None]
                 if rows is None:
@@ -899,7 +902,7 @@ class FusedMultiTransformerEngine:
                 else:
                     picked = out.data[0][rows.back[bidx, sel]]
                 logits = head(picked, w)                     # [B, W, V]
-            return logits, [c.data for c in cts], counts
+            return logits, [c.data for c in cts], counts, handed
 
         def feed_tokens(slab, prev, fed):
             """The token slab of a step dispatched before the previous
@@ -1031,18 +1034,26 @@ class FusedMultiTransformerEngine:
 
     def new_sampled(self, batch):
         """A step's samples before any step ran: zeros in the shape
-        `_paged_step` leaves its own ([batch, 1], one column wider where
+        `_paged_step` leaves its own ([batch, 1], two columns wider where
         the model has experts), for `_feed_tokens` to read when no slot
         is fed."""
         import jax
         import jax.numpy as jnp
-        z = jnp.zeros((batch, 2 if self.expert_specs else 1), jnp.int32)
+        z = jnp.zeros((batch, 3 if self.expert_specs else 1), jnp.int32)
         return z if self.tp == 1 else jax.device_put(z, self._replicated())
 
     def held_assignments(self, sampled):
         """The (token, expert) assignments that fell on a held expert in
         the step whose fetched samples these are, over the expert
         layers; None for a model without experts."""
+        return int(sampled[0, -2]) if self.expert_specs else None
+
+    def product_rows(self, sampled):
+        """The rows the experts' grouped products were handed in that
+        step, over the expert layers and row tiles (`expert_ffn`: as
+        many slabs of MOE_SLAB sorted rows, or of a narrower call's
+        R x top_k, as its held assignments fill); None for a model
+        without experts."""
         return int(sampled[0, -1]) if self.expert_specs else None
 
     def _build_quant_mm(self, weights, dtype):

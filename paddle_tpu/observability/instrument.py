@@ -137,6 +137,16 @@ def serve_moe_assignments():
         labels=("where",))     # bounded: here | elsewhere
 
 
+def serve_moe_slab_rows():
+    return get_registry().counter(
+        "serve_moe_slab_rows_total",
+        help="rows the experts' grouped products were handed (whole "
+             "slabs of the sorted assignments that fell on a held "
+             "expert), summed over the expert layers and row tiles of "
+             "the steps; serve_moe_assignments_total{where=here} over "
+             "this is how full the products' rows ran")
+
+
 def serve_kv_block_steps():
     return get_registry().counter(
         "serve_kv_block_steps_total",

@@ -22,7 +22,8 @@ import pytest
 
 from paddle_tpu.incubate.nn import (ContinuousBatchingEngine,
                                     GenerationRequest)
-from paddle_tpu.incubate.nn.functional import ExpertSpec, expert_ffn
+from paddle_tpu.incubate.nn.functional import (MOE_SLAB, ExpertSpec,
+                                               expert_ffn)
 from paddle_tpu.inference import FusedMultiTransformerEngine
 from paddle_tpu.ops.pallas import flash_attention as fa
 
@@ -209,7 +210,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_whole_layer():
         total, touched = 0.0, 0
         for lo in range(0, 16, 4):
             part = dict(t, w13=t["w13"][lo:lo + 4], w2=t["w2"][lo:lo + 4])
-            got, counts = expert_ffn(
+            got, counts, _ = expert_ffn(
                 z, t["router"], t["router_b"], part["w13"], part["w2"],
                 ExpertSpec(n_routed=16, top_k=4, lo=lo, held=4),
                 jnp.ones(37, bool), jax.nn.silu)
@@ -221,6 +222,87 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_whole_layer():
     assert touched == 37 * 4            # every assignment fell on one share
     np.testing.assert_allclose(total, whole, atol=5e-6)
     assert np.abs(whole).max() > 1e-3
+
+
+# expert_ffn against the family's plain `experts`, by where the router's
+# bias sends the assignments (a bias of 10 on an expert puts it among
+# every token's top_k; the weights are the scores', the bias selects
+# only): rows, top_k, experts held of 16 routed, experts the bias
+# favours (held ones are 4 .. 4 + held - 1), share of dead rows, and the
+# trips of the slab loop where the case fixes them (SOME: as many as the
+# reference's held assignments fill)
+SOME = object()
+_ROUTINGS = {
+    "nothing_falls_here_zero_trips": (80, 4, 4, (0, 1, 2, 3), 0.0, 0),
+    "all_held_one_and_a_half_slabs": (
+        3 * MOE_SLAB // 8, 4, 8, tuple(range(4, 12)), 0.0, 2),
+    "all_held_three_slabs": (
+        3 * MOE_SLAB // 4, 4, 8, tuple(range(4, 12)), 0.0, 3),
+    "held_assignments_exactly_one_slab": (
+        MOE_SLAB // 2, 4, 4, (0, 1, 4, 5), 0.0, 1),
+    "one_expert_takes_every_row": (
+        MOE_SLAB + 44, 4, 4, (0, 1, 2, 6), 0.0, 2),
+    "dead_rows_among_live_ones": (
+        3 * MOE_SLAB // 4, 4, 8, tuple(range(4, 12)), 0.35, SOME),
+    "free_routing_some_held": (MOE_SLAB // 2, 4, 4, (), 0.0, SOME),
+    "fewer_assignment_rows_than_a_slab": (
+        MOE_SLAB // 8, 4, 4, (), 0.2, SOME),
+}
+
+
+@pytest.mark.parametrize("routing", list(_ROUTINGS))
+def test_every_held_assignment_is_multiplied_whatever_the_distribution(
+        routing):
+    """Output, per-expert counts and the rows the products were handed,
+    in float32 at `highest` precision: slabs of MOE_SLAB sorted rows
+    (of all R x top_k where those are fewer), as many as the held
+    assignments fill, give what one product over all R x top_k rows
+    gave, for any distribution of the assignments."""
+    rows, top_k, held, favoured, dead, trips = _ROUTINGS[routing]
+    fam = _family()
+    d = fam.dims(dict(CFG, expert_first=4, n_routed_experts=held,
+                      num_experts_per_tok=top_k))
+    t = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32),
+        fam.layer_tensors(fam._key(SEED), 1, d, jnp.dtype("float32")))
+    t["router_b"] = t["router_b"].at[jnp.asarray(favoured, int)].add(10.0)
+    kz, kl = jax.random.split(jax.random.key(11))
+    z = jax.random.normal(kz, (rows, d.E), jnp.float32)
+    live = jax.random.uniform(kl, (rows,)) >= dead
+    with jax.default_matmul_precision("highest"):
+        got, counts, handed = jax.jit(
+            lambda z, live: expert_ffn(
+                z, t["router"], t["router_b"], t["w13"], t["w2"],
+                ExpertSpec(n_routed=16, top_k=top_k, lo=d.lo, held=held),
+                live, jax.nn.silu))(z, live)
+        want = np.asarray(fam.experts(z, t, d)) * np.asarray(live)[:, None]
+        sent = np.asarray(fam.routing(
+            z, t["router"], t["router_b"], top_k))[:, d.lo:d.lo + held] > 0
+    want_counts = (sent & np.asarray(live)[:, None]).sum(0)
+    np.testing.assert_array_equal(np.asarray(counts), want_counts)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-6)
+    here = int(want_counts.sum())
+    width = min(rows * top_k, MOE_SLAB)
+    if trips is SOME:
+        trips = -(-here // width)
+    assert int(handed) == trips * width
+    assert trips == -(-here // width)
+    assert (width < MOE_SLAB) == (routing.startswith("fewer"))
+    if routing.startswith("all_held"):
+        assert here == rows * top_k
+        # some group lies across a slab's edge
+        ends = np.cumsum(want_counts)
+        assert any(lo < MOE_SLAB * k < hi for k in range(1, trips)
+                   for lo, hi in zip(ends - want_counts, ends))
+    elif routing == "held_assignments_exactly_one_slab":
+        assert here == MOE_SLAB
+    elif routing == "one_expert_takes_every_row":
+        assert sorted(want_counts) == [0, 0, 0, rows]
+    elif routing == "nothing_falls_here_zero_trips":
+        assert here == 0 and not np.asarray(got).any()
+    else:
+        assert 0 < here < rows * top_k
+    assert routing.startswith("nothing") or np.abs(want).max() > 1e-3
 
 
 def test_window_blocks_come_back_and_both_tables_are_returned(served):
