@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from ....core.dispatch import apply_op
 from ....core import random as _random
+from ....observability import tracing
 from ....nn.functional.rope import fused_rotary_position_embedding  # noqa: F401
 
 NEG_INF_F = -1e30
@@ -521,7 +522,7 @@ def expert_ffn(z, router, router_b, w13, w2, ex, live, act):
     most MOE_SLAB rows whatever R is, and every held assignment is
     multiplied whatever their number."""
     r = z.shape[0]
-    with jax.named_scope("moe_route"):
+    with tracing.device_scope("moe_route"):
         sigma = jax.nn.sigmoid(jnp.dot(
             z.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
@@ -532,7 +533,7 @@ def expert_ffn(z, router, router_b, w13, w2, ex, live, act):
         local = sel - ex.lo
         here = (local >= 0) & (local < ex.held) & live[:, None]
         eid = jnp.where(here, local, ex.held).reshape(-1)
-    with jax.named_scope("moe_experts"):
+    with tracing.device_scope("moe_experts"):
         order = jnp.argsort(eid, stable=True)
         counts = jnp.sum(eid[:, None] == jnp.arange(ex.held)[None, :],
                          axis=0, dtype=jnp.int32)
@@ -571,8 +572,9 @@ def expert_ffn(z, router, router_b, w13, w2, ex, live, act):
             return out.at[tok].add(y)
 
         trips = -(-ends[-1] // width)
-        out = jax.lax.fori_loop(
-            0, trips, slab, jnp.zeros((r, w2.shape[-1]), jnp.float32))
+        with tracing.device_scope("moe_slabs"):
+            out = jax.lax.fori_loop(
+                0, trips, slab, jnp.zeros((r, w2.shape[-1]), jnp.float32))
     return out.astype(z.dtype), counts, trips * width
 
 
@@ -874,24 +876,26 @@ def fused_multi_transformer(
             token (None: all)."""
             b, s = ctx.shape[:2]    # this call's rows, not the slab's
             got = None
-            if _mm is not None:
-                attn = _mm(ctx.reshape(b * s, -1), lw.lin,
-                           "lin", li).reshape(b, s, -1)
-            else:
-                attn = ctx.reshape(b, s, -1) @ dq(lw.lin, "lin", li)
-            attn = tp_red(attn)
-            if lw.lin_b is not None:
-                attn = attn + lw.lin_b
-            if dkey is not None:
-                keep = jax.random.bernoulli(
-                    dkey, 1.0 - dropout_rate, attn.shape)
-                attn = jnp.where(keep, attn / (1.0 - dropout_rate), 0.0) \
-                    if mode == "upscale_in_train" else \
-                    jnp.where(keep, attn, 0.0)
-            h = resid * residual_alpha + attn
-            if not pre_layer_norm:
-                h = norm(h, lw.ln, lw.ln_b)
-            with jax.named_scope("ffn"):
+            with tracing.device_scope("out_proj"):
+                if _mm is not None:
+                    attn = _mm(ctx.reshape(b * s, -1), lw.lin,
+                               "lin", li).reshape(b, s, -1)
+                else:
+                    attn = ctx.reshape(b, s, -1) @ dq(lw.lin, "lin", li)
+                attn = tp_red(attn)
+                if lw.lin_b is not None:
+                    attn = attn + lw.lin_b
+                if dkey is not None:
+                    keep = jax.random.bernoulli(
+                        dkey, 1.0 - dropout_rate, attn.shape)
+                    attn = jnp.where(
+                        keep, attn / (1.0 - dropout_rate), 0.0) \
+                        if mode == "upscale_in_train" else \
+                        jnp.where(keep, attn, 0.0)
+                h = resid * residual_alpha + attn
+                if not pre_layer_norm:
+                    h = norm(h, lw.ln, lw.ln_b)
+            with tracing.device_scope("ffn"):
                 resid2 = h
                 z2 = norm(h, lw.fln, lw.fln_b) if pre_layer_norm else h
                 act = jax.nn.silu if sp.activation == "swiglu" \
@@ -963,21 +967,29 @@ def fused_multi_transformer(
             and carries `expert_ffn`'s two counts beside the rows; it
             returns them last (None otherwise)."""
             li, sp = key
-            pos = ln[rows.slot] + rows.col                     # [R]
 
             def before(r0, carry):
                 qp, cache = carry
                 slot, at = row_tile(rows.slot, r0), row_tile(pos, r0)
-                q, k, v = project(row_tile(hp, r0)[None], lw, li, sp)
-                q, k = rope(q, k, table, (slot, at))
-                with jax.named_scope("kv_write"):
+                with tracing.device_scope("qkv_proj"):
+                    q, k, v = project(row_tile(hp, r0)[None], lw, li, sp)
+                with tracing.device_scope("rope"):
+                    q, k = rope(q, k, table, (slot, at))
+                with tracing.device_scope("kv_write"):
                     cache = append_paged_kv_rows(
                         cache, k[0], v[0], tables, slot, at,
                         row_tile(rows.live, r0))
-                return put_row_tile(qp, q[0], r0), cache
+                with tracing.device_scope("q_pack"):
+                    return put_row_tile(qp, q[0], r0), cache
 
-            qp, cache = over_row_tiles(rows.n_tiles, before, (qp, cache))
-            with jax.named_scope("attention"):
+            # each loop under a name of its own: its `%while` and
+            # whatever op of its body a fusion left without metadata
+            # still say which loop they are
+            with tracing.device_scope("rows_before"):
+                pos = ln[rows.slot] + rows.col                 # [R]
+                qp, cache = over_row_tiles(
+                    rows.n_tiles, before, (qp, cache))
+            with tracing.device_scope("attention"):
                 tiles = ragged_attention_tiles(
                     qp[rows.back], cache,
                     (work, None, work[0].shape[0], ragged_pack),
@@ -985,30 +997,34 @@ def fused_multi_transformer(
 
             def after(r0, carry):
                 hp, *sums = carry if sp.experts else (carry,)
-                with jax.named_scope("attention"):
+                with tracing.device_scope("attention"):
                     slot, col = row_tile(rows.slot, r0), row_tile(rows.col, r0)
                     live = row_tile(rows.live, r0)
                     ctx = tile_rows(
                         tiles, slot, col, live, ragged_pack,
                         rows.back.shape[1], qp.shape[1],
-                        sp.v_head_dim or qp.shape[2])
+                        sp.v_head_dim or qp.shape[2]).astype(hp.dtype)
+                with tracing.device_scope("out_proj"):
+                    resid = row_tile(hp, r0)[None]
                 out, got = finish(
-                    row_tile(hp, r0)[None], ctx.astype(hp.dtype)[None],
-                    lw, li, None if dkey is None
+                    resid, ctx[None], lw, li, None if dkey is None
                     else jax.random.fold_in(dkey, r0), sp,
                     live[None] if sp.experts else None)
-                hp = put_row_tile(hp, out[0], r0)
+                with tracing.device_scope("ffn"):
+                    hp = put_row_tile(hp, out[0], r0)
                 if sp.experts:
                     return (hp, *(a + b for a, b in zip(sums, got)))
                 return hp
 
-            if sp.experts:
-                hp, *sums = over_row_tiles(
-                    rows.n_tiles, after,
-                    (hp, jnp.zeros(sp.experts.held, jnp.int32),
-                     jnp.int32(0)))
-                return hp, qp, cache, sums
-            return over_row_tiles(rows.n_tiles, after, hp), qp, cache, None
+            with tracing.device_scope("rows_after"):
+                if sp.experts:
+                    hp, *sums = over_row_tiles(
+                        rows.n_tiles, after,
+                        (hp, jnp.zeros(sp.experts.held, jnp.int32),
+                         jnp.int32(0)))
+                    return hp, qp, cache, sums
+                return (over_row_tiles(rows.n_tiles, after, hp), qp, cache,
+                        None)
 
         padded = False
         if tables_a is not None:
@@ -1035,10 +1051,11 @@ def fused_multi_transformer(
                     return tuple(rwork)
                 k = (sp.window, sp.table)
                 if k not in works:
-                    works[k] = window_work(
-                        tabs[sp.table], ln, ql, window=sp.window,
-                        block_size=cache.shape[3], chunk=width,
-                        pack=ragged_pack)
+                    with tracing.device_scope("attention"):
+                        works[k] = window_work(
+                            tabs[sp.table], ln, ql, window=sp.window,
+                            block_size=cache.shape[3], chunk=width,
+                            pack=ragged_pack)
                 return works[k]
 
         gots = []       # each expert layer's two counts (`expert_ffn`)
@@ -1050,19 +1067,22 @@ def fused_multi_transformer(
         def counted():
             """[[expert layers, held], [expert layers]] where some layer
             has experts."""
-            return [jnp.stack(c) for c in zip(*gots)]
+            with tracing.device_scope("moe_experts"):
+                return [jnp.stack(c) for c in zip(*gots)]
 
         if padded:
             # a wide slab handed over as [B, C, E]: packed here, and
             # handed back in the slab's geometry
-            rows = live_rows(qlens, s)
-            xa = xa[rows.slot, rows.col][None]
+            with tracing.device_scope("embed"):
+                rows = live_rows(qlens, s)
+                xa = xa[rows.slot, rows.col][None]
         new_caches = []
         if rows is not None:
             # a wide paged step: xa is [1, R, E], the packed live rows
             q_rows = jax.eval_shape(
                 lambda z: project(z, layer(0), 0, layers[0])[0], xa)
-            hp, qp = xa[0], jnp.zeros(q_rows.shape[1:], q_rows.dtype)
+            with tracing.device_scope("q_pack"):
+                hp, qp = xa[0], jnp.zeros(q_rows.shape[1:], q_rows.dtype)
             for li, sp in enumerate(layers):
                 hp, qp, cache, got = packed_paged_layer(
                     (li if _dequant or _mm else 0, sp), layer(li),
@@ -1071,15 +1091,18 @@ def fused_multi_transformer(
                     dkeys[li] if dkeys else None, hp, qp, caches[li])
                 new_caches.append(cache)
                 count(got)
-            return tuple([hp[rows.back] if padded else hp[None]]
-                         + new_caches + counted())
+            with tracing.device_scope("embed"):
+                out = hp[rows.back] if padded else hp[None]
+            return tuple([out] + new_caches + counted())
         h = xa
         live = None
         for li, sp in enumerate(layers):
             lw = layer(li)
             resid = h
-            q, k, v = project(h, lw, li, sp)
-            q, k = rope(q, k, table_of(sp))
+            with tracing.device_scope("qkv_proj"):
+                q, k, v = project(h, lw, li, sp)
+            with tracing.device_scope("rope"):
+                q, k = rope(q, k, table_of(sp))
             nh, hd = q.shape[2:]
             scale = 1.0 / math.sqrt(hd)
             # grouped-attention geometry: kv heads g, queries-per-group r
@@ -1102,17 +1125,20 @@ def fused_multi_transformer(
                 # its blocks out of that same buffer. The buffer is the
                 # layer's result: nothing here slices a half out of it
                 # or stacks two back (either is a copy of the cache).
-                # (On the v5e the chunk writer's fused scatter keeps no
-                # op metadata, so the trace shows it under no scope.)
+                # (A fusion that keeps no op metadata, as the chunk
+                # writer's scatter did on the v5e, is read under the
+                # region of the op it runs inside:
+                # perfbench/lib/step_regions.py.)
                 cache = caches[li]             # [2, KVH, NB, BS, D]
                 work = work_of(sp, cache)
                 work = (work, None, work[0].shape[0], ragged_pack)
                 if sp.experts:  # the rows its router may send anywhere
-                    live = jnp.arange(s)[None, :] < ql[:, None]   # [B, C]
-                with jax.named_scope("kv_write"):
+                    with tracing.device_scope("moe_route"):
+                        live = jnp.arange(s)[None, :] < ql[:, None]  # [B, C]
+                with tracing.device_scope("kv_write"):
                     cache = append_paged_kv_chunk(
                         cache, k, v, tabs[sp.table], ln, ql)
-                with jax.named_scope("attention"):
+                with tracing.device_scope("attention"):
                     ctx = ragged_paged_attention(
                         q, cache, tabs[sp.table], ln + ql, scale=scale,
                         work=work, q_lens=ql, **attend_kw(sp, lw)

@@ -471,6 +471,7 @@ class FusedMultiTransformerEngine:
         import jax.numpy as jnp
         from ..incubate.nn.functional import (ExpertSpec, LayerSpec,
                                               fused_multi_transformer)
+        from ..observability import tracing
 
         def arr(v):
             from ..core.tensor import Tensor as _T
@@ -839,19 +840,21 @@ class FusedMultiTransformerEngine:
             whatever is live: straight-line code, no packing."""
             logits, caches, counts, handed = paged_logits(
                 w, caches, toks, qlens, sel, tables, lens, rwork, rpack)
-            with jax.named_scope("sampler"):
+            with tracing.device_scope("sampler"):
                 toks_out = select(logits, temp, topp, key)
-            if counts is not None:
-                # the assignments that fell on a held expert and the
-                # rows the grouped products were handed, each over the
-                # expert layers, ride out beside the step's samples, in
-                # row 0 of two columns past them (`held_assignments` and
-                # `product_rows` read them): the host fetches one array
-                cols = jnp.zeros((toks_out.shape[0], 2), toks_out.dtype)
-                toks_out = jnp.concatenate(
-                    [toks_out, cols.at[0].set(jnp.stack(
-                        [counts.sum(), handed.sum()]
-                    ).astype(toks_out.dtype))], axis=1)
+                if counts is not None:
+                    # the assignments that fell on a held expert and
+                    # the rows the grouped products were handed, each
+                    # over the expert layers, ride out beside the step's
+                    # samples, in row 0 of two columns past them
+                    # (`held_assignments` and `product_rows` read them):
+                    # the host fetches one array
+                    cols = jnp.zeros((toks_out.shape[0], 2),
+                                     toks_out.dtype)
+                    toks_out = jnp.concatenate(
+                        [toks_out, cols.at[0].set(jnp.stack(
+                            [counts.sum(), handed.sum()]
+                        ).astype(toks_out.dtype))], axis=1)
             return toks_out, caches
 
         def paged_logits(w, caches, toks, qlens, sel, tables, lens, rwork,
@@ -871,17 +874,18 @@ class FusedMultiTransformerEngine:
                 w = tp_dequant(w)
             emb = w["embedding"]
             rows = None
-            if toks.shape[0] * toks.shape[1] > ROW_TILE:
-                rows = live_rows(qlens, toks.shape[1])
-                ids = toks[rows.slot, rows.col]              # [R]
-                h = over_row_tiles(
-                    rows.n_tiles,
-                    lambda r0, h: put_row_tile(
-                        h, emb[row_tile(ids, r0)], r0),
-                    jnp.zeros((ids.shape[0], emb.shape[1]),
-                              emb.dtype))[None]              # [1, R, E]
-            else:
-                h = emb[toks]                                # [B, C, E]
+            with tracing.device_scope("embed"):
+                if toks.shape[0] * toks.shape[1] > ROW_TILE:
+                    rows = live_rows(qlens, toks.shape[1])
+                    ids = toks[rows.slot, rows.col]              # [R]
+                    h = over_row_tiles(
+                        rows.n_tiles,
+                        lambda r0, h: put_row_tile(
+                            h, emb[row_tile(ids, r0)], r0),
+                        jnp.zeros((ids.shape[0], emb.shape[1]),
+                                  emb.dtype))[None]              # [1, R, E]
+                else:
+                    h = emb[toks]                                # [B, C, E]
             from ..core.tensor import Tensor
             cts = [Tensor(c) for c in caches]
             out = fused_multi_transformer(
@@ -895,7 +899,7 @@ class FusedMultiTransformerEngine:
             counts = handed = None
             if isinstance(out, tuple):
                 out, counts, handed = out[0], out[1].data, out[2].data
-            with jax.named_scope("head"):
+            with tracing.device_scope("head"):
                 bidx = jnp.arange(toks.shape[0])[:, None]
                 if rows is None:
                     picked = out.data[bidx, sel]             # [B, W, E]

@@ -55,7 +55,8 @@ from .metrics import _host_float, get_registry
 
 __all__ = [
     "SpanRecorder", "FlightRecorder", "get_tracer", "get_flight_recorder",
-    "span", "event", "annotation", "PhaseMarks",
+    "span", "event", "annotation", "PhaseMarks", "device_scope",
+    "STEP_REGIONS",
     "chrome_span_events", "request_summary",
     "requests_seen", "load_dump", "write_dump", "arm_default",
     "load_manifest", "operator_abort_dump", "run_with_abort_evidence",
@@ -186,10 +187,46 @@ def event(name, request=None, **args):
     _tracer.event(name, request=request, **args)
 
 
+# -- names on the device -----------------------------------------------------
+# Every op of the serving step (`jit_paged_step`) lies in exactly one
+# innermost region of this vocabulary; the device trace's readers
+# (`perfbench/lib/step_regions.py`) match these names as path components
+# of an op's `tf_op`. The loops carry names of their own (`rows_before`,
+# `rows_after`: a wide step's two row-tile loops a layer; `moe_slabs`:
+# the routed experts' slab loop), so a `%while` event and whatever op of
+# its body lost its metadata to a fusion still say which loop they are.
+STEP_REGIONS = (
+    "embed", "rows_before", "qkv_proj", "rope", "kv_write", "q_pack",
+    "attention", "rows_after", "out_proj", "ffn", "moe_route",
+    "moe_experts", "moe_slabs", "head", "sampler")
+
+
+@contextlib.contextmanager
+def device_scope(name):
+    """`with tracing.device_scope("kv_write"):` inside a jitted function:
+    the ops traced under it carry `name` twice. As a `jax.named_scope`,
+    which the profiler shows as a path component of the op's `tf_op`;
+    and as the frontend attribute `scope` (`set_xla_metadata`), because
+    a named scope lives in MLIR locations, which jax strips from the
+    persistent compile cache's key: two programs that differ in a
+    scope's name only would be served each other's executables, with
+    the other's names in them. An attribute is IR, so the key follows
+    the region names. Nested, the innermost name is the attribute's
+    value and the path holds them all. A name outside `STEP_REGIONS`
+    raises: the vocabulary is that tuple and nothing else."""
+    if name not in STEP_REGIONS:
+        raise ValueError(f"device_scope: {name!r} is not one of "
+                         f"STEP_REGIONS {STEP_REGIONS}")
+    import jax
+    from jax.experimental.xla_metadata import set_xla_metadata
+    with jax.named_scope(name), set_xla_metadata(scope=name):
+        yield
+
+
 # -- the profiler's clock ----------------------------------------------------
 # The one seam between host code and the jax profiler. Host-side only,
 # like span(): never inside a jitted function (GL105) — names on the
-# device are `jax.named_scope` and a kernel's `name=`.
+# device are `device_scope` above and a kernel's `name=`.
 
 _NO_ANNOTATION = contextlib.nullcontext()
 _trace_annotation = None    # jax.profiler.TraceAnnotation, once jax is here

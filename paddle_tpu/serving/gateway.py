@@ -53,8 +53,7 @@ loop and the stepper thread are on the ring and in the registry: the
 `gateway_emit_to_wire_seconds` observation per token event (and an
 `emit_to_wire` span for the request's first), from a stamp that rides
 BESIDE the event through the bridge's queue (the SSE payload is
-untouched); `gateway.sse_write` marks the loop thread's writes for the
-profiler.
+untouched).
 """
 import asyncio
 import json
@@ -567,11 +566,8 @@ class ServingGateway:
             while True:
                 ev, t_emit = await next_event()
                 etype = ev.pop("type")
-                # the loop thread's share of the interpreter lock, to
-                # lay against serve.* on the stepper thread
-                with _tracing.annotation("gateway.sse_write"):
-                    writer.write(sse.format_event(etype, ev))
-                    await writer.drain()
+                writer.write(sse.format_event(etype, ev))
+                await writer.drain()
                 if etype == "token":
                     # the hand-off out: emitted on the stepper thread
                     # -> drained to the socket here. Every token event

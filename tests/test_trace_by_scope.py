@@ -1,9 +1,9 @@
 """tools/trace_by_scope.py: a traced run's device time by kind of step.
 A hand-made trace of one decode step (c1) and one wide chunk step (c128)
 whose expert layer's row-tile loop holds a gather, a slab loop and, in
-that, XLA's grouped product: self time by scope, depth and the op above,
-first on plain data, then through the benchmark's readers from an
-encoded xplane."""
+that, XLA's grouped product: self time by region (the benchmark's
+`lib/step_regions.py`), depth and the op above, first on plain data,
+then through the benchmark's readers from an encoded xplane."""
 import json
 import os
 import sys
@@ -41,16 +41,19 @@ def test_self_time_goes_to_the_scope_and_the_step_by_its_width():
     decode, chunk = out["widths"]
     assert (decode["c"], decode["steps"], chunk["c"]) == (1, 1, 128)
     assert decode["program_mean"] == pytest.approx(1.0)
-    assert decode["by_scope"] == {"kv_write": pytest.approx(0.5)}
+    assert decode["by_region"] == {"kv_write": pytest.approx(0.5)}
     assert chunk["program_median"] == pytest.approx(4.0)
-    # the row-tile loop keeps 3.0 - 0.4 - 2.0, the slab loop (under
-    # moe_experts) 2.0 - 1.5 beside the gather's 0.4
-    assert chunk["by_scope"] == {
-        "grouped product": pytest.approx(1.5),
-        "moe_experts": pytest.approx(0.9),
-        "while (own)": pytest.approx(0.6),
+    # the row-tile loop, under no region here, keeps 3.0 - 0.4 - 2.0;
+    # the slab loop (under moe_experts) 2.0 - 1.5 beside the gather's
+    # 0.4 and the grouped product's 1.5, which goes there by its name
+    assert chunk["by_region"] == {
+        "moe_experts": pytest.approx(2.4),
+        "unnamed": pytest.approx(0.6),
         "kv_write": pytest.approx(0.5)}
-    assert sum(chunk["by_scope"].values()) == pytest.approx(3.5)
+    assert sum(chunk["by_region"].values()) == pytest.approx(3.5)
+    assert out["kinds"]["chunk"]["groups"]["ffn"] == pytest.approx(2.4)
+    assert out["kinds"]["chunk"]["idle_ms"] == pytest.approx(0.5)
+    assert [o["op"] for o in out["rest"]] == ["%while.141"]
     ops = {o["op"]: o for o in out["ops"][128]}
     assert [o["op"] for o in out["ops"][128]][0] == "%while.141"
     assert (ops["%while.9"]["depth"], ops["%while.9"]["within"]) \
@@ -60,7 +63,22 @@ def test_self_time_goes_to_the_scope_and_the_step_by_its_width():
     assert ops["%ragged-dot-none.3"]["mean"] == pytest.approx(1.5)
     assert ops["%fusion.1"]["calls_a_step"] == 1.0
     assert [o["op"] for o in out["ops"][1]] == ["%fusion.1"]
-    assert trace_by_scope.reduce([], OPS) == {"widths": [], "ops": {}}
+    assert trace_by_scope.reduce([], OPS) == {
+        "kinds": {}, "widths": [], "ops": {}, "rest": []}
+
+
+def test_the_steps_counters_are_priced_as_a_share_of_the_commit():
+    ms = 1000 * US
+    turn = trace_by_scope.host_turn([
+        (0, 1 * ms, "serve.dispatch w8c1"), (1 * ms, 3 * ms, "serve.fetch w8c1"),
+        (3 * ms, 5 * ms, "serve.commit"), (4 * ms, 4.5 * ms, "serve.telemetry"),
+        (5 * ms, 6 * ms, "serve.dispatch w8c128"),
+        (6 * ms, 8 * ms, "serve.commit"), (7 * ms, 7.5 * ms, "serve.telemetry")])
+    assert turn["steps"] == 2
+    assert turn["ms_a_step"]["serve.telemetry"] == pytest.approx(0.5)
+    assert turn["ms_a_step"]["serve.commit"] == pytest.approx(2.0)
+    assert turn["telemetry_pct_of_commit"] == pytest.approx(25.0)
+    assert trace_by_scope.host_turn([]) == {}
 
 
 def test_the_command_reads_an_xplane_through_the_benchmarks_readers(
@@ -93,9 +111,16 @@ def test_the_command_reads_an_xplane_through_the_benchmarks_readers(
     (d / "p.xplane.pb").write_bytes(encode_xspace(space))
     assert trace_by_scope.main([str(tmp_path)]) == 0
     lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
-    assert [l["c"] for l in lines[:2]] == [1, 128]
-    assert lines[1]["by_scope"]["grouped product"] == pytest.approx(1.5)
-    assert lines[1]["by_scope"]["moe_experts"] == pytest.approx(0.9)
-    assert {l["op"] for l in lines[2:] if l["c"] == 128} == {
+    assert [l["kind"] for l in lines[:2]] == ["decode", "chunk"]
+    assert lines[1]["regions"]["moe_experts"] == pytest.approx(2.4)
+    assert [l["c"] for l in lines[2:4]] == [1, 128]
+    assert lines[3]["by_region"]["moe_experts"] == pytest.approx(2.4)
+    assert lines[-2]["rest"] and lines[-2]["op"] == "%while.141"
+    # the stepper's line: two dispatches, 0.1 ms each under each name
+    assert lines[-1]["host"]["steps"] == 2
+    assert lines[-1]["host"]["ms_a_step"] == {
+        "serve.dispatch": pytest.approx(0.1),
+        "serve.schedule": pytest.approx(0.1)}
+    assert {l["op"] for l in lines[4:-2] if l["c"] == 128} == {
         "%while.141", "%while.9", "%ragged-dot-none.3", "%fusion.7",
         "%fusion.1"}

@@ -77,6 +77,18 @@ def jitted_non_observability_call(x):
     return paddle_tpu.nn.functional.relu(x)
 
 
+from paddle_tpu.observability import tracing  # noqa: E402
+
+
+@jax.jit
+def jitted_region(x):
+    # `tracing.device_scope` names the ops traced under it (a named
+    # scope and a frontend attribute): the one observability call meant
+    # for jitted code — must not trip GL105
+    with tracing.device_scope("ffn"):
+        return x * 2
+
+
 @jax.jit
 def mxu_dot_with_accumulator(a, b):
     # the sanctioned MXU spellings: accumulator stated (GL106 clean) —

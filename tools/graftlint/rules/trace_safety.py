@@ -271,6 +271,11 @@ def _call_root(expr):
             return None
 
 
+# the one observability call that is MEANT for traced code: it records
+# nothing on the host, it names the ops traced under it
+_DEVICE_SIDE = frozenset({"device_scope"})
+
+
 @rule("GL105", "observability-record-in-jit", "trace-safety")
 def observability_in_jit(ctx):
     """paddle_tpu.observability calls inside a jit-decorated function:
@@ -286,6 +291,9 @@ def observability_in_jit(ctx):
             continue
         for node in ast.walk(fn):
             if not isinstance(node, ast.Call):
+                continue
+            if isinstance(node.func, ast.Attribute) and \
+                    node.func.attr in _DEVICE_SIDE:
                 continue
             root = _call_root(node.func)
             hit = root in mod_aliases or root in symbols
