@@ -11,7 +11,9 @@ out with the repo's own references:
             buffer_depth 1 and 2, a zero-length row) against
             `ragged_paged_attention_reference`; flash forward and the
             fused backward at S=2048/D=64 against `_sdpa_ref`; the paged
-            cache writers against numpy
+            cache writers against numpy, the writer of new rows also at
+            each served cache's shape, timed beside the scatter it
+            replaced
   trainer   `make_mesh(1)` -> `make_train_state` -> `make_train_step`,
             three steps on one seeded batch
   server    `FusedMultiTransformerEngine` -> `ContinuousBatchingEngine`
@@ -76,6 +78,16 @@ FULL_KERNELS = dict(dtype="bfloat16", KVH=8, G=2, D=64, BS=16, max_nb=16,
                     mixed_chunk=128,
                     mixed_qlens=[1, 128, 0, 1, 1, 1, 4, 1],
                     flash=dict(B=1, S=2048, H=16, D=64))
+
+# One layer's cache as each served configuration holds it (KVH, NB, BS,
+# key width, value width; perfbench/configs/*-serve-1chip.json): the
+# writer of new rows is checked and timed there, a 256-row tile as a
+# wide step packs it.
+WRITER_SHAPES = {
+    "mistral7b": (8, 321, 128, 128, 128),
+    "mimo full layer": (4, 289, 256, 192, 128),
+    "mimo window layer": (8, 33, 256, 192, 128),
+}
 
 
 class SmokeFailure(AssertionError):
@@ -280,6 +292,9 @@ def kernels_leg(k):
           and np.array_equal(np.asarray(got_v, np.float32), want_v),
           "copy_paged_kv differs from numpy")
     print("  paged cache writers: equal to numpy", flush=True)
+    for name, shape in WRITER_SHAPES.items():
+        writer_at(name, shape, dt)
+        writer_at(name + " decode", shape, dt, rows=16)
 
     # flash forward + fused backward, against _sdpa_ref
     f = k["flash"]
@@ -299,6 +314,86 @@ def kernels_leg(k):
     want = jax.jit(jax.grad(loss(sdpa), argnums=(0, 1, 2)))(fq, fk, fv)
     for name, g, w in zip("qkv", got, want):
         _close(f"flash bwd d{name}", g, w, GRAD_TOL_FACTOR * tol)
+
+
+def _scatter_rows(cache, k_rows, v_rows, blk, off):
+    """The writer of new rows until PR 43, kept here as the yardstick:
+    one scatter index row per (half, kv head, token)."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    d = cache.shape[-1]
+    new = jnp.stack([pa._lane_pad(k_rows, d), pa._lane_pad(v_rows, d)])
+    idx = (jnp.arange(2)[:, None, None], jnp.arange(cache.shape[1]),
+           blk[:, None], off[:, None])
+    return cache.at[idx].set(new, mode="drop")
+
+
+def writer_at(name, shape, dt, rows=256, timed=50):
+    """`append_paged_kv_rows` on one layer's cache of `shape`, a tile of
+    `rows` packed rows: a slot prefilling across a block boundary, then
+    decode rows, one of them at its table's capacity (dropped), the rest
+    of the tile dead. Equal to numpy everywhere; then the time of one
+    call, beside the scatter's (a loop of `timed` calls in one program,
+    the cache its carry, an empty loop's time taken off)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    KVH, NB, BS, DK, DV = shape
+    Dc = pa.paged_head_dim(max(DK, DV))
+    rng = np.random.default_rng(1)
+    cache = jax.random.normal(
+        jax.random.PRNGKey(0), (2, KVH, NB, BS, Dc), jnp.float32).astype(dt)
+    B, max_nb = 16, 2
+    tables = (1 + np.arange(B * max_nb, dtype=np.int32)).reshape(B, max_nb)
+    cap = max_nb * BS
+    span = rows * 135 // 256 if rows > B else 0     # crosses into block 2
+    slot = np.concatenate([np.zeros(span, np.int32),
+                           np.arange(1, B, dtype=np.int32),
+                           np.zeros(rows - span - B + 1, np.int32)])
+    pos = np.concatenate([BS - span // 2 + np.arange(span, dtype=np.int32),
+                          rng.integers(0, cap, B - 1).astype(np.int32),
+                          np.zeros(rows - span - B + 1, np.int32)])
+    pos[span + B - 2] = cap             # a full sequence: dropped
+    live = np.arange(rows) < span + B - 1
+    k = jnp.asarray(rng.standard_normal((rows, KVH, DK)), dt)
+    v = jnp.asarray(rng.standard_normal((rows, KVH, DV)), dt)
+    args = (k, v, jnp.asarray(tables), jnp.asarray(slot), jnp.asarray(pos),
+            jnp.asarray(live))
+    want = np.asarray(cache).copy()
+    kn, vn = np.asarray(k), np.asarray(v)
+    for r in range(rows):
+        if live[r] and pos[r] < cap:
+            cell = (slice(None), tables[slot[r], pos[r] // BS], pos[r] % BS)
+            want[(0,) + cell] = 0
+            want[(1,) + cell] = 0
+            want[(0,) + cell + (slice(0, DK),)] = kn[r]
+            want[(1,) + cell + (slice(0, DV),)] = vn[r]
+    got = jax.jit(pa.append_paged_kv_rows)(cache, *args)
+    check(np.array_equal(np.asarray(got), want),
+          f"{name}: append_paged_kv_rows differs from numpy")
+    del got, want
+
+    blk, off = pa._span_cells(
+        jnp.asarray(tables)[slot], jnp.asarray(pos),
+        jnp.asarray(pos + live), 1, NB, BS)
+    forms = {
+        "kernel": lambda c: pa.append_paged_kv_rows(c, *args),
+        "scatter": lambda c: _scatter_rows(c, k, v, blk[:, 0], off[:, 0]),
+        "empty loop": lambda c: c,
+    }
+    took = {}
+    for form, fn in forms.items():
+        loop = jax.jit(lambda c, fn=fn: jax.lax.fori_loop(
+            0, timed, lambda i, c: fn(c), c), donate_argnums=0)
+        cache = jax.block_until_ready(loop(cache))
+        t0 = time.perf_counter()
+        cache = jax.block_until_ready(loop(cache))
+        took[form] = (time.perf_counter() - t0) / timed * 1e3
+    print(f"  writer {name} {tuple(cache.shape)}: {int(live.sum())} live of "
+          f"{rows} rows: kernel {took['kernel'] - took['empty loop']:.4f} ms"
+          f" a call, the scatter {took['scatter'] - took['empty loop']:.4f}"
+          f" (empty loop {took['empty loop']:.4f})", flush=True)
 
 
 # -- trainer -----------------------------------------------------------------

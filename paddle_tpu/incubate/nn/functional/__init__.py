@@ -1119,16 +1119,16 @@ def fused_multi_transformer(
                 # not B x max_blocks, and a whole prompt chunk rides one
                 # kernel invocation next to the decode rows
                 # named for the device trace: `kv_write` is everything
-                # the append costs — the new rows stacked and scattered
-                # into the layer's [2, KVH, NB, BS, D] cache where it
-                # lies — and `attention` the ragged kernel, which reads
-                # its blocks out of that same buffer. The buffer is the
-                # layer's result: nothing here slices a half out of it
-                # or stacks two back (either is a copy of the cache).
-                # (A fusion that keeps no op metadata, as the chunk
-                # writer's scatter did on the v5e, is read under the
-                # region of the op it runs inside:
-                # perfbench/lib/step_regions.py.)
+                # the append costs — the new rows stacked, and the
+                # writer's kernel that lays them into the layer's
+                # [2, KVH, NB, BS, D] cache where it lies, a move per
+                # 8-row group that holds a live token — and `attention`
+                # the ragged kernel, which reads its blocks out of that
+                # same buffer. The buffer is the layer's result: nothing
+                # here slices a half out of it or stacks two back
+                # (either is a copy of the cache). The kernel's custom
+                # call keeps its op metadata, so a trace's readers find
+                # it under `kv_write` (perfbench/lib/step_regions.py).
                 cache = caches[li]             # [2, KVH, NB, BS, D]
                 work = work_of(sp, cache)
                 work = (work, None, work[0].shape[0], ragged_pack)

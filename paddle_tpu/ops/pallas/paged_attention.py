@@ -9,8 +9,9 @@ TPU-native shape: the KV cache lives in HBM as fixed-size blocks; each
 sequence owns a list of block ids (block_tables [B, max_blocks]). A
 layer's K and V halves are ONE buffer [2, KVH, num_blocks, block_size,
 Dc] that the engine's step program donates: the writers at the end of
-this file scatter new rows into it and the ragged kernel DMAs blocks out
-of it, so no program slices a half out or stacks two back. Dc is the
+this file lay new rows into it (a small kernel that moves the 8-row
+groups its live tokens fall in) and the ragged kernel DMAs blocks out of
+it, so no program slices a half out or stacks two back. Dc is the
 head dim rounded up to the 128-lane tile (`paged_head_dim`; the pad
 lanes hold zeros): Mosaic DMAs whole (sublane, 128) tiles, so a 64-wide
 row cannot be sliced out of HBM
@@ -1162,40 +1163,198 @@ def put_row_tile(buf, new, r0):
 # One operand form, one contract: a layer's stacked cache
 # [2, KVH, NB, BS, Dc] (`append_paged_kv`, `append_paged_kv_chunk`,
 # `append_paged_kv_rows` for a wide step's packed tiles,
-# `truncate_paged_kv`, `copy_paged_kv`). Rows are scattered into that
+# `truncate_paged_kv`, `copy_paged_kv`). Rows are written into that
 # buffer itself and the buffer is the result, so a jitted program that
 # donates it never reads or writes a whole cache to append a row —
 # `tests/test_attention_ragged_paged.py` `TestStepNeverCopiesTheCache`
 # pins that for the engine's paged step (no slice, stack or pad of a
 # cache or a half in its jaxpr, its cache results aliased to its donated
-# arguments). All go through `_write_span`, where the index arithmetic
-# and the boundary contract are written once:
+# arguments). All take their cells from `_span_cells`, where the index
+# arithmetic and the boundary contract are written once:
 #   * a position at or past its row's `stop`, or at/after the table's
 #     capacity (max_blocks * block_size), is DROPPED — its block id is
-#     set to NB, past the pool, and the scatter's mode="drop" discards
-#     it — never aliased onto whatever block a clamped gather would hand
-#     back;
+#     set to NB, past the pool, and the writer (`_kv_rows_kernel`, or
+#     the rewind's scatter with mode="drop") discards it — never aliased
+#     onto whatever block a clamped gather would hand back;
 #   * the block-table column read is clamped into the table.
+#
+# New rows have ONE writer, `_write_rows`, whatever the slab's width (a
+# wide tile, a narrow chunk slab, a decode step): a small kernel that
+# costs what it writes. A DMA into the cache can address no less than
+# the KV_GROUP = 8 rows of one HBM tile (Mosaic refuses a one-row slice
+# of the tiled [BS, Dc] plane), so the kernel moves a block's aligned
+# 8-row GROUP: read into VMEM, the live rows laid over it, written back.
+# Rows that share a group are merged into one such move, so a
+# prefilling slot's span costs one move per 8 tokens, a decode row one,
+# and a dead row nothing. (A scatter walks one index row per (half, kv
+# head, token), live or dead: 2 x 256 x KVH a wide tile, 68 ns each on
+# the v5e; with the token as its index, [2, KVH, Dc] windows, the v5e
+# compiler puts two cache-sized transposes around it.)
 
-def _write_span(cache, rows, block_tables, start, stop, span):
-    """Write rows[i, b, j, h, :] into half i of the stacked cache
-    [2, KVH, NB, BS, Dc] at position start[b] + j of sequence b (kv head
-    h), for j < span (a static int) — dropped where the position is
-    at/after stop[b] or the table's capacity. rows is
-    [2, B, span, KVH, Dc] or a scalar; the half's index joins the one
-    scatter. Positions are distinct per (b, j), so writes never
-    collide."""
-    _, kvh, nb, bs, _ = cache.shape
+KV_GROUP = 8    # cache rows of one HBM tile: the least a DMA addresses
+_KV_MOVES = 4   # a group's read starts this many turns before its write
+
+
+def _span_cells(block_tables, start, stop, span, nb, bs):
+    """([B, span] block id, in-block offset) of positions start[b] + j
+    of sequence b, j < span (a static int): the id is `nb`, past the
+    pool, where the position is at/after stop[b] or the table's
+    capacity (DROPPED, see above)."""
     max_nb = block_tables.shape[1]
     pos = jnp.reshape(start, (-1, 1)) + jnp.arange(span)[None, :]  # [B, S]
     valid = (pos < jnp.reshape(stop, (-1, 1))) & (pos < max_nb * bs)
     blk_col = jnp.minimum(pos // bs, max_nb - 1)    # clamp the table read
     blk_ids = jnp.take_along_axis(block_tables, blk_col, axis=1)
-    # scatter mode="drop": invalid rows aim past the cache and vanish
-    blk_ids = jnp.where(valid, blk_ids, nb)
-    idx = (jnp.arange(kvh), blk_ids[:, :, None], (pos % bs)[:, :, None])
-    idx = (jnp.arange(2)[:, None, None, None],) + idx
-    return cache.at[idx].set(rows, mode="drop")
+    return jnp.where(valid, blk_ids, nb), pos % bs
+
+
+def _kv_rows_kernel(blk, off, new_ref, _, cache, first, buf, rsem, wsem):
+    """Rows r of new_ref [2, R, KVH, Dc] (f32, in VMEM) into
+    cache[:, :, blk[r], off[r]] (HBM, aliased to the result), dropped
+    where blk[r] is outside the pool. blk, off: scalar-prefetched [R],
+    off in 0 .. BS - 1. The cells are distinct, as a scatter's are.
+
+    Pass 1 (scalars only) finds the groups: first[g] is the first row of
+    the g-th run of live rows that share (block, offset // group); dead
+    rows split no run. Pass 2 walks them over a ring of buffers, one
+    loop whose turn t starts group t's read and merges group
+    t - `_KV_MOVES` + 1: wait for its rows, lay its live rows over them,
+    start the write. A buffer's last write is waited for before the next
+    read lands in it, a wait a turn, the last writes in the loop's last
+    turns. Should two groups within a ring's length be ONE group (two
+    sequences in one block: no engine appends so, a scatter allows it),
+    a read ahead could pass the write it must see: pass 1 sees that
+    (`clash`), and the walk then reads nothing ahead, each turn waiting
+    for the write before it.
+
+    Every step program lowers this body once and holds a copy a layer,
+    so it is kept small: `lax` primitives on its scalars (a `jnp.where`
+    or a `//` is a nested jit the lowering traces again), one site each
+    for a move's start and its wait, three loops."""
+    _, _, nb, bs, _ = cache.shape
+    nbuf, group = buf.shape[0], buf.shape[3]
+    i32, lax = jnp.int32, jax.lax
+    pick = lambda c, a, b: lax.select(c, i32(a), i32(b))
+
+    def cell(r):
+        """Row r's (block, offset, whether the block is in the pool)."""
+        b = blk[r]
+        return b, off[r], (b >= 0) & (b < nb)
+
+    def scan(r, carry):
+        n, end, clash, prev, *ring = carry      # ring: the keys before prev
+        b, o, live = cell(r)
+        key = pick(live, b * (bs // group) + lax.div(o, i32(group)), prev)
+        head = key != prev
+        first[n] = r        # slot n is nobody's until a head row takes it
+        seen = functools.reduce(lax.bitwise_or, [key == k for k in ring])
+        ring = [pick(head, k, old)
+                for k, old in zip([prev] + ring[:-1], ring)]
+        return (n + head.astype(i32), pick(live, r + 1, end),
+                clash | (head & seen), key, *ring)
+
+    n, end, clash, *_ = lax.fori_loop(
+        0, blk.shape[0], scan,
+        (i32(0), i32(0), False) + (i32(-1),) * (nbuf - 1))
+    first[n] = end          # the last group stops after the last live row
+
+    def move(g, write=False):
+        # a group's head row is live, so its cell is in the pool; the
+        # ids are still data: clamp both before the HBM DMA (GL301)
+        b, o, _ = cell(first[g])
+        rows = cache.at[:, :, jnp.minimum(lax.max(b, 0), nb - 1), pl.ds(
+            pl.multiple_of(jnp.minimum(
+                lax.max(lax.div(o, i32(group)), 0), bs // group - 1)
+                * group, group), group)]
+        s = lax.rem(g, i32(nbuf))
+        if write:
+            return pltpu.make_async_copy(buf.at[s], rows, wsem.at[s])
+        return pltpu.make_async_copy(rows, buf.at[s], rsem.at[s])
+
+    def put(r, s):
+        _, o, live = cell(r)
+        shape = (2, group, buf.shape[-1])
+        at = (lax.broadcasted_iota(i32, shape, 1)
+              == lax.rem(o, i32(group))) & live
+        for h in range(buf.shape[2]):
+            buf[s, :, h] = lax.select(
+                at, jnp.broadcast_to(new_ref[:, pl.ds(r, 1), h, :], shape),
+                buf[s, :, h].astype(jnp.float32)).astype(buf.dtype)
+        return s
+
+    def turn(t, carry):
+        # group t's buffer is free once the write before it there has
+        # landed; after a clash its rows are final only once every
+        # earlier write has, and the turn before this one waited for
+        # all but the last
+        w = pick(clash, t - 1, t - nbuf)
+
+        @pl.when((w >= 0) & (w < n))
+        def _():
+            move(w, write=True).wait()
+
+        @pl.when(t < n)
+        def _():
+            move(t).start()
+        g = pick(clash, t, t - (_KV_MOVES - 1))
+
+        @pl.when((g >= 0) & (g < n))
+        def _():
+            move(g).wait()
+            lax.fori_loop(first[g], first[g + 1], put, lax.rem(g, i32(nbuf)))
+            move(g, write=True).start()
+        return carry
+
+    # the last turns start nothing: they wait for the last writes
+    lax.fori_loop(0, pick(n > 0, n + nbuf, 0), turn, 0)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _write_rows(cache, k_new, v_new, block_tables, start, stop, *,
+                interpret):
+    """THE writer of new rows: k_new / v_new [B, S, KVH, D], row (b, j)
+    at position start[b] + j of the sequence whose blocks
+    block_tables[b] lists, into the stacked cache [2, KVH, NB, BS, Dc];
+    dropped at/after stop[b] or the table's capacity (`_span_cells`).
+    Rows narrower than Dc are zero-padded (`paged_head_dim`). One jitted
+    function, cells and all: a step's layers of one shape share one
+    trace of it and one lowering of the kernel."""
+    _, kvh, nb, bs, d = cache.shape
+    b, s = k_new.shape[:2]
+    blk, off = _span_cells(block_tables, start, stop, s, nb, bs)
+    # a block of fewer rows than a tile, or not of whole tiles, moves in
+    # the largest groups that divide it (the CPU tests' blocks; Mosaic
+    # takes whole tiles or a whole plane)
+    group = math.gcd(bs, KV_GROUP)
+    # rows in the cache's type (the values the scatter stored), widened:
+    # a packed type's single row cannot be read out of VMEM at a row the
+    # data chooses, a 32-bit one can, and the way back is exact. They
+    # stay token major, as the projection leaves them: handed over kv
+    # head major (the cache's order) the compiler lays the projection's
+    # and the rope's outputs out head major for it, q's with them, and
+    # copies a wide step's packed q buffer back once a layer
+    new = jnp.stack([_lane_pad(k_new, d), _lane_pad(v_new, d)]) \
+        .reshape(2, b * s, kvh, d).astype(cache.dtype).astype(jnp.float32)
+    return pl.pallas_call(
+        _kv_rows_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.SMEM((b * s + 1,), jnp.int32),
+                pltpu.VMEM((_KV_MOVES + 2, 2, kvh, group, d), cache.dtype),
+                pltpu.SemaphoreType.DMA((_KV_MOVES + 2,)),
+                pltpu.SemaphoreType.DMA((_KV_MOVES + 2,))]),
+        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        input_output_aliases={3: 0},
+        # NOT `paged_step*`: the benchmark's reader of the ragged kernel
+        # counts every custom call so named
+        name="kv_rows_write",
+        interpret=interpret,
+    )(blk.reshape(-1).astype(jnp.int32), off.reshape(-1).astype(jnp.int32),
+      new, cache)
 
 
 def append_paged_kv_chunk(cache, k_new, v_new, block_tables, context_lens,
@@ -1203,32 +1362,30 @@ def append_paged_kv_chunk(cache, k_new, v_new, block_tables, context_lens,
     """Append a CHUNK of new K/V rows ([B, C, KVH, D]) into one layer's
     stacked cache [2, KVH, NB, BS, Dc]: sequence b's row j lands at
     position context_lens[b] + j for j < valid_counts[b]. The chunk may
-    span block boundaries (the caller grew the block table first). Rows
-    narrower than Dc are zero-padded (`paged_head_dim`). Returns the
-    updated cache: one scatter into the operand, whose new rows
-    ([2, B, C, KVH, Dc]) are all that is stacked.
+    span block boundaries (the caller grew the block table first).
+    Returns the updated cache (`_write_rows`).
 
     Boundary contract: rows past valid_counts[b] and rows whose position
     falls at/after the table capacity are DROPPED (see above)."""
-    d = cache.shape[-1]
-    rows = jnp.stack([_lane_pad(k_new, d), _lane_pad(v_new, d)])
-    return _write_span(cache, rows, block_tables, context_lens,
-                       context_lens + valid_counts, k_new.shape[1])
+    return _write_rows(
+        cache, k_new, v_new, block_tables, context_lens,
+        context_lens + valid_counts, interpret=_interpret_mode())
 
 
 def append_paged_kv_rows(cache, k_rows, v_rows, block_tables, slot, pos,
                          live):
     """Append one tile of a wide step's PACKED rows ([R, KVH, D], in
     `live_rows` order) into one layer's stacked cache: row r lands at
-    position pos[r] of sequence slot[r]. A one-column span per row, so
-    the scatter walks 2 x R x KVH index rows however wide the slab is.
+    position pos[r] of sequence slot[r], a one-column span per row. The
+    writer costs the tile's live rows, however wide the slab is
+    (`_write_rows`).
 
     Boundary contract: a row that is not `live`, or whose position falls
     at/after the table capacity, is DROPPED (see above)."""
-    d = cache.shape[-1]
-    new = jnp.stack([_lane_pad(k_rows, d), _lane_pad(v_rows, d)])
-    return _write_span(cache, new[:, :, None],
-                       jnp.asarray(block_tables)[slot], pos, pos + live, 1)
+    return _write_rows(
+        cache, k_rows[:, None], v_rows[:, None],
+        jnp.asarray(block_tables)[slot], pos, pos + live,
+        interpret=_interpret_mode())
 
 
 def append_paged_kv(cache, k_new, v_new, block_tables, context_lens):
@@ -1256,8 +1413,14 @@ def truncate_paged_kv(cache, block_tables, new_lens, old_lens, max_span):
 
     Boundary contract: positions past the span, past old_lens, or
     at/after the table capacity are DROPPED (see above)."""
-    return _write_span(cache, jnp.zeros((), cache.dtype), block_tables,
-                       new_lens, old_lens, int(max_span))
+    _, kvh, nb, bs, _ = cache.shape
+    blk, off = _span_cells(
+        block_tables, new_lens, old_lens, int(max_span), nb, bs)
+    # one scatter of a scalar, the half's and the head's index in it:
+    # dropped cells aim past the pool and vanish
+    idx = (jnp.arange(2)[:, None, None, None], jnp.arange(kvh),
+           blk[:, :, None], off[:, :, None])
+    return cache.at[idx].set(jnp.zeros((), cache.dtype), mode="drop")
 
 
 def copy_paged_kv(cache, src_block, dst_block):

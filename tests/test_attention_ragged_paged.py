@@ -371,6 +371,78 @@ class TestCacheUpdateBoundary:
             np.testing.assert_array_equal(kc2[:, tables[b, 1], 3], kn[b])
 
 
+# the packed-rows writer's cases: (kv heads, key width, value width) and
+# what the tile holds
+ROWS_CASES = {
+    # a span across a block boundary, a parked slot's dead rows in the
+    # tile's middle, a row at the table's capacity, dead rows at its end
+    "tile": (2, 8, 8),
+    # two sequences' rows in one block's 8-row group, not adjacent in
+    # the tile: no engine appends so, a scatter allows it
+    "shared-group": (2, 8, 8),
+    # MiMo's layers: keys 192 wide over values 128 wide in Dc = 256
+    # lanes, two kv-head counts, each kind its own block table
+    "mimo-full": (1, 192, 128),
+    "mimo-window": (2, 192, 128),
+    # the device's own kv heads inside a shard_map body, tp = 2
+    "tp2": (4, 8, 8),
+}
+
+
+def _rows_case(case, dtype, seed=13):
+    """One tile of packed rows for `append_paged_kv_rows`: (the call as
+    a thunk, numpy oracle over the two halves)."""
+    rng = np.random.default_rng(seed + len(case))
+    kvh, dk, dv = ROWS_CASES[case]
+    dc = pa.paged_head_dim(max(dk, dv)) if dk != dv else dk
+    nb, bs, b, max_nb = 17, 16, 4, 3
+    cap = max_nb * bs
+    f32 = lambda a: np.asarray(jnp.asarray(a, dtype), np.float32)
+    kc, vc = (f32(rng.standard_normal((kvh, nb, bs, dc))) for _ in "kv")
+    tables = rng.permutation(nb - 1)[:b * max_nb].reshape(b, max_nb) \
+        .astype(np.int32)
+    if case == "mimo-window":       # the window layers' own table
+        tables = tables[::-1].copy()
+    # (slot, first position, rows, live)
+    spans = [(0, 10, 11, True),     # crosses a block and two groups
+             (1, 4, 6, False),      # parked: dead in the tile's middle
+             (2, cap - 2, 3, True),  # its third row is at the capacity
+             (3, 3, 5, True),
+             (0, 0, 15, False)]     # the tile's dead end
+    if case == "shared-group":
+        tables[2, 0] = tables[0, 0]     # slots 0 and 2 write one block
+        spans = [(0, 1, 2, True), (1, 20, 3, True), (2, 5, 2, True),
+                 (3, 9, 1, True), (0, 7, 1, True), (1, 0, 4, False)]
+    slot = np.concatenate([np.full(n, s_, np.int32) for s_, _, n, _ in spans])
+    pos = np.concatenate([p0 + np.arange(n, dtype=np.int32)
+                          for _, p0, n, _ in spans])
+    live = np.concatenate([np.full(n, l_) for _, _, n, l_ in spans])
+    kn = f32(rng.standard_normal((len(slot), kvh, dk)))
+    vn = f32(rng.standard_normal((len(slot), kvh, dv)))
+    want_k, want_v = kc.copy(), vc.copy()
+    for r in range(len(slot)):
+        if live[r] and pos[r] < cap:
+            cell = (slice(None), tables[slot[r], pos[r] // bs], pos[r] % bs)
+            want_k[cell], want_v[cell] = 0.0, 0.0   # the lane padding
+            want_k[cell + (slice(0, dk),)] = kn[r]
+            want_v[cell + (slice(0, dv),)] = vn[r]
+    assert (want_k != kc).any()
+    j = lambda a: jnp.asarray(a, dtype)
+    args = (j(kn), j(vn), jnp.asarray(tables), jnp.asarray(slot),
+            jnp.asarray(pos), jnp.asarray(live))
+    kv = jnp.stack([j(kc), j(vc)])
+    if case != "tp2":
+        return lambda: pa.append_paged_kv_rows(kv, *args), (want_k, want_v)
+    from jax.sharding import Mesh, PartitionSpec as P
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+    heads, rows = P(None, "tp"), P(None, "tp")
+    sharded = jax.shard_map(
+        pa.append_paged_kv_rows, mesh=mesh,
+        in_specs=(heads, rows, rows, P(), P(), P(), P()),
+        out_specs=heads, check_vma=False)
+    return lambda: sharded(kv, *args), (want_k, want_v)
+
+
 def _writer_case(writer, dtype, seed=11):
     """Random tables and rows for one writer: (the writer's call as a
     thunk, numpy oracle) over the same data. The rows cover
@@ -378,6 +450,8 @@ def _writer_case(writer, dtype, seed=11):
     capacity (its write drops), a chunk with valid_counts 0 (parked), a
     chunk that crosses a block boundary and one that runs into the
     capacity mid-chunk."""
+    if writer in ROWS_CASES:
+        return _rows_case(writer, dtype)
     rng = np.random.default_rng(seed)
     kvh, nb, bs, d, b, max_nb, c = 2, 17, 4, 8, 4, 3, 6
     cap = max_nb * bs
@@ -435,7 +509,8 @@ class TestStackedWriters:
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("writer",
-                             ["decode", "chunk", "rewind", "copy"])
+                             ["decode", "chunk", "rewind", "copy",
+                              *ROWS_CASES])
     def test_equals_numpy(self, writer, dtype):
         stacked, want = _writer_case(writer, jnp.dtype(dtype))
         got = stacked()
@@ -624,9 +699,21 @@ def _eqns(jaxpr, path=None):
                     yield from _eqns(sub, inner)
 
 
+def _is_writer(eqn, cache):
+    """The writer of new rows: the kernel `kv_rows_write` over a layer's
+    stacked buffer, the buffer aliased to its result."""
+    if eqn.primitive.name != "pallas_call" \
+            or eqn.params["name"] != "kv_rows_write":
+        return False
+    (src, dst), = eqn.params["input_output_aliases"]
+    assert tuple(eqn.invars[src].aval.shape) == cache
+    assert tuple(eqn.outvars[dst].aval.shape) == cache
+    return True
+
+
 class TestStepNeverCopiesTheCache:
     """To append one row a step must not read or write a layer's whole
-    cache: the rows are scattered into the donated [2, KVH, NB, BS, Dc]
+    cache: the rows are written into the donated [2, KVH, NB, BS, Dc]
     buffer, the ragged kernel reads blocks out of that buffer, and the
     buffer is the step's result. A slice of a half (the parent's
     `cache[0]`), or two halves stacked back (`jnp.stack([kc, vc])`),
@@ -670,7 +757,7 @@ class TestStepNeverCopiesTheCache:
         cache = tuple(cb.caches[0].shape)
         assert len(cache) == 5 and cache[0] == 2
         sized = (cache, cache[1:])
-        seen_scatter = 0
+        writers = 0
         for eqn in _eqns(traced.jaxpr.jaxpr):
             shapes = [tuple(getattr(v.aval, "shape", ()))
                       for v in list(eqn.invars) + list(eqn.outvars)]
@@ -678,19 +765,21 @@ class TestStepNeverCopiesTheCache:
                 assert not any(s in sized for s in shapes), (
                     f"{eqn.primitive.name} over a cache or a cache half: "
                     f"{shapes}")
-            if eqn.primitive.name == "scatter" and cache in shapes:
-                seen_scatter += 1
-        # the walk did reach the writers: one scatter per layer, into
-        # the stacked buffer itself
-        assert seen_scatter == len(cb.caches)
+            # nothing else writes a cache: no scatter over one is left
+            assert eqn.primitive.name != "scatter" or cache not in shapes
+            writers += _is_writer(eqn, cache)
+        # the walk did reach the writers: one per layer, into the
+        # stacked buffer itself
+        assert writers == len(cb.caches)
         text = traced.lower().as_text()
         assert text.count("tf.aliasing_output") == len(cb.caches)
 
     def test_wide_step_has_no_slab_sized_matmul_or_scatter(self):
         """Outside the two loops of a layer nothing multiplies or
         scatters B x C rows (or the 2 x B x C x KVH index rows of the
-        padded writer); inside them a tile's ROW_TILE rows do. The
-        layers are calls of one traced function."""
+        padded writer); inside them a tile's ROW_TILE rows do, and no
+        scatter walks the 2 x ROW_TILE x KVH index rows of the writer
+        that was. The layers are calls of one traced function."""
         cb, traced = self._traced(**self.WIDE)
         slab = self.WIDE["max_batch"] * self.WIDE["width"]
         kvh = cb.caches[0].shape[1]
@@ -707,6 +796,10 @@ class TestStepNeverCopiesTheCache:
                 continue    # the ragged kernel keeps the slab's geometry
             shapes = [tuple(getattr(v.aval, "shape", ()))
                       for v in list(eqn.invars) + list(eqn.outvars)]
+            if eqn.primitive.name == "scatter":
+                # index rows: the indices' dims but the last
+                walked = int(np.prod(eqn.invars[1].aval.shape[:-1]))
+                assert walked < 2 * pa.ROW_TILE * kvh, shapes
             if "while" in path:
                 rows_in_loop.update(d for s in shapes for d in s)
                 continue
@@ -738,13 +831,16 @@ class TestStepNeverCopiesTheCache:
     @pytest.mark.parametrize("width", [1, 8, 16])
     def test_one_tile_step_is_straight_line(self, width):
         """A slab of at most ROW_TILE rows is one tile whatever is live
-        in it: no packing, no loop, one scatter a layer."""
+        in it: no packing, no loop, one writer a layer (the one every
+        slab width has) and no scatter."""
         cb, traced = self._traced(width, max_batch=8)
         assert 8 * width <= pa.ROW_TILE
+        cache = tuple(cb.caches[0].shape)
         names = [e.primitive.name
                  for e, path in _eqns(traced.jaxpr.jaxpr, ())
                  if "pallas_call" not in path]
-        assert "while" not in names
-        assert names.count("scatter") == len(cb.caches)
+        assert "while" not in names and "scatter" not in names
+        assert sum(_is_writer(e, cache)
+                   for e in _eqns(traced.jaxpr.jaxpr)) == len(cb.caches)
         assert not any(e.params.get("name") == "packed_paged_layer"
                        for e in _eqns(traced.jaxpr.jaxpr))

@@ -43,6 +43,13 @@ class TestSmokeLegs:
     def test_server_leg_tiny(self, interpret):
         chip_smoke.server_leg(TINY_SERVE)
 
+    @pytest.mark.parametrize("rows", [64, 16], ids=["tile", "decode"])
+    def test_writer_at_tiny(self, interpret, rows, capsys):
+        # keys wider than values, as the second family's, at toy sizes
+        chip_smoke.writer_at("tiny", (2, 40, 16, 24, 16),
+                             np.dtype("float32"), rows=rows, timed=2)
+        assert "the scatter" in capsys.readouterr().out
+
     def test_wrong_tokens_fail_the_reference_check(self):
         rows = np.zeros((2, 8), np.float32)
         rows[:, 3] = 1.0

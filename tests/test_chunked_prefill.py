@@ -160,18 +160,21 @@ class TestChunkedKernel:
         np.testing.assert_array_equal(np.asarray(plain),
                                       np.asarray(bucketed))
 
-    def test_chunk_cache_update_spans_blocks_and_drops(self):
+    # blocks of 4 rows move whole; blocks of 16 are two of the writer's
+    # 8-row groups, and a 12-wide chunk crosses groups and blocks
+    @pytest.mark.parametrize("bs,c", [(4, 4), (16, 12)])
+    def test_chunk_cache_update_spans_blocks_and_drops(self, bs, c):
         rng = np.random.default_rng(5)
-        kvh, nb, bs, d, max_nb, c = 2, 13, 4, 8, 3, 4
+        kvh, nb, d, max_nb = 2, 13, 8, 3
         kc = rng.standard_normal((kvh, nb, bs, d)).astype(np.float32)
         vc = rng.standard_normal((kvh, nb, bs, d)).astype(np.float32)
         kn = rng.standard_normal((3, c, kvh, d)).astype(np.float32)
         vn = rng.standard_normal((3, c, kvh, d)).astype(np.float32)
         tables = np.arange(3 * max_nb, dtype=np.int32).reshape(3, max_nb)
         # row 0: chunk crosses a block boundary; row 1: parked (0 valid);
-        # row 2: runs into the table capacity (12) mid-chunk -> dropped
-        lens = np.asarray([2, 5, 10], np.int32)
-        valid = np.asarray([4, 0, 4], np.int32)
+        # row 2: runs into the table capacity mid-chunk -> dropped
+        lens = np.asarray([bs - 2, bs + 1, max_nb * bs - 2], np.int32)
+        valid = np.asarray([c, 0, c], np.int32)
         kc2, vc2 = np.asarray(pa.append_paged_kv_chunk(
             jnp.stack([kc, vc]), jnp.asarray(kn), jnp.asarray(vn),
             jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(valid)))

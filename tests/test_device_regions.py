@@ -183,8 +183,10 @@ def test_at_most_a_fiftieth_of_the_steps_ops_lie_under_no_region(
     if kind != "dense":
         want |= {"moe_route", "moe_experts", "moe_slabs"}
     assert want <= set(by), (want - set(by), by)
-    # the interpreted kernel is one region
-    assert by["attention"] > by["kv_write"]
+    # each interpreted kernel is one region: the ragged kernel's ops
+    # under `attention`, the writer's under `kv_write`
+    rest = max(n for r, n in by.items() if r not in ("attention", "kv_write"))
+    assert min(by["attention"], by["kv_write"]) > rest, by
 
 
 @pytest.mark.parametrize("bucket", list(BUCKETS))

@@ -97,3 +97,33 @@ def test_mixed_window_and_full_layers_compile_for_a_v5e(one_chip, kw):
     # tile is all the kernel moves), 64 query heads over 8 kv heads with
     # a window and a sink, or over 4 without; pack 1, as 16 slots give
     _compile(one_chip, d=192, v_dim=128, pack=1, nb=65, **kw)
+
+
+@pytest.mark.parametrize("rows", [256, 16], ids=["tile", "decode"])
+@pytest.mark.parametrize("kw", [
+    dict(kvh=8, nb=321, bs=128, dk=128, dv=128),
+    dict(kvh=4, nb=289, bs=256, dk=192, dv=128),
+    dict(kvh=8, nb=33, bs=256, dk=192, dv=128),
+    dict(kvh=2, nb=257, bs=16, dk=64, dv=64),
+], ids=["mistral", "mimo_full", "mimo_window", "smoke_tp4_shard"])
+def test_the_writer_of_new_rows_compiles_for_a_v5e(one_chip, kw, rows):
+    """`append_paged_kv_rows` at each served cache's shape: the kernel's
+    DMAs address whole 8-row tiles of the cache where it lies (Mosaic
+    refuses a one-row slice of it), and the donated cache is the result:
+    no temporary the cache's size."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    dc = pa.paged_head_dim(max(kw["dk"], kw["dv"]))
+    cache = sds((2, kw["kvh"], kw["nb"], kw["bs"], dc), jnp.bfloat16)
+    args = [cache, sds((rows, kw["kvh"], kw["dk"]), jnp.bfloat16),
+            sds((rows, kw["kvh"], kw["dv"]), jnp.bfloat16),
+            sds((16, 18), jnp.int32), sds((rows,), jnp.int32),
+            sds((rows,), jnp.int32), sds((rows,), jnp.bool_)]
+    compiled = jax.jit(pa.append_paged_kv_rows, donate_argnums=0).trace(
+        *args).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert "%kv_rows_write" in text and "tpu_custom_call" in text
+    assert "%paged_step" not in text    # the ragged kernel's reader's name
+    cache_bytes = 2 * kw["kvh"] * kw["nb"] * kw["bs"] * dc * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 8

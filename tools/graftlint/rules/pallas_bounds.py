@@ -30,7 +30,8 @@ import ast
 from ..core import rule, in_pallas
 
 # calls that clamp/guard an index into range
-_CLAMP_CALLS = {"minimum", "clip", "where", "mod", "remainder"}
+_CLAMP_CALLS = {"minimum", "clip", "where", "mod", "remainder",
+                "clamp", "rem"}     # lax.clamp, lax.rem: a kernel's scalars
 # calls fine to see inside an index expression: grid coordinates are
 # bounded by the grid, dtype casts don't change the value class
 _SAFE_CALLS = {"program_id", "num_programs", "astype", "int32", "int64",
